@@ -11,13 +11,9 @@ multi-host checkpointing in place of MonitoredTrainingSession and its hooks.
 
 __version__ = "0.1.0"
 
-# Chip-session lease guard FIRST, before any submodule can touch a jax
-# backend: while tools/chip_session.sh holds the lock, every other
-# importer of this package pins itself to CPU (utils/chip_lock.py).
-from .utils.chip_lock import pin_cpu_if_locked as _pin_cpu_if_locked
-
-_pin_cpu_if_locked()
-
+# Importing the package must initialise no jax backend: a launcher that
+# imports it (and then starts the process that owns the chip) would
+# otherwise take the chip itself (tests/test_chip_smoke.py pins this).
 from . import data  # noqa: F401
 from . import models  # noqa: F401
 from . import obs  # noqa: F401

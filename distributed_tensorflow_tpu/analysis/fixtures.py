@@ -310,10 +310,10 @@ def global_sum(x):
     return lax.psum(partial, mesh_lib.MODEL)
 ''',
     "sharding-seam-bypass": '''\
+import jax
 from jax.sharding import PartitionSpec as P
 
 from ..parallel import sharding
-from ..utils.compat import shard_map
 
 
 def cache_rules():
@@ -324,8 +324,8 @@ def cache_rules():
 def island_mean(mesh, x):
     # carve-out (b): specs inside a shard_map island describe the
     # island's local view, not persistent placement
-    f = shard_map(lambda a: a.mean(), mesh=mesh,
-                  in_specs=P("data"), out_specs=P())
+    f = jax.shard_map(lambda a: a.mean(), mesh=mesh,
+                      in_specs=P("data"), out_specs=P())
     return f(x)
 
 

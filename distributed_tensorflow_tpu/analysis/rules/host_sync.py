@@ -6,8 +6,7 @@ keeps one fused decode program hot. A ``float()`` / ``bool()`` /
 ``.item()`` / ``np.asarray()`` / ``jax.device_get()`` on a traced value
 inside a jit-compiled step either fails at trace time (concretization
 error) or — worse, when it slips through on a re-traced python value —
-silently serializes dispatch with execution, the ~40x step-rate cliff
-utils/benchmarking.py documents for tunneled platforms.
+silently serializes dispatch with execution.
 
 What counts as jit-reachable (PROJECT-SCOPE since the v2 engine —
 analysis/callgraph.py holds the resolution contract):

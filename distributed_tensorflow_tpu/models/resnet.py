@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..parallel import sharding
-from ..utils.compat import shard_map
 
 #: Coverage fixture: the stage_sizes=(1, 1) tree (every param family the
 #: full ResNet-50 tree repeats — stem, bottleneck convs/BNs incl. the
@@ -69,7 +68,8 @@ class ResNetConfig:
     # computed in f32 (flax normalization upcasts internally); bf16 output
     # halves the HBM traffic of the normalize/scale pass — the activations
     # between BN and the next conv are the widest tensors in the net
-    # (round-1 used f32 BN output: -25% throughput, PERF_NOTES.md).
+    # (f32 BN output measured -25% throughput on a v5e, previous
+    # toolchain — PERF.md "Earlier chip findings").
     norm_dtype: str | None = None
     bn_momentum: float = 0.9
     bn_epsilon: float = 1e-5
@@ -82,7 +82,8 @@ class ResNetConfig:
     # "standard": flax Conv/BatchNorm bottlenecks. "fused": Pallas
     # conv1x1+BN kernels (ops/fused_conv_bn.py) — the 1x1 convs absorb the
     # adjacent BN normalize/stats passes (prologue/epilogue), cutting the
-    # HBM traffic that bounds the step (PERF_NOTES.md roofline). Same
+    # HBM traffic that bounds the step (PERF.md "Earlier chip
+    # findings"). Same
     # param/batch_stats tree as "standard" (checkpoints interoperate).
     block_impl: str = "standard"
 
@@ -299,7 +300,7 @@ class FusedBottleneckBlock(nn.Module):
         args = (x, w1, w2, w3, wp_in, g1, b1, g2, b2, g3, b3, gp_in, bp_in)
         if axis_names:
             bspec = P(axis_names, None, None, None)
-            fn = shard_map(
+            fn = jax.shard_map(
                 block_fn,
                 mesh=self.mesh,
                 in_specs=(bspec,) + (P(),) * 12,

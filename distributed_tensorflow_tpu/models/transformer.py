@@ -270,6 +270,35 @@ def _row_parallel_dense(h, out_features, in_features_local, name, dtype,
     return (y + b).astype(dtype)
 
 
+def _flash_on_mesh(q, k, v, kv_mask, *, causal: bool, mesh):
+    """The flash kernel under a GSPMD step. A Mosaic kernel is opaque to
+    the SPMD partitioner — jax refuses to lower one inside a multi-device
+    jit ("Mosaic kernels cannot be automatically partitioned") — so on a
+    mesh the call runs per device under ``shard_map``: batch over
+    (data, fsdp), heads over ``model``, the layout ``transformer_rules``
+    already gives q/k/v. Where the arrays are already per-device —
+    ``mesh=None`` (single device, the pipeline island) or a caller's own
+    ``shard_map`` over this mesh (the sharded evaluator) — it is called
+    directly; ``shard_map`` does not nest."""
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    if mesh is None or mesh.size == 1 or manual == set(mesh.axis_names):
+        return flash_attention(q, k, v, causal=causal, kv_mask=kv_mask)
+    heads, shards = q.shape[1], mesh.shape[mesh_lib.MODEL]
+    if heads % shards:
+        raise ValueError(
+            f"heads ({heads}) not divisible by the model axis ({shards})")
+    qkv_spec = P(mesh_lib.BATCH_AXES, mesh_lib.MODEL, None, None)
+    return jax.shard_map(
+        lambda q, k, v, m: flash_attention(
+            q, k, v, causal=causal, kv_mask=m),
+        mesh=mesh,
+        in_specs=(qkv_spec, qkv_spec, qkv_spec,
+                  None if kv_mask is None else P(mesh_lib.BATCH_AXES, None)),
+        out_specs=qkv_spec,
+        check_vma=False,  # the kernel is per-(batch, head): nothing varies
+    )(q, k, v, kv_mask)
+
+
 class SelfAttention(nn.Module):
     cfg: TransformerConfig
     mesh: Any = None  # jax.sharding.Mesh or None; static module metadata
@@ -414,11 +443,12 @@ class SelfAttention(nn.Module):
                         else jnp.ones((B, S), bool)
                     )
                     pmask = jnp.pad(pmask, ((0, 0), (0, pad)))
-                    out = flash_attention(
-                        pq, pk, pv, causal=cfg.causal, kv_mask=pmask
+                    out = _flash_on_mesh(
+                        pq, pk, pv, pmask, causal=cfg.causal, mesh=self.mesh
                     )[:, :, :S]
                 else:
-                    out = flash_attention(q, k, v, causal=cfg.causal, kv_mask=mask)
+                    out = _flash_on_mesh(
+                        q, k, v, mask, causal=cfg.causal, mesh=self.mesh)
             elif impl == "blockwise":
                 out = blockwise_attention(q, k, v, causal=cfg.causal, kv_mask=mask)
             else:
@@ -711,7 +741,7 @@ class Transformer(nn.Module):
 # only (no MoE interleave — MoE layers break the stacked layout). Dropout
 # works through the schedule (pipelined_apply train=True + rng: per-
 # (microbatch, global-layer) keys threaded through the tick, schedule-
-# independent by construction — VERDICT r2 item 7).
+# independent by construction).
 
 
 def _layer_keys(cfg: TransformerConfig) -> list[str]:
@@ -824,7 +854,7 @@ def pipelined_apply(
     [B,K,vocab].
 
     ``train=True`` with ``rng`` enables dropout (training-semantics parity
-    with the dense path, VERDICT r2 item 7): each layer's mask key is
+    with the dense path): each layer's mask key is
     ``fold_in(fold_in(rng, microbatch), global_layer_index)`` plus, inside
     a pipe>1 island, the (data, fsdp) shard index — flax draws masks at
     the LOCAL shape there, so the shard fold keeps dropout decorrelated

@@ -11,8 +11,7 @@ Two jobs:
   exactly here — so ``bench.py``'s JSON line, ``MetricsLogger``'s log
   line, and the exported ``mfu`` gauge can never disagree.
   ``flops_per_step_from_compiled`` derives the per-step FLOP count from
-  a compiled step's cost analysis (utils/compat.cost_analysis_dict) for
-  models without an analytic count.
+  a compiled step's cost analysis for models without an analytic count.
 
 - **Goodput accounting.** Wall-clock partitioned into a productive
   bucket (steps that advanced training) and wasted buckets
@@ -176,12 +175,10 @@ def train_mfu(
 
 def flops_per_step_from_compiled(compiled) -> float | None:
     """Per-step FLOPs from a compiled executable's cost analysis
-    (``jax.jit(...).lower(...).compile()``), via the cross-version shim
-    ``utils/compat.cost_analysis_dict``. None when the backend offers no
-    analysis — callers fall back to the model's analytic count."""
-    from ..utils.compat import cost_analysis_dict  # lazy: pulls jax
-
-    flops = cost_analysis_dict(compiled).get("flops")
+    (``jax.jit(...).lower(...).compile()``). None when the backend
+    offers no analysis — callers fall back to the model's analytic
+    count."""
+    flops = (compiled.cost_analysis() or {}).get("flops")
     return float(flops) if flops else None
 
 

@@ -182,8 +182,9 @@ def pallas_bwd_known_slow(M: int, cin: int, cout: int) -> bool:
 def resolve_bwd_impl(bwd_impl: str | None) -> str:
     """The fused composites' backward selection policy (one home for the
     env default so the two op families cannot drift): explicit argument
-    wins, else ``DTF_FUSED_BWD``, else the measured-faster "xla" path
-    (round-3 on-chip microbenches, PERF_NOTES.md)."""
+    wins, else ``DTF_FUSED_BWD``, else the "xla" path, which measured
+    faster at every ResNet-50 shape on a v5e (previous toolchain —
+    PERF.md "Earlier chip findings")."""
     import os
 
     impl = bwd_impl or os.environ.get("DTF_FUSED_BWD", "xla")

@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.compat import axis_size, shard_map
 
 from ..parallel import mesh as mesh_lib
 from ..parallel import sharding as sharding_lib
@@ -80,7 +79,7 @@ def mod_sharded_lookup(
     [ceil(V/n), D] shard. One psum over ``axis`` replaces the reference's
     PS gather round-trip (§3.1: variable read = gRPC hop per step).
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     part = _owned_lookup(ids, local_table, lax.axis_index(axis), n)
     return lax.psum(part, axis)
 
@@ -112,7 +111,7 @@ def batch_sharded_lookup(
     sharded over ``axis``. all_gather ids → local contributions →
     reduce_scatter back to the caller's batch slice. Wire-equivalent to the
     TPUEmbedding all_to_all exchange, static-shaped."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     all_ids = lax.all_gather(ids, axis, axis=0, tiled=True)
     part = _owned_lookup(all_ids, local_table, lax.axis_index(axis), n)
     return lax.psum_scatter(part, axis, scatter_dimension=0, tiled=True)
@@ -130,7 +129,7 @@ def make_sharded_lookup(mesh: Mesh, axis: str = mesh_lib.MODEL):
     out_spec = P(mesh_lib.BATCH_AXES, None)
 
     def fn(ids, table_shards):
-        return shard_map(
+        return jax.shard_map(
             lambda i, t: mod_sharded_lookup(i, t, axis),
             mesh=mesh,
             in_specs=(bspec, P(axis, None)),
@@ -152,7 +151,7 @@ def make_range_sharded_lookup(mesh: Mesh, axis: str = mesh_lib.MODEL):
         n = mesh.shape[axis]
         rows = shard_vocab(table.shape[0], n)
         padded = jnp.pad(table, ((0, n * rows - table.shape[0]), (0, 0)))
-        return shard_map(
+        return jax.shard_map(
             lambda i, t: range_sharded_lookup(i, t, axis),
             mesh=mesh,
             in_specs=(bspec, P(axis, None)),

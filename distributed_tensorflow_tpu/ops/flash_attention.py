@@ -396,8 +396,10 @@ def _paged_fwd_kernel(
     kpos = j * block_size + jax.lax.broadcasted_iota(
         jnp.int32, (S, block_size), 1
     )
-    qp = qpos_ref[0]  # [S] absolute query positions (-1 = padded row)
-    mask = kpos <= qp[:, None]
+    # [S, 1] absolute query positions (-1 = padded row); the row stat
+    # arrives lane-broadcast like m/l, so this is a lane slice, not a
+    # sublane<->lane relayout
+    mask = kpos <= qpos_ref[0][:, :1]
     logits = jnp.where(mask, logits, NEG_INF)
 
     m_prev = m_ref[...]
@@ -470,6 +472,9 @@ def paged_flash_attention(
         qp = jnp.pad(qp, ((0, 0), (0, Sp - S)), constant_values=-1)
     scale = sm_scale if sm_scale is not None else D**-0.5
 
+    # [B, Sp, LANES]: a (1, Sp) block of a [B, Sp] array would put 1 on
+    # the sublane dim, neither a multiple of 8 nor the full extent
+    qp = jnp.broadcast_to(qp[:, :, None], (B, Sp, LANES))
     qspec = pl.BlockSpec((1, 1, Sp, D), lambda b, h, j, bt: (b, h, 0, 0))
     kvspec = pl.BlockSpec(
         (1, 1, bs, D),
@@ -480,7 +485,7 @@ def paged_flash_attention(
         grid=(B, H, MB),
         in_specs=[
             qspec,
-            pl.BlockSpec((1, Sp), lambda b, h, j, bt: (b, 0)),
+            pl.BlockSpec((1, Sp, LANES), lambda b, h, j, bt: (b, 0, 0)),
             kvspec,
             kvspec,
         ],

@@ -1,6 +1,7 @@
 """Pallas-TPU fused 1x1-conv + BatchNorm kernels (ResNet hot path).
 
-Why this exists (PERF_NOTES.md profile): ResNet-50 training on TPU is
+Why this exists (step profile in PERF.md "Earlier chip findings"):
+ResNet-50 training on TPU is
 HBM-bandwidth-bound, and ~2/3 of the step is BatchNorm-adjacent
 elementwise/reduce passes over the widest activations — XLA cannot fuse
 the BN statistics pass or the normalize pass into its conv custom-calls.
@@ -359,8 +360,8 @@ def _xla_bwd(x, y, dy, w, scale, shift, dsum, dssq, *, prologue, relu,
              emit_stats):
     """Same math as the two Pallas backward kernels, in plain jnp.
 
-    Round-3 on-chip microbenches (artifacts/onchip_r3/microbench_*.log):
-    the Pallas FORWARD beats the unfused XLA sequence 1.0-2.5x at every
+    Microbenches on a v5e (previous toolchain — PERF.md "Earlier chip
+    findings"): the Pallas FORWARD beats the unfused XLA sequence 1.0-2.5x at every
     batch-256 ResNet shape, but the two-kernel Pallas backward re-streams
     x/y/dy once per kernel (2 full passes) and loses to XLA's fused
     backward at every shape (0.40-0.87x). So the composite keeps the
@@ -434,7 +435,7 @@ def _make_op(prologue, relu, emit_stats, out_dtype, interpret, bwd_impl):
         use_xla = bwd_impl == "xla"
         if not use_xla and _tiling.pallas_bwd_known_slow(
                 x.shape[0], x.shape[1], w.shape[1]):
-            # landmine guard (VERDICT r3 weak #4): this shape stalled
+            # landmine guard: this shape stalled
             # >10 min in the Pallas-backward path on the real chip;
             # fall back to the measured-faster XLA backward rather than
             # hang whoever flipped DTF_FUSED_BWD=pallas. Set

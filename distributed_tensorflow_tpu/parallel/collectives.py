@@ -31,15 +31,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.compat import axis_size as _compat_axis_size
-
 AxisNames = str | tuple[str, ...]
 Groups = Sequence[Sequence[int]] | None
 
 # The emulated ``groups=`` path below costs the FULL axis in wire traffic
 # (all_gather then mask) regardless of group size. Fine for the small
 # ad-hoc meshes it exists for; a silent O(axis) collective on a pod axis
-# would be a production footgun (VERDICT r2 Weak #5), so past this axis
+# would be a production footgun, so past this axis
 # size it is an error — structural subgroups belong on
 # ``mesh.factor_mesh_axis`` (true subgroup collectives, HLO-asserted).
 EMULATED_GROUP_AXIS_LIMIT = 8
@@ -208,4 +206,4 @@ def axis_index(axis: AxisNames):
 
 
 def axis_size(axis: str) -> int:
-    return _compat_axis_size(axis)
+    return lax.axis_size(axis)

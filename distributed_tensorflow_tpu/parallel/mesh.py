@@ -30,6 +30,7 @@ innermost axes to physically adjacent chips, so the highest-traffic collectives
 from __future__ import annotations
 
 import dataclasses
+import logging
 import math
 from typing import Mapping, Sequence
 
@@ -37,6 +38,8 @@ import jax
 import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh
+
+logger = logging.getLogger(__name__)
 
 # Canonical axis order, outermost → innermost.
 AXIS_NAMES: tuple[str, ...] = ("pipe", "data", "fsdp", "seq", "expert", "model")
@@ -224,10 +227,15 @@ def build_mesh(
             dev_array = mesh_utils.create_device_mesh(
                 shape, devices=np.asarray(devices, dtype=object)
             )
-        except (ValueError, AssertionError, NotImplementedError):
-            # Fallback for topologies mesh_utils cannot optimize (e.g. CPU
-            # fake devices or single-chip): plain row-major reshape.
-            # Collective placement is still correct, just not hop-optimal.
+            logger.info("mesh %s placed by mesh_utils.create_device_mesh",
+                        shape)
+        except (ValueError, AssertionError, NotImplementedError) as e:
+            # Fallback for device sets mesh_utils cannot place (a subset
+            # of a slice): plain row-major reshape. Collective placement
+            # is still correct, just not hop-optimal — so say so.
+            logger.warning("mesh %s: create_device_mesh refused (%s); "
+                           "row-major device order, not hop-optimal",
+                           shape, e)
             dev_array = np.asarray(devices, dtype=object).reshape(shape)
     return Mesh(dev_array, AXIS_NAMES)
 

@@ -65,7 +65,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from . import mesh as mesh_lib
 from . import sharding
-from ..utils.compat import shard_map
 
 
 def stage_param_specs(stage_params: Any) -> Any:
@@ -116,7 +115,7 @@ def pipeline_apply(
         then responsible for the matching manual collectives (Block's
         tp_shards psums). Specs must keep 'pipe' on the leading dim.
     rng: optional PRNG key enabling STOCHASTIC stage fns (dropout in
-        pipelined training — VERDICT r2 item 7). When given, stage_fn is
+        pipelined training). When given, stage_fn is
         called with two extra trailing args ``(mb_key, chunk_idx)``:
         ``mb_key = fold_in(rng, m)`` is unique per microbatch and
         ``chunk_idx = v·S + stage`` identifies the chunk, so the stage fn
@@ -227,7 +226,7 @@ def pipeline_apply(
         _pipeline_body, stage_fn, n_stages=n_stages, n_microbatches=M,
         n_virtual=V,
     )
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, x_spec, aux_specs, P()),

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import NEG_INF, attention_reference
-from ..utils.compat import shard_map
 from ..ops.flash_attention import flash_attention
 from . import mesh as mesh_lib
 
@@ -250,7 +249,7 @@ def sequence_parallel_attention(
         ),
     }[impl]
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec,

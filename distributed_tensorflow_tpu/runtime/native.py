@@ -1,14 +1,17 @@
 """Build + load the native runtime library (ctypes).
 
-Policy: compile on first use with g++ (-O3, no external deps), cache the
-.so beside the source, degrade silently to the Python fallbacks if a
-toolchain isn't present. The C ABI is small and stable — see
-native/dtf_runtime.cpp for the contract.
+Policy: compile on first use with g++ (-O3, no external deps), keep the
+.so under native/build/ keyed by a hash of its source (a copied or
+checked-out tree's mtimes say nothing, so a stale binary is never
+loaded), degrade with a warning to the Python fallbacks if a toolchain
+isn't present. The C ABI is small and stable — see native/dtf_runtime.cpp
+for the contract.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -19,7 +22,6 @@ logger = logging.getLogger(__name__)
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO, "native", "dtf_runtime.cpp")
 _BUILD_DIR = os.path.join(_REPO, "native", "build")
-_SO = os.path.join(_BUILD_DIR, "libdtf_runtime.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -60,31 +62,38 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     return lib
 
 
-def _build() -> str | None:
-    if not os.path.exists(_SRC):
-        return None
+def library_path(src: str, name: str) -> str:
+    """``native/build/lib<name>-<hash of the source>.so``: changing the
+    source changes the path, so a binary built from other source is
+    never picked up."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"lib{name}-{digest}.so")
+
+
+def build_library(src: str, name: str, link: tuple[str, ...] = ()) -> str:
+    """Path of the shared library built from ``src``, compiling it with
+    g++ unless this exact source was built before. Raises OSError /
+    subprocess.SubprocessError when the source or toolchain is missing
+    (callers fall back to their Python tier)."""
+    so = library_path(src, name)
+    if os.path.exists(so):
+        return so
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
     # per-process tmp name: concurrent first-use builds (multi-process jax,
     # pytest-xdist) each write their own file; os.replace is atomic, last
     # writer wins with a complete library either way
-    tmp = f"{_SO}.{os.getpid()}.tmp"
-    cmd = [
-        "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-        _SRC, "-o", tmp,
-    ]
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=180)
-        os.replace(tmp, _SO)
-    except (OSError, subprocess.SubprocessError) as e:
-        logger.warning("native runtime build failed (%s); using Python "
-                       "fallbacks", e)
-        return None
+        subprocess.run(
+            ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
+             src, "-o", tmp, *link],
+            check=True, capture_output=True, text=True, timeout=180)
+        os.replace(tmp, so)
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
-    return _SO
+    return so
 
 
 def load_library() -> ctypes.CDLL | None:
@@ -95,14 +104,16 @@ def load_library() -> ctypes.CDLL | None:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        so = _build()
-        if so is None:
+        try:
+            so = build_library(_SRC, "dtf_runtime")
+        except (OSError, subprocess.SubprocessError) as e:
+            logger.warning("native runtime build failed (%s); using Python "
+                           "fallbacks", e)
             return None
         try:
             lib = _configure(ctypes.CDLL(so))
-            # sanity-probe a pure function; a corrupt/stale .so fails here
-            # (AttributeError when a symbol is missing from an old build),
-            # and deleting it makes the next process rebuild cleanly
+            # sanity-probe a pure function; a corrupt .so fails here, and
+            # deleting it makes the next process rebuild cleanly
             if lib.dtf_crc32(b"123456789", 9) != 0xCBF43926:
                 raise OSError("crc self-test failed")
             _lib = lib

@@ -24,7 +24,7 @@ rtol 1e-4 and 64-step greedy equality in tests/test_serve.py.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import partial, wraps
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +80,13 @@ def decode_step(
     return logits[:, 0], cache
 
 
+def _bound(fn, model: Transformer):
+    """``partial(fn, model)`` under ``fn``'s own name: jax calls a jitted
+    bare partial ``jit__unknown``, and the name is how an XLA dump or a
+    profiler trace finds the step."""
+    return wraps(fn)(partial(fn, model))
+
+
 def jit_prefill(model: Transformer):
     """Compiled prefill; one compile per (prompt-bucket, cache shape).
 
@@ -89,13 +96,13 @@ def jit_prefill(model: Transformer):
     train/step.py donates the train state. Callers must rebind
     (``logits, cache = fn(params, cache, ...)``), never reuse the old
     pytree; the engine already does."""
-    return jax.jit(partial(prefill, model), donate_argnums=(1,))
+    return jax.jit(_bound(prefill, model), donate_argnums=(1,))
 
 
 def jit_decode_step(model: Transformer):
     """Compiled decode step; one compile per cache shape. The cache is
     donated (see jit_prefill)."""
-    return jax.jit(partial(decode_step, model), donate_argnums=(1,))
+    return jax.jit(_bound(decode_step, model), donate_argnums=(1,))
 
 
 # ---------------------------------------------------------------------------
@@ -204,18 +211,19 @@ def copy_block(
 def jit_paged_prefill_chunk(model: Transformer):
     """Compiled paged prefill chunk; the pool is donated (in-place
     scatter, no per-chunk pool copy — see jit_prefill)."""
-    return jax.jit(partial(paged_prefill_chunk, model), donate_argnums=(1,))
+    return jax.jit(_bound(paged_prefill_chunk, model),
+                   donate_argnums=(1,))
 
 
 def jit_paged_decode_step(model: Transformer):
     """Compiled paged decode step; the pool is donated."""
-    return jax.jit(partial(paged_decode_step, model), donate_argnums=(1,))
+    return jax.jit(_bound(paged_decode_step, model), donate_argnums=(1,))
 
 
 def jit_paged_verify_step(model: Transformer):
     """Compiled speculative verify step; the pool is donated. One
     compile per draft length K (tokens [num_slots, K+1])."""
-    return jax.jit(partial(paged_verify_step, model), donate_argnums=(1,))
+    return jax.jit(_bound(paged_verify_step, model), donate_argnums=(1,))
 
 
 def jit_copy_block():

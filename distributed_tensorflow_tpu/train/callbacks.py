@@ -24,6 +24,7 @@ from ..obs import flightrec as flightrec_lib
 from ..obs import goodput
 from ..obs.registry import Registry, default_registry
 from ..parallel import cluster
+from ..utils import flops as flops_lib
 
 logger = logging.getLogger(__name__)
 
@@ -195,10 +196,13 @@ class MetricsLogger(Callback):
         contract — every model's flops_per_example is fwd-only). The ×3
         training multiplier is applied by the shared MFU helper
         (obs/goodput.train_mfu), the one consumer site for all of
-        MetricsLogger, bench.py, and the ``mfu`` gauge."""
+        MetricsLogger, bench.py, and the ``mfu`` gauge. Where the running
+        device kind has no entry in utils/flops.PEAK_FLOPS_BY_KIND (a CPU
+        run) no ``mfu`` key is reported."""
         self.every_n = every_n
         self.batch_size = batch_size
         self.model_flops = model_flops_per_step
+        self._peak: float | None = None
         self.clock = clock
         self._t0: float | None = None
         self._step0 = 0
@@ -212,6 +216,8 @@ class MetricsLogger(Callback):
     def on_train_start(self, trainer):
         self._t0 = None
         self.last, self.last_step = {}, None
+        if self.model_flops:
+            self._peak = flops_lib.known_peak_flops()
 
     def note_pause(self, seconds: float) -> None:
         """Wall time spent OFF the train path between two steps (a
@@ -231,11 +237,12 @@ class MetricsLogger(Callback):
             fetched["steps_per_sec"] = steps_per_sec
             if self.batch_size:
                 fetched["examples_per_sec"] = steps_per_sec * self.batch_size
-            if self.model_flops:
+            if self.model_flops and self._peak:
                 # one MFU definition for log line, bench JSON, and gauge:
                 # obs/goodput.py applies the fwd+bwd multiplier
                 fetched["mfu"] = goodput.train_mfu(
-                    self.model_flops, steps_per_sec)
+                    self.model_flops, steps_per_sec,
+                    peak_per_chip=self._peak)
         self._t0, self._step0 = now, step
         self.last, self.last_step = fetched, step
         if self.history is not None:

@@ -28,7 +28,11 @@ reduction tree to the PROGRAM rather than the partitioning:
 
 A serial evaluator that walks the same chunks in the same order
 computes the identical float sequence, so equality is structural —
-``tests/test_distributed_eval.py`` proves it on the 8-device mesh.
+``tests/test_distributed_eval.py`` proves it on the 8-device mesh. The
+contract is per chunking: meshes that cut the batch into the same
+number of shards agree to the bit; a different shard count is a
+different chunk shape, XLA sums each chunk's float32 statistics in its
+own order, and the results agree to float32 rounding, not to the bit.
 
 The eval body receives params/model_state REPLICATED (``in_specs
 P()``): distributed eval parallelizes the *batch*; when the stored
@@ -54,7 +58,6 @@ from ..obs.registry import Registry, default_registry
 from ..parallel import mesh as mesh_lib
 from ..parallel import sharding as sh
 from ..utils import metrics as metrics_lib
-from ..utils.compat import shard_map
 from . import step as step_lib
 
 __all__ = [
@@ -96,8 +99,9 @@ def make_sharded_eval_step(eval_fn, mesh) -> Callable:
     def eval_step(state, batch):
         in_specs = (P(), P(), jax.tree.map(
             lambda x: sh.batch_spec(jnp.ndim(x)), batch))
-        fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=P(mesh_lib.BATCH_AXES), check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=P(mesh_lib.BATCH_AXES),
+                           check_vma=False)
         return fn(state.params, state.model_state, batch)
 
     return jax.jit(eval_step)

@@ -2,10 +2,11 @@
 
 MFU is computed from *analytic* model FLOPs — the model's own arithmetic
 count, not profiler-counted device FLOPs (which flatter recompute). Peak
-chip FLOP/s comes from a table keyed on jax's device_kind, overridable via
-config for new hardware.
+chip FLOP/s comes from one table keyed on jax's device_kind; a device that
+is not in it has no peak (``peak_flops_per_chip`` raises), so a CPU run
+reports no MFU.
 
-FRAMEWORK-WIDE CONTRACT (round-2 unification, VERDICT.md item 2): every
+FRAMEWORK-WIDE CONTRACT: every
 model's ``flops_per_example`` and every workload's
 ``WorkloadParts.flops_per_step`` are FORWARD-only. The fwd+bwd training
 multiplier (``train_flops_multiplier()``, ×3) is applied in exactly ONE
@@ -31,19 +32,31 @@ PEAK_FLOPS_BY_KIND: dict[str, float] = {
     "TPU v5p": 459e12,
     "TPU v6 lite": 918e12,  # trillium
     "TPU v6e": 918e12,
-    # CPU fake devices in tests: arbitrary small constant so MFU math runs.
-    "cpu": 1e12,
 }
 
 
-def peak_flops_per_chip(device: jax.Device | None = None) -> float:
+def known_peak_flops(device: jax.Device | None = None) -> float | None:
+    """Peak FLOP/s of ``device`` (default: the first jax device), or None
+    when its device_kind is not in the table."""
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "cpu")
+    kind = device.device_kind.lower()
     for key, val in PEAK_FLOPS_BY_KIND.items():
-        if kind.lower().startswith(key.lower()):
+        if kind.startswith(key.lower()):
             return val
-    return PEAK_FLOPS_BY_KIND.get(kind, 1e12)
+    return None
+
+
+def peak_flops_per_chip(device: jax.Device | None = None) -> float:
+    """Like ``known_peak_flops`` but an unknown kind is an error, never
+    a default: a utilization against an invented peak is not a number."""
+    peak = known_peak_flops(device)
+    if peak is None:
+        kind = (device or jax.devices()[0]).device_kind
+        raise ValueError(
+            f"no peak FLOP/s known for device_kind {kind!r}; add it to "
+            f"PEAK_FLOPS_BY_KIND or pass peak_per_chip explicitly")
+    return peak
 
 
 def mfu(model_flops_per_step: float, steps_per_sec: float, n_chips: int,

@@ -22,7 +22,8 @@ def default_config() -> RunConfig:
     return RunConfig(
         workload="resnet50_imagenet",
         # space_to_depth conv0 (the MLPerf TPU stem) + bf16 BN output:
-        # +28% images/sec over the naive stem/f32-BN config (PERF_NOTES.md).
+        # +28% images/sec over the naive stem/f32-BN config on a v5e
+        # (previous toolchain — PERF.md "Earlier chip findings").
         model=ResNetConfig(stem="space_to_depth"),
         mesh=MeshSpec(data=-1),
         data=DataConfig(

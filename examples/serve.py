@@ -45,7 +45,9 @@ def main(argv=None):
 
     from distributed_tensorflow_tpu import serve
     from distributed_tensorflow_tpu.models import transformer as tfm
+    from distributed_tensorflow_tpu.parallel import cluster
 
+    cluster.configure_compile_cache()
     cfg = tfm.TransformerConfig(
         vocab_size=256, max_len=128, num_layers=2, d_model=64, num_heads=4,
         d_ff=128, dropout=0.0, dtype="float32", causal=True, pre_ln=True,

@@ -65,6 +65,10 @@ import sys
 # must precede any jax import in this process
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=2")
+# persistent compile cache off, mirroring tests/conftest.py: this worker
+# exists to catch silently-wrong resumes, so it shares no state with
+# earlier runs
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -72,13 +76,6 @@ sys.path.insert(0, REPO)
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-# persistent compile cache: OPT-IN only, mirroring tests/conftest.py —
-# cache-deserialized executables corrupt donated buffers on this jaxlib
-# (silent NaN params on resume), which this worker exists to catch
-_cache_dir = os.environ.get("DTF_TEST_CACHE", "0")
-if _cache_dir != "0":
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.05)
 
 import numpy as np  # noqa: E402
 

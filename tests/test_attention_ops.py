@@ -132,11 +132,11 @@ def test_blockwise_fully_masked_rows_are_zero():
 
 
 def test_flash_env_block_fallback(monkeypatch):
-    # ADVICE r3: DTF_FLASH_BLOCK_Q/K are process-global trace-time knobs;
+    # DTF_FLASH_BLOCK_Q/K are process-global trace-time knobs;
     # a sweep value that doesn't divide some OTHER call site's seq len
     # must fall back to the 128 default with a warning, not raise.
     # 384 % 256 != 0 (and 256 < 384, so min() doesn't clamp it away),
-    # while the 128 fallback divides — the ADVICE finding's exact example
+    # while the 128 fallback divides
     q, k, v = make_qkv(jax.random.PRNGKey(7), B=1, H=2, S=384)
     ref = attention_reference(q, k, v)
     monkeypatch.setenv("DTF_FLASH_BLOCK_Q", "256")

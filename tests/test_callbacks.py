@@ -124,6 +124,28 @@ def test_metrics_logger_throughput_math():
     assert "mfu" not in ml.last  # no model_flops given
 
 
+def test_metrics_logger_reports_mfu_only_against_a_known_peak(monkeypatch):
+    """The train log carries no mfu on the CPU (no peak for that device
+    kind); on a kind the peak table knows, the shared helper's value."""
+    def last():
+        ml = cb.MetricsLogger(every_n=1, model_flops_per_step=1e9,
+                              clock=FakeClock(dt=1.0))
+        t = StubTrainer()
+        ml.on_train_start(t)
+        for step in (1, 2):
+            ml.on_step_end(t, step, {"loss": np.float32(1.0)})
+        return ml.last
+
+    assert "mfu" not in last() and "steps_per_sec" in last()
+    import jax
+
+    from distributed_tensorflow_tpu.utils import flops as flops_lib
+
+    monkeypatch.setitem(flops_lib.PEAK_FLOPS_BY_KIND, "cpu", 3e9)
+    # 1e9 fwd FLOPs x3 x 1 step/s over n chips x 3e9 peak
+    assert last()["mfu"] == pytest.approx(1.0 / jax.device_count())
+
+
 def test_metrics_logger_cadence_and_history():
     ml = cb.MetricsLogger(every_n=3, history=True, clock=FakeClock())
     t = StubTrainer()

@@ -7,14 +7,13 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from distributed_tensorflow_tpu.utils.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from distributed_tensorflow_tpu.parallel import collectives as col
 
 
 def smap(mesh, fn, in_specs, out_specs):
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
 def test_all_reduce_sum(mesh8):
@@ -138,7 +137,7 @@ def test_subgroup_collective_on_2d_mesh(mesh_dp4_tp2):
     def fn(v):
         return col.all_reduce(v, "model")
 
-    out = shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh_dp4_tp2, in_specs=P("data", "model"), out_specs=P("data", "model")
     )(x)
     expected = np.asarray(x).reshape(4, 2).sum(1, keepdims=True).repeat(2, 1)
@@ -147,18 +146,18 @@ def test_subgroup_collective_on_2d_mesh(mesh_dp4_tp2):
 
 def test_factor_mesh_axis_numerics(mesh8):
     """Factored sub-axis psum == emulated grouped all_reduce with the
-    matching contiguous groups (mesh.factor_mesh_axis API, VERDICT item 10)."""
+    matching contiguous groups (mesh.factor_mesh_axis API)."""
     from distributed_tensorflow_tpu.parallel import factor_mesh_axis
 
     x = jnp.arange(8.0)
     groups = [[0, 1], [2, 3], [4, 5], [6, 7]]
-    emulated = shard_map(
+    emulated = jax.shard_map(
         lambda v: col.all_reduce(v, "data", groups=groups),
         mesh=mesh8, in_specs=P("data"), out_specs=P("data"),
     )(x)
 
     sub = factor_mesh_axis(mesh8, "data", {"outer": 4, "inner": 2})
-    factored = shard_map(
+    factored = jax.shard_map(
         lambda v: col.all_reduce(v, "inner"),
         mesh=sub, in_specs=P(("outer", "inner")), out_specs=P(("outer", "inner")),
     )(x)
@@ -172,7 +171,7 @@ def test_factored_axis_avoids_full_gather(mesh8):
 
     x = jnp.arange(8.0)
     sub = factor_mesh_axis(mesh8, "data", {"outer": 4, "inner": 2})
-    factored = jax.jit(shard_map(
+    factored = jax.jit(jax.shard_map(
         lambda v: col.all_reduce(v, "inner"),
         mesh=sub, in_specs=P(("outer", "inner")), out_specs=P(("outer", "inner")),
     ))
@@ -188,7 +187,7 @@ def test_factored_axis_avoids_full_gather(mesh8):
     assert len(first_group.strip("{}").split(",")) == 2, first_group
 
     groups = [[0, 1], [2, 3], [4, 5], [6, 7]]
-    emulated = jax.jit(shard_map(
+    emulated = jax.jit(jax.shard_map(
         lambda v: col.all_reduce(v, "data", groups=groups),
         mesh=mesh8, in_specs=P("data"), out_specs=P("data"),
     ))
@@ -210,7 +209,7 @@ def test_factor_mesh_axis_validation(mesh8):
 
 
 def test_emulated_groups_warn_and_cap(mesh8, monkeypatch):
-    """The emulated groups= path is fenced (VERDICT r2 Weak #5): it warns
+    """The emulated groups= path is fenced: it warns
     on every use, and past EMULATED_GROUP_AXIS_LIMIT it refuses outright,
     pointing at factor_mesh_axis."""
     import re

@@ -83,20 +83,30 @@ def test_sharded_eval_bit_identical_to_serial(devices):
 
 
 def test_sharded_eval_same_result_across_meshes(devices):
-    """The reduction tree is pinned by the program, not the mesh: dp8
-    and dp4×tp2 evaluate to the same bits for the same weights."""
-    meshes = [build_mesh(MeshSpec(data=8), devices[:8]),
-              build_mesh(MeshSpec(data=4, model=2), devices[:8])]
+    """The reduction tree is pinned by the program — the per-shard chunk
+    — not by the rest of the mesh: meshes that cut the batch into the
+    same number of shards (dp4×tp2 and dp2×fsdp2×tp2) evaluate to the
+    same bits. A different shard count (dp8) is a different chunking, so
+    XLA sums each chunk's float32 statistics in another order: counts
+    stay exact, float sums agree to a few float32 ulps."""
+    meshes = [build_mesh(MeshSpec(data=4, model=2), devices[:8]),
+              build_mesh(MeshSpec(data=2, fsdp=2, model=2), devices[:8]),
+              build_mesh(MeshSpec(data=8), devices[:8])]
     batches = _batches(2, 128)
     results = []
     for mesh in meshes:
         eval_fn, state = _mlp_fixture(mesh, hidden=(64, 64))
         ev = ShardedEvaluator(eval_fn, mesh, registry=obs.Registry())
-        results.append(ev.run(state, iter(batches), 2))
-    for k in results[0]:
-        a = np.asarray(results[0][k], np.float64)
-        b = np.asarray(results[1][k], np.float64)
-        assert a.tobytes() == b.tobytes(), f"{k} differs across meshes"
+        results.append({k: np.asarray(v, np.float64) for k, v in
+                        ev.run(state, iter(batches), 2).items()})
+    same_chunks, same_chunks_2, other_chunks = results
+    for k, a in same_chunks.items():
+        assert a.tobytes() == same_chunks_2[k].tobytes(), \
+            f"{k} differs across meshes with the same batch shards"
+        np.testing.assert_allclose(
+            other_chunks[k], a, atol=0,
+            rtol=0 if k == "count" else 4 * np.finfo(np.float32).eps,
+            err_msg=f"{k} differs across shard counts beyond rounding")
 
 
 def test_eval_obs_surface(devices):
@@ -244,3 +254,37 @@ def test_runner_eval_paths_use_sharded_evaluator(devices, tmp_path):
     again = workloads.evaluate_from_checkpoint(cfg, mod.build)
     assert again["step"] == 6
     assert again["loss"] == pytest.approx(result.eval_metrics["loss"])
+
+
+def test_flash_attention_model_evaluates_sharded(devices, caplog):
+    """The transformer's flash call wraps itself in shard_map on a mesh
+    (a Mosaic kernel cannot be partitioned by GSPMD); inside the
+    evaluator's own shard_map it must call the kernel directly instead of
+    nesting — otherwise every multi-chip eval is demoted to the flat
+    path. Interpret-mode kernel here; same values as dense attention."""
+    import dataclasses
+    import logging
+
+    from distributed_tensorflow_tpu.models import transformer as tfm
+
+    mesh = build_mesh(MeshSpec(data=4, model=2), devices[:8])
+    cfg = tfm.TransformerConfig(
+        vocab_size=128, max_len=256, num_layers=1, d_model=32, num_heads=2,
+        d_ff=64, dropout=0.0, dtype="float32", causal=True, pre_ln=True,
+        attention_impl="flash")
+    rng = np.random.RandomState(0)
+    batches = [{"input_ids": rng.randint(0, 128, (8, 256)).astype(np.int32)}]
+    totals = {}
+    for impl in ("flash", "dense"):
+        model = tfm.Transformer(
+            dataclasses.replace(cfg, attention_impl=impl), mesh)
+        state, _ = init_train_state(
+            tfm.make_init_fn(model, 256), optax.sgd(0.1), mesh,
+            jax.random.PRNGKey(0), param_rules=tfm.transformer_rules(cfg))
+        ev = ShardedEvaluator(tfm.lm_eval_fn(model), mesh,
+                              registry=obs.Registry())
+        with caplog.at_level(logging.WARNING):
+            totals[impl] = ev.run(state, iter(batches), 1)
+    assert "falling back" not in caplog.text, caplog.text
+    np.testing.assert_allclose(totals["flash"]["loss_sum"],
+                               totals["dense"]["loss_sum"], rtol=1e-5)

@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from distributed_tensorflow_tpu.utils.compat import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_tensorflow_tpu.ops import embedding as emb
@@ -56,7 +55,7 @@ def test_mod_sharded_lookup_grad_matches_take(mesh_tp4):
 
 def test_range_sharded_lookup_matches_take(mesh_tp4):
     table, ids = _table_and_ids(2)
-    got = shard_map(
+    got = jax.shard_map(
         lambda i, t: emb.range_sharded_lookup(i, t, mesh_lib.MODEL),
         mesh=mesh_tp4,
         in_specs=(P(mesh_lib.BATCH_AXES), P(mesh_lib.MODEL, None)),
@@ -70,7 +69,7 @@ def test_batch_sharded_lookup_matches_take(mesh_tp4):
     # batch sharded over the SAME axis as the table (all_to_all-style path)
     table, ids = _table_and_ids(3, n_ids=32)
     mod = emb.to_mod_sharded(table, mesh_tp4)
-    got = shard_map(
+    got = jax.shard_map(
         lambda i, t: emb.batch_sharded_lookup(i, t, mesh_lib.MODEL),
         mesh=mesh_tp4,
         in_specs=(P(mesh_lib.MODEL), P(mesh_lib.MODEL, None)),

@@ -1,4 +1,4 @@
-"""FLOPs/MFU accounting contract (VERDICT round-1 item 2, SURVEY.md §6).
+"""FLOPs/MFU accounting contract (SURVEY.md §6).
 
 Every workload's declared ``flops_per_step`` must be FORWARD-only model
 arithmetic. Oracle: XLA's own cost analysis of the jitted *forward* (loss)
@@ -50,8 +50,7 @@ def test_declared_flops_are_forward_only(name):
     lowered = jax.jit(
         lambda p, m, b: parts.loss_fn(p, m, b, rng)[0]
     ).lower(params, mstate, batch)
-    from distributed_tensorflow_tpu.utils.compat import cost_analysis_dict
-    xla_fwd = cost_analysis_dict(lowered.compile()).get("flops")
+    xla_fwd = lowered.compile().cost_analysis().get("flops")
     if not xla_fwd or xla_fwd != xla_fwd:  # backend returned none/NaN
         pytest.skip("cost_analysis unavailable on this backend")
 
@@ -81,3 +80,27 @@ def test_train_multiplier_single_site():
     assert sorted(hits) == [
         "distributed_tensorflow_tpu/obs/goodput.py",
     ], hits
+
+
+def test_unknown_device_kind_has_no_peak():
+    """A device that is not in the peak table is an error, not a default:
+    no utilization is ever computed against an invented peak (the CPU
+    rig's device_kind is "cpu")."""
+    import jax
+    import pytest
+
+    from distributed_tensorflow_tpu.obs import goodput
+    from distributed_tensorflow_tpu.utils import flops as flops_lib
+
+    assert flops_lib.known_peak_flops(jax.devices()[0]) is None
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        flops_lib.peak_flops_per_chip()
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        goodput.train_mfu(1e12, 1.0)
+    # arithmetic that needs a peak passes it explicitly
+    assert goodput.train_mfu(1e12, 1.0, n_chips=1, peak_per_chip=6e12) == 0.5
+
+    class V5e:
+        device_kind = "TPU v5 lite"
+
+    assert flops_lib.peak_flops_per_chip(V5e()) == 197e12

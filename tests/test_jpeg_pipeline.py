@@ -118,7 +118,7 @@ def test_augment_ops_oracles():
 
 @pytest.mark.slow
 def test_decode_throughput_host_only(tmp_path):
-    """VERDICT round-1 item 6 'done' probe: the threaded decode+augment
+    """The threaded decode+augment
     path must sustain a real per-core rate (measured ~500 img/s/core at
     256->224 on this container's single core — a 16-core TPU-VM host
     extrapolates to ~8k img/s, past the ~2.5k img/s bench step rate).
@@ -218,7 +218,7 @@ def test_imagefolder_converter_roundtrip(tmp_path):
 def test_converter_limit_without_shuffle_keeps_all_classes(tmp_path):
     """--limit + --no-shuffle must not truncate the label-major list to
     the first class(es): the subset is interleaved round-robin so every
-    class stays represented (ADVICE r2)."""
+    class stays represented."""
     import json
     import sys
 

@@ -997,8 +997,8 @@ def test_mesh_axis_silent_when_vocab_unreadable(tmp_path):
 
 def test_seam_bypass_carve_outs_and_scope():
     body = '''
+        import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from distributed_tensorflow_tpu.utils.compat import shard_map
 
 
         def attn_rules():
@@ -1006,8 +1006,8 @@ def test_seam_bypass_carve_outs_and_scope():
 
 
         def island(mesh, x):
-            f = shard_map(lambda a: a, mesh=mesh,
-                          in_specs=P("data"), out_specs=P("data"))
+            f = jax.shard_map(lambda a: a, mesh=mesh,
+                              in_specs=P("data"), out_specs=P("data"))
             return f(x)
 
 

@@ -433,7 +433,7 @@ def test_restore_none_when_empty(mesh8, tmp_path):
 
 def test_manifest_written_and_verified(mesh8, tmp_path):
     """Production saves stamp each step dir with a CRC-trailered
-    MANIFEST.dtf via the native IO path (VERDICT round-1 item 8), and
+    MANIFEST.dtf via the native IO path, and
     restore refuses a checkpoint whose shards don't match it."""
     import os
 

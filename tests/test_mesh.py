@@ -134,7 +134,7 @@ def test_all_devices_used(mesh_dp4_tp2):
     assert ids == sorted(d.id for d in jax.devices()[:8])
 
 
-# ---- hybrid ICI x DCN mesh (SURVEY.md §2d; VERDICT round-1 item 5) ----
+# ---- hybrid ICI x DCN mesh (SURVEY.md §2d) ----
 
 def test_hybrid_mesh_dcn_data_blocks(devices):
     """dcn_data=2 over 8 devices: the data axis splits into 2 DCN blocks

@@ -418,7 +418,7 @@ def test_pipeline_apply_rejects_param_specs_on_degenerate_mesh(devices):
 
 @pytest.mark.slow
 def test_pipelined_dropout_schedule_independent(devices):
-    """Dropout through the pipeline (VERDICT r2 item 7): the per-
+    """Dropout through the pipeline: the per-
     (microbatch, global-layer, batch-shard) key derivation must be
     independent of the S>1 (S, V) schedule decomposition — pipe=2/V=1,
     pipe=2/V=2 and pipe=4/V=1 draw the SAME masks at a fixed batch

@@ -13,6 +13,7 @@ from distributed_tensorflow_tpu.runtime import (
     read_payload, write_payload,
 )
 from distributed_tensorflow_tpu.runtime import io as io_lib
+from distributed_tensorflow_tpu.runtime import native
 
 
 @pytest.fixture(scope="module")
@@ -35,6 +36,19 @@ def record_file(tmp_path):
 
 def test_native_builds(lib):
     assert available()
+
+
+def test_library_is_keyed_by_its_source(tmp_path):
+    """After a copy or a checkout mtimes say nothing, so the built library
+    is named by a hash of its source: another source, another path — a
+    stale binary is never loaded."""
+    src = tmp_path / "k.cpp"
+    src.write_text('extern "C" int k() { return 1; }\n')
+    first = native.library_path(str(src), "k")
+    assert first == native.library_path(str(src), "k")
+    assert os.path.basename(first).startswith("libk-")
+    src.write_text('extern "C" int k() { return 2; }\n')
+    assert native.library_path(str(src), "k") != first
 
 
 def test_permutation_parity(lib):

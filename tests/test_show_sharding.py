@@ -49,8 +49,8 @@ def test_pipelined_plan_uses_explicit_specs():
 
 def test_wildcard_mesh_with_nondividing_fixed_axis():
     """A -1 wildcard with a fixed axis that doesn't divide 8 (e.g.
-    pipe=3) must still size a representable fake mesh (ADVICE r2:
-    previously max(8, 3)=8, which 3 doesn't divide -> build_mesh fail)."""
+    pipe=3) must still size a representable fake mesh (max(8, 3)=8,
+    which 3 doesn't divide, would fail build_mesh)."""
     out = _run(
         "bert_pretrain", "--mesh.pipe=3", "--mesh.data=-1",
         "--model.num_layers=3", "--model.d_model=32", "--model.num_heads=4",

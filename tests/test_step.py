@@ -148,7 +148,7 @@ def test_fsdp_auto_sharding(devices):
 def test_grads_finite_free_via_grad_norm(devices):
     """When grad-norm/clipping is already on, grads_finite derives from
     the global norm at zero extra cost — same-step NaN signal without
-    the per-leaf isfinite pass (VERDICT r2 Weak #4)."""
+    the per-leaf isfinite pass."""
     mesh = build_mesh(MeshSpec(data=2), devices[:2])
     tx = optax.sgd(0.1)
 

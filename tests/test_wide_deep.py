@@ -268,7 +268,7 @@ class TestCTRRecords:
         import numpy as np
 
         # no eval_dataset given: eval drew from the training file, so the
-        # metric is tagged train_auc (ADVICE r3) — the honest label
+        # metric is tagged train_auc — the honest label
         assert np.isfinite(result.eval_metrics["train_auc"])
         assert "auc" not in result.eval_metrics
 

@@ -449,7 +449,7 @@ def test_convergence_demo_machinery(tmp_path):
     """tools/convergence_demo.py end to end at smoke scale: real digit
     scans -> JPEG records -> run_workload (decode+augment+train+ckpt) ->
     eval_workload restore on the held-out pair. The committed 400-step
-    run reaches 98.4% (PERF_NOTES.md); here 20 steps must beat 3x chance
+    run reaches 98.4%; here 20 steps must beat 3x chance
     and the machinery must produce valid JSON."""
     import json
     import subprocess
@@ -483,7 +483,7 @@ def test_convergence_demo_ctr_machinery():
     """tools/convergence_demo_ctr.py end to end at smoke scale:
     teacher-labeled Criteo-format TSV -> make_ctr_records.py -> ctr:
     training through the native loader -> held-out AUC. The committed
-    600-step run reaches AUC 0.77 (PERF_NOTES.md); here 40 steps must
+    600-step run reaches AUC 0.77; here 40 steps must
     clear a weak above-chance gate and emit valid JSON."""
     import json
     import subprocess
@@ -505,7 +505,7 @@ def test_convergence_demo_ctr_machinery():
 def test_convergence_demo_mlm_machinery():
     """tools/convergence_demo_mlm.py at smoke scale: repo .md prose ->
     byte token files -> tokens_mlm: training -> held-out masked-byte
-    accuracy. The committed 1600-step run reaches 0.50 (PERF_NOTES.md);
+    accuracy. The committed 1600-step run reaches 0.50;
     here 60 steps must beat the unigram floor and emit valid JSON."""
     import json
     import subprocess

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Ablation driver for the ResNet-50 bench (PERF_NOTES.md evidence).
+"""Ablation driver for the ResNet-50 bench.
 
 Thin wrapper: each variant is a `bench.py` run with BENCH_* env overrides,
 so timing methodology, FLOPs accounting (fwd-only × train multiplier), and
@@ -52,7 +52,6 @@ def main() -> None:
     for name in names:
         env = {k: v for k, v in os.environ.items() if k not in _KNOBS}
         env.update(VARIANTS[name])
-        env["BENCH_SKIP_PROBE"] = "1"  # one sweep, one relay; skip per-run probes
         proc = subprocess.run(
             [sys.executable, BENCH], env=env, capture_output=True, text=True
         )

@@ -42,8 +42,6 @@ def main() -> None:
 
     from distributed_tensorflow_tpu.utils import benchmarking as bm
 
-    bm.fall_back_to_cpu_if_unreachable(log=log)
-    bm.honor_env_platform()
     import numpy as np
 
     from distributed_tensorflow_tpu.models import wide_deep as wd
@@ -125,28 +123,29 @@ def main() -> None:
     # shared MFU helper (obs/goodput.py): applies the fwd+bwd multiplier
     from distributed_tensorflow_tpu.obs import goodput
 
-    peak = flops_lib.peak_flops_per_chip(devices[0])
+    # no peak for this device kind (the explicit CPU run) → no MFU
+    peak = flops_lib.peak_flops_per_chip(devices[0]) if on_tpu else None
     mfu = goodput.train_mfu(
         wd.flops_per_example(cfg) * global_batch, steps_per_sec,
         n_chips=n_chips, peak_per_chip=peak,
-    )
+    ) if peak else None
     log(f"steps/sec={steps_per_sec:.3f} "
         f"examples/sec/chip={examples_per_sec_per_chip:.0f} "
-        f"embed-traffic={embed_gbps:.1f} GB/s MFU={mfu:.4f}")
+        f"embed-traffic={embed_gbps:.1f} GB/s MFU={mfu}")
 
     # vs_baseline for THIS family is achieved-vs-spec HBM bandwidth, not
     # MFU/0.50: the module docstring's own roofline argument — comparing
     # a gather/scatter-bound workload's MFU to the ResNet MXU target is
-    # a misleading datum (ADVICE r4). 819 GB/s = v5e HBM spec
-    # (tools/bench_hbm.py); on the CPU fallback the spec doesn't apply
-    # and the field reports 0.0 (full_size_model already flags the row).
+    # a misleading datum. 819 GB/s = v5e HBM spec (tools/bench_hbm.py);
+    # under the explicit CPU request the spec doesn't apply and the
+    # field reports 0.0 (full_size_model already flags the row).
     print(json.dumps({
         "metric": "wide_deep_examples_per_sec_per_chip",
         "value": round(examples_per_sec_per_chip, 1),
         "unit": "examples/sec/chip",
         "vs_baseline": round(embed_gbps / 819.0, 4) if on_tpu else 0.0,
         "vs_baseline_basis": "embed_traffic_gbps / 819 GB/s v5e HBM spec",
-        "mfu": round(mfu, 4),
+        "mfu": round(mfu, 4) if mfu else None,
         "platform": platform,
         "n_chips": n_chips,
         "global_batch": global_batch,

@@ -88,8 +88,8 @@
 #      bound for resident decoders, and (d) the same-run speculation
 #      win: chaos throughput must beat the non-spec gather baseline
 #      measured in the same process (--min-speedup — the bar is LOW
-#      because the CI preset is tiny and noisy; the honest numbers
-#      live in PERF_NOTES.md)
+#      because the CI preset is tiny and noisy; it is a CPU
+#      wall-clock ratio, not a device number)
 #   7d. tools/bench_trend.py — serve perf-regression sentinel
 #      (ISSUE 20): same freshest-pair trend as 4b, over the serve
 #      chaos bench — when a previous run left

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Convergence demonstration on REAL decoded JPEG data (VERDICT r2 item 4).
+"""Convergence demonstration on REAL decoded JPEG data.
 
 Pushes a real image-classification dataset through the framework's whole
 production path: JPEG record files -> JpegClassificationDataset decode +

@@ -34,13 +34,6 @@ import tempfile
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from distributed_tensorflow_tpu.utils.benchmarking import (  # noqa: E402
-    fall_back_to_cpu_if_unreachable, honor_env_platform,
-)
-
-honor_env_platform()
-fall_back_to_cpu_if_unreachable(log=lambda m: print(m, file=sys.stderr))
-
 VOCAB, MASK = 261, 260  # byte tokenizer: 256 bytes + 5 specials
 # --long configuration, defined ONCE (CLI args + artifact stamp share it)
 LONG_MESH_SEQ, LONG_SEQ_IMPL = 4, "ring"

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Measure the pipelined BERT-base train step's per-device memory across
-the (M microbatches, S stages, V virtual) grid — the VERDICT r2 item 3
+the (M microbatches, S stages, V virtual) grid — the
 evidence for "GPipe(+interleave)+remat fits the pod shapes" vs needing a
 hand-scheduled 1F1B.
 
@@ -23,7 +23,7 @@ Usage:  python tools/pipeline_memory_analysis.py [--quick]
 
 Prints one JSON line per config:
   {"S":..,"V":..,"M":..,"per_device_bytes":..,"gib":..,"fits_v5e":..}
-plus a markdown table on stderr for PERF_NOTES.md.
+plus a markdown table on stderr.
 """
 
 import argparse
@@ -110,12 +110,12 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="tiny smoke grid (tests)")
     ap.add_argument("--pod", action="store_true",
-                    help="16-device pod-shape grid (VERDICT r3 item 7): "
+                    help="16-device pod-shape grid: "
                          "BERT-base over pipe=4 x data=4, global batch "
                          "1024 — the pod-like M/S/V statement")
     ap.add_argument("--check", metavar="JSON",
                     help="single-config estimate for the runner's "
-                         "pipeline-memory guard (VERDICT r4 item 8a): "
+                         "pipeline-memory guard: "
                          '{"model": <TransformerConfig dict>, "S":, '
                          '"V":, "M":, "batch":, "seq":, "mlm":}. '
                          "Prints ONE JSON row.")

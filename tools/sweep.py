@@ -12,16 +12,15 @@ curves connecting them. This harness runs the matrix and produces them:
   steady-state ``train_step_seconds`` histogram (first step — compile —
   excluded), per-cell goodput fraction from the ledger counters, MFU
   through ``obs/goodput.train_mfu`` (THE multiplier site; dtflint pins
-  it) — all isolated per cell with ``Registry.delta`` snapshots, never
-  a mid-run ``reset()``;
+  it) where the device kind has a known peak — all isolated per cell
+  with ``Registry.delta`` snapshots, never a mid-run ``reset()``;
 - a distributed-eval pass per cell (train/evaluation.py: batch sharded
   over the mesh, host-side fixed-order reduction) so the eval surface
   is exercised on every mesh shape the sweep claims works;
 - a schema-versioned ``dtf-scaling-1`` report (obs/scaling.py) where
   EVERY cell is provenance-stamped (backend, device kind/count, mesh
-  shape, git sha, hostname) — after BENCH_r02–r05 silently recorded
-  CPU fallbacks as if they were TPU rows, no number leaves this tool
-  without its platform context;
+  shape, git sha, hostname) — no number leaves this tool without its
+  platform context;
 - per-axis scaling efficiency vs the 1-device baseline and an enforced
   gate: 8-dev dp must hold ≥ 0.8 × ideal. On the host-shared CPU rig
   the ideal is flat throughput (8 fake devices partition ONE host's
@@ -179,12 +178,13 @@ def run_cell(sweep_name: str, cell_name: str, steps: int,
     if topo is not None:
         cell["pods"] = topo.num_pods
         cell["devices_per_pod"] = topo.devices_per_pod
-    if parts.flops_per_step:
-        # fwd-only count; the shared site applies the fwd+bwd multiplier
+    peak = flops_lib.known_peak_flops(devices[0])
+    if parts.flops_per_step and peak:
+        # fwd-only count; the shared site applies the fwd+bwd multiplier.
+        # No peak for this device kind (the CPU rig) → no mfu in the cell
         cell["mfu"] = round(goodput.train_mfu(
             parts.flops_per_step, steps_per_sec, n_chips=n_devices,
-            peak_per_chip=flops_lib.peak_flops_per_chip(devices[0]),
-            registry=registry,
+            peak_per_chip=peak, registry=registry,
         ), 6)
     if eval_batches and parts.eval_fn is not None \
             and parts.eval_dataset_fn is not None:
@@ -231,9 +231,6 @@ def main(argv=None) -> int:
                     help=f"CI mode: mlp × {DRYRUN_CELLS}, 8 steps")
     args = ap.parse_args(argv)
 
-    from distributed_tensorflow_tpu.utils import benchmarking as bm
-
-    bm.honor_env_platform()
     import jax
 
     from distributed_tensorflow_tpu.obs import scaling
