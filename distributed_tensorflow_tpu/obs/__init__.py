@@ -31,7 +31,7 @@ from .registry import (  # noqa: F401
     default_registry,
     log_buckets,
 )
-from .trace import Span, Tracer, default_tracer, span  # noqa: F401
+from .trace import Span, Tracer, default_tracer  # noqa: F401
 from .export import JsonlLogger, render, serve_http  # noqa: F401
 from .flightrec import (  # noqa: F401
     EVENT_KINDS,

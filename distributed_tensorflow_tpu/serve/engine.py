@@ -43,6 +43,7 @@ import numpy as np
 
 from ..models.transformer import Transformer, TransformerConfig, make_init_fn
 from ..obs import flightrec as flightrec_lib
+from ..obs import trace as trace_lib
 from ..obs.registry import Registry
 from . import decode as decode_lib
 from . import sampling
@@ -76,9 +77,11 @@ class StepStats:
     tokens: list[tuple[int, int]] = dataclasses.field(default_factory=list)
     finished: list[int] = dataclasses.field(default_factory=list)
     #: host wall-clock split of this step: prefill phase (all admits,
-    #: compile-warm), decode phase (one fused step), and the whole call.
-    #: Timings block on sampled-token transfer, so they are real compute
-    #: latencies, not dispatch times.
+    #: compile-warm), decode phase (one fused step), and the whole call —
+    #: the durations of the step's ``serve.step.admit`` + ``.prefill``,
+    #: ``.decode`` and ``serve.step`` spans (obs/trace.py). Timings block
+    #: on sampled-token transfer, so they are real compute latencies, not
+    #: dispatch times.
     wall_s: float = 0.0
     prefill_s: float = 0.0
     decode_s: float = 0.0
@@ -117,6 +120,7 @@ class ServeEngine:
         clock: Callable[[], float] = time.perf_counter,
         flightrec=None,
         reqtrace=None,
+        tracer=None,
     ):
         if not cfg.causal:
             raise ValueError("ServeEngine requires a causal (decoder) model")
@@ -198,6 +202,12 @@ class ServeEngine:
         # engine's drain event, so the postmortem timeline interleaves
         self.flightrec = (flightrec if flightrec is not None
                           else flightrec_lib.default_recorder())
+        #: span ring for the step's host phases and each request's
+        #: queue/prefill/decode phases (obs/trace.py; the names and counts
+        #: are a contract, docs/observability.md "Span tracing"); present
+        #: in every run, defaults to the process ring
+        self.tracer = (tracer if tracer is not None
+                       else trace_lib.default_tracer())
         #: per-request span ledger (obs/reqtrace.py) shared with the
         #: scheduler; None = untraced. Only requests that entered with a
         #: router trace id (rid) emit spans — direct submissions don't.
@@ -342,48 +352,48 @@ class ServeEngine:
         decode-ready slot by one token. Returns per-step stats and
         records them into ``self.registry``."""
         stats = StepStats()
-        t0 = self.clock()
-        expired = self.sched.expire()
-        for req in expired:
-            self._observe_finish(req, stats)
-        if expired:
-            self._reconcile_slots()
-        if self.paged:
-            self._gate_reserved = 0  # fresh admit cycle
-        placed = self.sched.admit()
-        for slot, req in placed:
-            stats.admitted += 1
-            self._m_admitted.inc()
-            if req.preemptions == 0:
-                self._m_queue_wait.observe(req.t_admit - req.t_submit)
+        tracer = self.tracer
+        with tracer.span("serve.step") as sp:
+            with tracer.span("admit") as admit:
+                expired = self.sched.expire()
+                for req in expired:
+                    self._observe_finish(req, stats)
+                if expired:
+                    self._reconcile_slots()
+                if self.paged:
+                    self._gate_reserved = 0  # fresh admit cycle
+                placed = self.sched.admit()
+                for slot, req in placed:
+                    stats.admitted += 1
+                    self._m_admitted.inc()
+                    if req.preemptions == 0:
+                        self._m_queue_wait.observe(req.t_admit - req.t_submit)
+                    if self.paged:
+                        self._begin_paged(slot, req)
+            stats.prefill_s = admit.duration
+            # occupancy counts every slot WORKING this step — decoding,
+            # mid-chunked-prefill, or just admitted (even if its first
+            # token finishes it before the step ends); measured here,
+            # after admission and before any delivery, so a max_new=1
+            # stream still reads as a full batch
+            stats.occupancy = (
+                len(self.sched.active_slots()) / self.sched.num_slots
+            )
             if self.paged:
-                self._begin_paged(slot, req)
-        # occupancy counts every slot WORKING this step — decoding,
-        # mid-chunked-prefill, or just admitted (even if its first
-        # token finishes it before the step ends); measured here, after
-        # admission and before any delivery, so a max_new=1 stream
-        # still reads as a full batch
-        stats.occupancy = (
-            len(self.sched.active_slots()) / self.sched.num_slots
-        )
-        if self.paged:
-            # one chunk per pending slot per step — the interleave bound
-            for slot in sorted(self._pending):
-                if slot in self._pending:  # preemption may drop peers
-                    self._paged_prefill_step(slot, stats)
-        else:
-            for slot, req in placed:
-                self._do_prefill(slot, req, stats)
-        t1 = self.clock()
-        active = self.sched.active_slots()
-        if self.paged:
-            active = [s for s in active if s not in self._pending]
-        if active:
-            self._do_decode(active, stats)
-        t2 = self.clock()
-        stats.prefill_s = t1 - t0
-        stats.decode_s = t2 - t1
-        stats.wall_s = t2 - t0
+                # one chunk per pending slot per step — the interleave
+                # bound
+                for slot in sorted(self._pending):
+                    if slot in self._pending:  # preemption may drop peers
+                        self._paged_prefill_step(slot, stats)
+            else:
+                for slot, req in placed:
+                    self._do_prefill(slot, req, stats)
+            active = self.sched.active_slots()
+            if self.paged:
+                active = [s for s in active if s not in self._pending]
+            if active:
+                self._do_decode(active, stats)
+        stats.wall_s = sp.duration
         self._m_step.observe(stats.wall_s)
         if stats.admitted or stats.prefill_chunks:
             self._m_prefill.observe(stats.prefill_s)
@@ -630,28 +640,42 @@ class ServeEngine:
         sample the first token, publish the prompt's blocks for prefix
         reuse, and hand the slot to the decode phase."""
         req = self.sched.slots[slot]
+        with self.tracer.span("prefill", key=req.uid) as sp:
+            self._prefill_chunk(slot, req, stats, sp)
+        stats.prefill_s += sp.duration
+
+    def _prefill_chunk(self, slot: int, req: Request, stats: StepStats,
+                       sp) -> None:
+        tracer = self.tracer
         toks = self._ptoks[slot]
         T = len(toks)
         start = self._pending[slot]
         end = min(start + self.prefill_chunk, T)
-        self._ensure_blocks(slot, start, end)
-        buf = np.zeros(self.prefill_chunk, np.int32)
-        buf[: end - start] = toks[start:end]
-        mbu = self._mb_bucket(len(self._blocks[slot]))
-        logits, self.cache = self._prefill_chunk_fn(
-            self.params, self.cache, jnp.asarray(self._table[slot, :mbu]),
-            jnp.asarray(buf), start, end - start,
-        )
+        n = end - start
+        with tracer.span("stage"):
+            self._ensure_blocks(slot, start, end)
+            buf = np.zeros(self.prefill_chunk, np.int32)
+            buf[:n] = toks[start:end]
+            mbu = self._mb_bucket(len(self._blocks[slot]))
+            table = jnp.asarray(self._table[slot, :mbu])
+            buf = jnp.asarray(buf)
+        # positions start..end-1 attend start+1..end keys
+        sp.attrs.update(q_tokens=n, attended=n * start + n * (n + 1) // 2,
+                        context=end, table_blocks=mbu)
+        with tracer.span("dispatch"):
+            logits, self.cache = self._prefill_chunk_fn(
+                self.params, self.cache, table, buf, start, n,
+            )
         stats.prefill_chunks += 1
         self._m_chunks.inc()
         self.flightrec.emit("serve_prefill_chunk", uid=req.uid, slot=slot,
-                            start=start, n=end - start)
+                            start=start, n=n)
         if self.reqtrace is not None and req.rid is not None:
             # one span per chunk: the waterfall shows where a long
             # prompt's prefill interleaved with the residents' decode
             self.reqtrace.transition(req.rid, "prefill_chunks",
                                      uid=req.uid, slot=slot,
-                                     start=start, n=end - start)
+                                     start=start, n=n)
         self._written[slot] = end
         if end < T:
             self._pending[slot] = end
@@ -663,12 +687,13 @@ class ServeEngine:
             self.alloc.register_prefix(
                 req.prompt, self._blocks[slot][:n_prompt_blocks]
             )
-        tok = int(
-            sampling.sample(
-                logits, self._next_rng(),
-                temperature=self.temperature, top_k=self.top_k,
+        with tracer.span("fetch"):
+            tok = int(
+                sampling.sample(
+                    logits, self._next_rng(),
+                    temperature=self.temperature, top_k=self.top_k,
+                )
             )
-        )
         self._last[slot] = tok
         if self.reqtrace is not None and req.rid is not None:
             # prefill complete, first token of this residency sampled —
@@ -693,6 +718,7 @@ class ServeEngine:
         if stats is not None:
             stats.finished.append(req.uid)
         self._m_finished[req.finish_reason].inc()
+        self._record_request_spans(req)
         if req.t_first_token is None:
             self._m_ttft.observe(req.t_finish - req.t_submit)
             self._m_tpot.observe(0.0)
@@ -701,6 +727,28 @@ class ServeEngine:
             self._m_tpot.observe(
                 (req.t_finish - req.t_first_token) / max(g - 1, 1)
             )
+
+    def _record_request_spans(self, req: Request) -> None:
+        """``serve.request.queue`` / ``.prefill`` / ``.decode``, from the
+        stamps the request already carries (no clock read): together they
+        partition ``t_submit..t_finish``; a phase the request never
+        reached is left out and the one it ended in runs to ``t_finish``.
+        The stamps are readings of the engine's ``clock``; the ring has
+        one time axis, the tracer's, so an engine driven by another clock
+        than its tracer's records none of the three."""
+        if self.clock is not self.tracer.clock:
+            return
+        record, uid = self.tracer.record, req.uid
+        record("serve.request.queue", req.t_submit,
+               req.t_finish if req.t_admit is None else req.t_admit, key=uid)
+        if req.t_admit is None:
+            return
+        record("serve.request.prefill", req.t_admit,
+               req.t_finish if req.t_first_token is None
+               else req.t_first_token, key=uid)
+        if req.t_first_token is not None:
+            record("serve.request.decode", req.t_first_token, req.t_finish,
+                   key=uid)
 
     def _find(self, uid: int) -> Request:
         req = self.sched.finished.get(uid)
@@ -735,23 +783,30 @@ class ServeEngine:
 
     def _do_prefill(self, slot: int, req: Request, stats: StepStats) -> None:
         P = len(req.prompt)
-        bucket = min(decode_lib.prefill_bucket(P), self.cache.max_len)
-        toks = np.zeros(bucket, np.int32)
-        toks[:P] = req.prompt
-        logits, self.cache = self._prefill(
-            self.params, self.cache, slot, toks, P
-        )
-        tok = int(
-            sampling.sample(
-                logits, self._next_rng(),
-                temperature=self.temperature, top_k=self.top_k,
-            )
-        )
-        self._written[slot] = P
-        self._last[slot] = tok
-        if self.reqtrace is not None and req.rid is not None:
-            self.reqtrace.transition(req.rid, "decode_gap", uid=req.uid)
-        self._deliver(slot, tok, stats)
+        tracer = self.tracer
+        with tracer.span("prefill", key=req.uid, q_tokens=P,
+                         attended=P * (P + 1) // 2, context=P) as sp:
+            with tracer.span("stage"):
+                bucket = min(decode_lib.prefill_bucket(P), self.cache.max_len)
+                toks = np.zeros(bucket, np.int32)
+                toks[:P] = req.prompt
+            with tracer.span("dispatch"):
+                logits, self.cache = self._prefill(
+                    self.params, self.cache, slot, toks, P
+                )
+            with tracer.span("fetch"):
+                tok = int(
+                    sampling.sample(
+                        logits, self._next_rng(),
+                        temperature=self.temperature, top_k=self.top_k,
+                    )
+                )
+            self._written[slot] = P
+            self._last[slot] = tok
+            if self.reqtrace is not None and req.rid is not None:
+                self.reqtrace.transition(req.rid, "decode_gap", uid=req.uid)
+            self._deliver(slot, tok, stats)
+        stats.prefill_s += sp.duration
 
     def _draft(self, slot: int, k: int) -> list[int]:
         """N-gram prompt-lookup drafter (zero extra weights): find the
@@ -774,7 +829,8 @@ class ServeEngine:
                     return cont
         return [ctx[-1]] * k
 
-    def _do_verify_decode(self, active: list[int], stats: StepStats) -> None:
+    def _do_verify_decode(self, active: list[int], stats: StepStats,
+                          sp) -> None:
         """Speculative decode step: draft ``spec_k`` tokens per slot,
         verify every slot's drafts in ONE chunked-prefill-shaped step,
         emit each slot's accepted prefix plus its correction/bonus
@@ -785,118 +841,150 @@ class ServeEngine:
         the per-token ``_deliver`` loop keeps every scheduler/telemetry
         invariant of single-token decode, including discarding tokens
         drafted past a mid-burst finish."""
+        tracer = self.tracer
         bs = self.block_size
         cap = self._oob  # positions a slot's table can address
         drafts: dict[int, list[int]] = {}
-        for slot in active:
-            if self.sched.slots[slot] is None:
-                continue  # a peer's _ensure_blocks preempted it
-            w = int(self._written[slot])
-            ks = max(min(self.spec_k, cap - 1 - w), 0)
-            drafts[slot] = self._draft(slot, ks) if ks else []
-            # writable span: the pending token at w plus every draft
-            self._ensure_blocks(slot, w, w + len(drafts[slot]) + 1)
-        active = [s for s in active if self.sched.slots[s] is not None]
-        if not active:
-            return
-        stats.decoded_slots = len(active)
-        S = self.spec_k + 1
-        toks = np.zeros((self.sched.num_slots, S), np.int32)
-        pos = np.full((self.sched.num_slots, S), self._oob, np.int32)
-        for slot in active:
-            d = drafts[slot]
-            w = int(self._written[slot])
-            toks[slot, 0] = self._last[slot]
-            toks[slot, 1: 1 + len(d)] = d
-            pos[slot, : 1 + len(d)] = np.arange(w, w + 1 + len(d))
-        mbu = self._mb_bucket(max(len(self._blocks[s]) for s in active))
-        logits, self.cache = self._verify(
-            self.params, self.cache, jnp.asarray(self._table[:, :mbu]),
-            jnp.asarray(toks), jnp.asarray(pos),
-        )
-        logits = np.asarray(logits)
-        for slot in active:
-            d = drafts[slot]
-            w = int(self._written[slot])
-            rows = logits[slot, : len(d) + 1]
-            if self.temperature <= 0.0:
-                emitted, accepted = sampling.spec_verify_greedy(rows, d)
-            else:
-                emitted, accepted = sampling.spec_verify_sample(
-                    rows, d, self._spec_gen,
-                    temperature=self.temperature, top_k=self.top_k,
-                )
-            # the verify wrote K/V at w..w+len(d); everything past
-            # w+accepted is rejected-draft garbage — retreat the write
-            # index over it (future writes overwrite in place, masked
-            # until then) and give wholly-garbage tail blocks back
-            self._written[slot] = w + accepted + 1
-            keep = -(-int(self._written[slot]) // bs)
-            if len(self._blocks[slot]) > keep:
-                self.alloc.release_tail(self._blocks[slot], keep)
-                self._table[slot, keep:] = self.cache.num_blocks
-            self._spec_proposed += len(d)
-            self._spec_accepted += accepted
-            if d:
-                self._m_spec_prop.inc(len(d))
-            if accepted:
-                self._m_spec_acc.inc(accepted)
-            req = self.sched.slots[slot]
-            req.spec_accepted += accepted
-            self.flightrec.emit("serve_spec_step", uid=req.uid, slot=slot,
-                                proposed=len(d), accepted=accepted)
-            self._last[slot] = emitted[-1]
-            for tok in emitted:
-                self._deliver(slot, tok, stats)
+        with tracer.span("stage"):
+            for slot in active:
                 if self.sched.slots[slot] is None:
-                    break  # finished mid-burst; trailing tokens discarded
+                    continue  # a peer's _ensure_blocks preempted it
+                w = int(self._written[slot])
+                ks = max(min(self.spec_k, cap - 1 - w), 0)
+                drafts[slot] = self._draft(slot, ks) if ks else []
+                # writable span: the pending token at w plus every draft
+                self._ensure_blocks(slot, w, w + len(drafts[slot]) + 1)
+            active = [s for s in active if self.sched.slots[s] is not None]
+            if not active:
+                return
+            stats.decoded_slots = len(active)
+            S = self.spec_k + 1
+            toks = np.zeros((self.sched.num_slots, S), np.int32)
+            pos = np.full((self.sched.num_slots, S), self._oob, np.int32)
+            kv_tokens = 0
+            for slot in active:
+                d = drafts[slot]
+                w = int(self._written[slot])
+                toks[slot, 0] = self._last[slot]
+                toks[slot, 1: 1 + len(d)] = d
+                pos[slot, : 1 + len(d)] = np.arange(w, w + 1 + len(d))
+                kv_tokens += w + 1 + len(d)
+            mbu = self._mb_bucket(max(len(self._blocks[s]) for s in active))
+            table = jnp.asarray(self._table[:, :mbu])
+            toks, pos = jnp.asarray(toks), jnp.asarray(pos)
+        sp.attrs.update(
+            slots=len(active), kv_tokens=kv_tokens, table_blocks=mbu,
+            kv_positions_walked=self.sched.num_slots * mbu * bs)
+        with tracer.span("dispatch"):
+            logits, self.cache = self._verify(
+                self.params, self.cache, table, toks, pos,
+            )
+        with tracer.span("fetch"):
+            logits = np.asarray(logits)
+        with tracer.span("deliver"):
+            for slot in active:
+                d = drafts[slot]
+                w = int(self._written[slot])
+                rows = logits[slot, : len(d) + 1]
+                if self.temperature <= 0.0:
+                    emitted, accepted = sampling.spec_verify_greedy(rows, d)
+                else:
+                    emitted, accepted = sampling.spec_verify_sample(
+                        rows, d, self._spec_gen,
+                        temperature=self.temperature, top_k=self.top_k,
+                    )
+                # the verify wrote K/V at w..w+len(d); everything past
+                # w+accepted is rejected-draft garbage — retreat the write
+                # index over it (future writes overwrite in place, masked
+                # until then) and give wholly-garbage tail blocks back
+                self._written[slot] = w + accepted + 1
+                keep = -(-int(self._written[slot]) // bs)
+                if len(self._blocks[slot]) > keep:
+                    self.alloc.release_tail(self._blocks[slot], keep)
+                    self._table[slot, keep:] = self.cache.num_blocks
+                self._spec_proposed += len(d)
+                self._spec_accepted += accepted
+                if d:
+                    self._m_spec_prop.inc(len(d))
+                if accepted:
+                    self._m_spec_acc.inc(accepted)
+                req = self.sched.slots[slot]
+                req.spec_accepted += accepted
+                self.flightrec.emit("serve_spec_step", uid=req.uid,
+                                    slot=slot, proposed=len(d),
+                                    accepted=accepted)
+                self._last[slot] = emitted[-1]
+                for tok in emitted:
+                    self._deliver(slot, tok, stats)
+                    if self.sched.slots[slot] is None:
+                        break  # finished mid-burst; trailing tokens discarded
         if self._spec_proposed:
             self._m_spec_rate.set(
                 self._spec_accepted / self._spec_proposed)
 
     def _do_decode(self, active: list[int], stats: StepStats) -> None:
-        if self.paged and self.spec_k > 0:
-            self._do_verify_decode(active, stats)
-            return
-        if self.paged:
-            # make each decoding slot's write position privately owned
-            # (fresh block at a boundary, COW off a shared block);
-            # allocation pressure may preempt the youngest residents, so
-            # re-filter afterwards
+        with self.tracer.span("decode") as sp:
+            if self.paged and self.spec_k > 0:
+                self._do_verify_decode(active, stats, sp)
+            else:
+                self._do_plain_decode(active, stats, sp)
+        stats.decode_s = sp.duration
+
+    def _do_plain_decode(self, active: list[int], stats: StepStats,
+                         sp) -> None:
+        tracer = self.tracer
+        with tracer.span("stage"):
+            if self.paged:
+                # make each decoding slot's write position privately owned
+                # (fresh block at a boundary, COW off a shared block);
+                # allocation pressure may preempt the youngest residents,
+                # so re-filter afterwards
+                for slot in active:
+                    if self.sched.slots[slot] is not None:
+                        w = int(self._written[slot])
+                        self._ensure_blocks(slot, w, w + 1)
+                active = [s for s in active
+                          if self.sched.slots[s] is not None]
+                if not active:
+                    return
+            stats.decoded_slots = len(active)
+            # each live slot attends its written positions plus the token
+            # it writes now; from the host's mirror, no device read
+            sp.attrs.update(
+                slots=len(active),
+                kv_tokens=int(self._written[active].sum()) + len(active))
+            if self.paged:
+                # non-decoding slots write through the past-the-table
+                # sentinel — their garbage token must not touch a live
+                # (possibly shared) block
+                lens = np.full(self.sched.num_slots, self._oob, np.int32)
+                for slot in active:
+                    lens[slot] = self._written[slot]
+                mbu = self._mb_bucket(
+                    max(len(self._blocks[s]) for s in active))
+                # the kernel's grid visits every block position of every
+                # slot up to the bucket width, live or not
+                sp.attrs.update(
+                    table_blocks=mbu,
+                    kv_positions_walked=(self.sched.num_slots * mbu
+                                         * self.block_size))
+                args = (jnp.asarray(self._table[:, :mbu]),
+                        jnp.asarray(self._last), jnp.asarray(lens))
+            else:
+                args = (jnp.asarray(self._last), jnp.asarray(self._written))
+        with tracer.span("dispatch"):
+            logits, self.cache = self._decode(self.params, self.cache, *args)
+        with tracer.span("fetch"):
+            toks = np.asarray(
+                sampling.sample(
+                    logits, self._next_rng(),
+                    temperature=self.temperature, top_k=self.top_k,
+                )
+            )
+        with tracer.span("deliver"):
             for slot in active:
-                if self.sched.slots[slot] is not None:
-                    w = int(self._written[slot])
-                    self._ensure_blocks(slot, w, w + 1)
-            active = [s for s in active if self.sched.slots[s] is not None]
-            if not active:
-                return
-        stats.decoded_slots = len(active)
-        if self.paged:
-            # non-decoding slots write through the past-the-table
-            # sentinel — their garbage token must not touch a live
-            # (possibly shared) block
-            lens = np.full(self.sched.num_slots, self._oob, np.int32)
-            for slot in active:
-                lens[slot] = self._written[slot]
-            mbu = self._mb_bucket(
-                max(len(self._blocks[s]) for s in active))
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(self._table[:, :mbu]),
-                jnp.asarray(self._last), jnp.asarray(lens),
-            )
-        else:
-            logits, self.cache = self._decode(
-                self.params, self.cache,
-                jnp.asarray(self._last), jnp.asarray(self._written),
-            )
-        toks = np.asarray(
-            sampling.sample(
-                logits, self._next_rng(),
-                temperature=self.temperature, top_k=self.top_k,
-            )
-        )
-        for slot in active:
-            self._written[slot] += 1  # the decode wrote k/v at the old index
-            tok = int(toks[slot])
-            self._last[slot] = tok
-            self._deliver(slot, tok, stats)
+                # the decode wrote k/v at the old index
+                self._written[slot] += 1
+                tok = int(toks[slot])
+                self._last[slot] = tok
+                self._deliver(slot, tok, stats)

@@ -26,8 +26,9 @@ Protocol (the file-based data plane, serve/fleet.py):
   it, which is the point.
 - **Drain.** A ``DRAIN`` sentinel (or SIGTERM) stops ingestion, decodes
   the residents to completion, writes the audit, exports a final
-  snapshot, dumps the flight recorder, and exits 0. Any other exit is
-  a death the supervisor requeues around.
+  snapshot, dumps the flight recorder and the span ring
+  (``flightrec-w<i>i<k>.jsonl``, ``spans-w<i>i<k>.jsonl``), and exits 0.
+  Any other exit is a death the supervisor requeues around.
 """
 
 from __future__ import annotations
@@ -124,6 +125,12 @@ def main(argv=None) -> int:
         rec.dump(path, reason="serve_replica_exit",
                  extra={"worker": args.index,
                         "incarnation": args.incarnation})
+        # the engine's span ring beside it, under the same suffix
+        # (obs/trace.py: the last minutes' steps phase by phase, each
+        # call's size, each request's queue/prefill/decode phases)
+        head, tail = os.path.split(path)
+        engine.tracer.dump(os.path.join(
+            head, tail.replace("flightrec", "spans", 1)))
 
     trace_path = os.path.join(
         os.path.abspath(os.path.expanduser(args.workdir)),

@@ -15,12 +15,14 @@ device; only cadence'd callbacks (logging every N) synchronize.
 from __future__ import annotations
 
 import logging
+import os
 from typing import Any, Callable, Iterable, Sequence
 
 import jax
 from jax.sharding import Mesh
 
 from ..obs import flightrec as flightrec_lib
+from ..obs import trace as trace_lib
 from ..parallel import sharding as sh
 from . import step as step_lib
 from .callbacks import Callback, CheckpointCallback
@@ -48,6 +50,7 @@ class Trainer:
         flightrec=None,
         postmortem_dir: str | None = None,
         anomaly_policy=None,
+        tracer=None,
     ):
         self.mesh = mesh
         self.spec_tree = spec_tree
@@ -73,6 +76,11 @@ class Trainer:
         #: defaults to the process ring so every layer shares one timeline
         self.flightrec = (flightrec if flightrec is not None
                           else flightrec_lib.default_recorder())
+        #: span ring for the loop's host phases (obs/trace.py; the span
+        #: names are a contract, docs/observability.md "Span tracing");
+        #: defaults to the process ring, as the flight recorder does
+        self.tracer = (tracer if tracer is not None
+                       else trace_lib.default_tracer())
         #: where an abnormal-exit postmortem dump lands; defaults to the
         #: emergency checkpointer's directory (the run dir)
         self.postmortem_dir = postmortem_dir
@@ -132,53 +140,64 @@ class Trainer:
             for cb in self.callbacks:
                 cb.on_train_start(self)
             data_iter = iter(data)
+            tracer = self.tracer
             while not self.should_stop:
                 if num_steps is not None and step_now >= num_steps:
                     self.request_stop(f"num_steps={num_steps}")
                     break
-                try:
-                    batch = next(data_iter)
-                except StopIteration:
-                    self.request_stop("data exhausted")
-                    break
-                rec.emit("step_start", step=step_now + 1)
-                batch = self.put_batch(batch)
-                self.state, metrics = self.step_fn(self.state, batch)
-                if self.anomaly_policy is not None:
-                    if self.anomaly_policy.observe(step_now + 1, metrics):
-                        # the compiled step kept the old state
-                        # bit-identically (in-graph nonfinite guard): the
-                        # batch vanishes from the trajectory — not a
-                        # completed step, so neither the step mirror nor
-                        # any callback may count it. The policy already
-                        # blamed + quarantined the index and emitted
-                        # anomaly_skip (which is what resolves this
-                        # step's dangling step_start in a postmortem); a
-                        # spent skip budget raises out of observe() into
-                        # the classified-exit path below (poisoned),
-                        # with the state still clean.
-                        continue
-                elif step_lib.step_nonfinite(metrics):
-                    # guard on, no policy wired: fail fast HERE, before
-                    # the step is counted. Counting it would desync the
-                    # host mirror from the device step counter (the
-                    # guard kept state.step unchanged) and mislabel
-                    # every later checkpoint by one. The state is still
-                    # the last healthy one, so the emergency save below
-                    # lands under its true step number; the exception
-                    # classifies poisoned — the pre-guard NaNGuard
-                    # semantics, made exact and immediate.
-                    raise FloatingPointError(
-                        f"non-finite loss/gradients at step {step_now + 1}"
-                        " (in-graph guard skipped the update; wire an "
-                        "AnomalyPolicy to skip-and-continue instead)")
-                step_now += 1
-                for cb in self.callbacks:
-                    cb.on_step_end(self, step_now, metrics)
-                # after the callbacks: step_end marks the step COMPLETE
-                # (checkpoint cadence included), so a missing step_end in
-                # a postmortem points at the exact step that died
-                rec.emit("step_end", step=step_now)
+                with tracer.span("train.step", step=step_now + 1):
+                    with tracer.span("next_batch"):
+                        try:
+                            batch = next(data_iter)
+                        except StopIteration:
+                            self.request_stop("data exhausted")
+                            break
+                    rec.emit("step_start", step=step_now + 1)
+                    with tracer.span("put_batch"):
+                        batch = self.put_batch(batch)
+                    with tracer.span("dispatch"):
+                        self.state, metrics = self.step_fn(self.state, batch)
+                        if self.anomaly_policy is not None:
+                            if self.anomaly_policy.observe(step_now + 1,
+                                                           metrics):
+                                # the compiled step kept the old state
+                                # bit-identically (in-graph nonfinite
+                                # guard): the batch vanishes from the
+                                # trajectory — not a completed step, so
+                                # neither the step mirror nor any callback
+                                # may count it. The policy already blamed +
+                                # quarantined the index and emitted
+                                # anomaly_skip (which is what resolves this
+                                # step's dangling step_start in a
+                                # postmortem); a spent skip budget raises
+                                # out of observe() into the classified-exit
+                                # path below (poisoned), with the state
+                                # still clean.
+                                continue
+                        elif step_lib.step_nonfinite(metrics):
+                            # guard on, no policy wired: fail fast HERE,
+                            # before the step is counted. Counting it would
+                            # desync the host mirror from the device step
+                            # counter (the guard kept state.step unchanged)
+                            # and mislabel every later checkpoint by one.
+                            # The state is still the last healthy one, so
+                            # the emergency save below lands under its true
+                            # step number; the exception classifies
+                            # poisoned — the pre-guard NaNGuard semantics,
+                            # made exact and immediate.
+                            raise FloatingPointError(
+                                f"non-finite loss/gradients at step "
+                                f"{step_now + 1} (in-graph guard skipped "
+                                "the update; wire an AnomalyPolicy to "
+                                "skip-and-continue instead)")
+                    step_now += 1
+                    with tracer.span("callbacks"):
+                        for cb in self.callbacks:
+                            cb.on_step_end(self, step_now, metrics)
+                    # after the callbacks: step_end marks the step COMPLETE
+                    # (checkpoint cadence included), so a missing step_end
+                    # in a postmortem points at the exact step that died
+                    rec.emit("step_end", step=step_now)
         except PreemptionSaved as e:
             # Clean preemption exit (SURVEY.md §5.3): state is safely on
             # disk; stop so the scheduler — or an in-process
@@ -196,8 +215,9 @@ class Trainer:
             # latest checkpoint; any error here must not mask the
             # original exception.
             self._emergency_save(step_now)
-            # abnormal exit: dump the flight recorder as a postmortem
-            # (best-effort, never masks the original exception)
+            # abnormal exit: dump the flight recorder and the span ring
+            # as a postmortem (best-effort, never masks the original
+            # exception)
             self._dump_postmortem(f"train_exception:{type(e).__name__}")
             raise
         finally:
@@ -241,8 +261,19 @@ class Trainer:
 
     def _dump_postmortem(self, reason: str) -> None:
         """Best-effort JSONL postmortem into the run dir
-        (tools/postmortem.py renders it); on the abnormal exit path it
-        must never raise past the original failure — the shared helper
-        guarantees that."""
-        flightrec_lib.dump_postmortem(self.flightrec, self.postmortem_dir,
-                                      reason=reason)
+        (tools/postmortem.py renders it), with the span ring beside it
+        under the same suffix (``postmortem-1.jsonl`` / ``spans-1.jsonl``);
+        on the abnormal exit path it must never raise past the original
+        failure — the shared helper guarantees that for the recorder, the
+        handler below for the ring. No directory, nothing written."""
+        path = flightrec_lib.dump_postmortem(self.flightrec,
+                                             self.postmortem_dir,
+                                             reason=reason)
+        if path is None:
+            return
+        head, tail = os.path.split(path)
+        try:
+            self.tracer.dump(os.path.join(
+                head, tail.replace("postmortem", "spans", 1)))
+        except Exception:
+            logger.exception("span-ring postmortem dump failed")

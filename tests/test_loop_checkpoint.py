@@ -352,6 +352,114 @@ def test_no_emergency_checkpoint_without_checkpointer(mesh8):
     assert trainer.failed
 
 
+def _traced_trainer(mesh, tracer, callbacks=(), **kw):
+    tx = optax.sgd(0.1)
+    state, specs = init_train_state(linear_init, tx, mesh,
+                                    jax.random.PRNGKey(0))
+    return Trainer(make_train_step(linear_loss, tx), state, mesh, specs,
+                   callbacks=callbacks, tracer=tracer, **kw)
+
+
+def test_fit_spans_show_a_slow_iterator_in_next_batch_not_in_dispatch(mesh8):
+    """One train.step per loop iteration with its four phases as children,
+    in order; a data iterator that sleeps lands in next_batch."""
+    import time
+
+    from distributed_tensorflow_tpu import obs
+
+    def slow():
+        for i in range(4):
+            time.sleep(0.05)
+            yield make_batch(16, seed=i)
+
+    tracer = obs.Tracer(annotate=False)
+    trainer = _traced_trainer(mesh8, tracer)
+    trainer.fit(slow(), num_steps=5)
+    steps = [s for s in tracer.events if s.name == "train.step"]
+    # four real steps and the iteration that found the feed exhausted
+    assert [s.attrs["step"] for s in steps] == [1, 2, 3, 4, 5]
+    assert all(s.parent is None for s in steps)
+    for sp in steps[:4]:
+        kids = [s for s in tracer.events if s.parent == sp.id]
+        assert [k.name for k in kids] == [
+            "train.step.next_batch", "train.step.put_batch",
+            "train.step.dispatch", "train.step.callbacks"]
+        assert all(sp.start <= k.start <= k.end <= sp.end for k in kids)
+        assert all(a.end <= b.start for a, b in zip(kids, kids[1:]))
+        nb, _, dispatch, _ = kids
+        assert nb.duration >= 0.05
+        if sp is not steps[0]:  # the first dispatch compiles the step
+            assert dispatch.duration < 0.05
+    last = [s.name for s in tracer.events if s.parent == steps[-1].id]
+    assert last == ["train.step.next_batch"]
+    assert trainer.stop_reason == "data exhausted"
+
+
+def test_raising_callback_still_closes_train_step_and_dumps_the_ring(
+        mesh8, tmp_path):
+    """A span that dies still records: the step whose callback raised is in
+    the ring with its callbacks child, nothing is left open, and the ring
+    is dumped beside the flight recorder's postmortem, same suffix."""
+    import json
+
+    from distributed_tensorflow_tpu import obs
+
+    class Boom(cb.Callback):
+        def on_step_end(self, trainer, step, metrics):
+            if step == 2:
+                raise RuntimeError("callback exploded")
+
+    tracer = obs.Tracer(annotate=False)
+    for n in ("", "-1"):
+        trainer = _traced_trainer(mesh8, tracer, callbacks=[Boom()],
+                                  flightrec=obs.FlightRecorder(),
+                                  postmortem_dir=str(tmp_path))
+        with pytest.raises(RuntimeError, match="callback exploded"):
+            trainer.fit(batches(10), num_steps=10)
+        assert tracer.current() is None
+        died = [s for s in tracer.events if s.name == "train.step"][-1]
+        assert died.attrs == {"step": 2} and died.end >= died.start
+        assert [s.name for s in tracer.events if s.parent == died.id][-1] \
+            == "train.step.callbacks"
+        assert (tmp_path / f"postmortem{n}.jsonl").exists()
+        with open(tmp_path / f"spans{n}.jsonl") as f:
+            header, *rows = [json.loads(line) for line in f]
+        assert header["schema"] == "dtf-spans-1"
+        assert header["spans"] == len(rows) == len(tracer.events)
+        assert rows[-1]["name"] == "train.step" and rows[-1]["id"] == died.id
+
+
+def test_no_postmortem_dir_no_span_dump_and_a_failed_dump_masks_nothing(
+        mesh8, tmp_path, monkeypatch):
+    from distributed_tensorflow_tpu import obs
+
+    def dies():
+        yield make_batch(16, seed=0)
+        raise IOError("dead feed")
+
+    tracer = obs.Tracer(annotate=False)
+    trainer = _traced_trainer(mesh8, tracer)
+    assert trainer.postmortem_dir is None
+    monkeypatch.chdir(tmp_path)
+    with pytest.raises(IOError, match="dead feed"):
+        trainer.fit(dies(), num_steps=10)
+    assert list(tmp_path.iterdir()) == []  # nothing written anywhere
+    # the iteration whose next() raised is recorded, with its next_batch
+    assert [s.name for s in tracer.events][-2:] == [
+        "train.step.next_batch", "train.step"]
+
+    def broken(path):
+        raise OSError("disk full")
+
+    monkeypatch.setattr(tracer, "dump", broken)
+    trainer = _traced_trainer(mesh8, tracer, flightrec=obs.FlightRecorder(),
+                              postmortem_dir=str(tmp_path / "run"))
+    with pytest.raises(IOError, match="dead feed"):  # not the OSError
+        trainer.fit(dies(), num_steps=10)
+    assert (tmp_path / "run" / "postmortem.jsonl").exists()
+    assert not (tmp_path / "run" / "spans.jsonl").exists()
+
+
 def test_optimizer_clip_grad_norm_wired(mesh8):
     """clip_grad_norm on OptimizerConfig must actually clip."""
     big = make_batch(16)

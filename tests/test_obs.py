@@ -378,15 +378,15 @@ def test_tracer_nesting_and_registry_feed():
 
     r = obs.Registry()
     tr = obs.Tracer(registry=r, annotate=False, clock=clock)
-    with tr.span("step"):
-        assert tr.current_path == "step"
-        with tr.span("prefill"):
-            assert tr.current_path == "step.prefill"
-    assert tr.current_path == ""
+    with tr.span("step") as outer:
+        assert tr.current() is outer and outer.name == "step"
+        with tr.span("prefill") as inner:
+            assert tr.current() is inner and inner.name == "step.prefill"
+    assert tr.current() is None
     # inner closes first; durations from the fake clock are exact
-    assert [(s.path, s.depth, s.duration) for s in tr.events] == [
-        ("step.prefill", 1, 1.0),
-        ("step", 0, 3.0),
+    assert [(s.name, s.parent, s.duration) for s in tr.events] == [
+        ("step.prefill", outer.id, 1.0),
+        ("step", None, 3.0),
     ]
     from distributed_tensorflow_tpu.obs.trace import SPAN_HISTOGRAM
 
@@ -400,11 +400,13 @@ def test_tracer_records_on_exception_and_bounds_events():
         with tr.span("dies"):
             raise RuntimeError("boom")
     assert tr.events[-1].name == "dies"
-    assert tr.current_path == ""  # stack unwound
+    assert tr.events[-1].end >= tr.events[-1].start
+    assert tr.current() is None  # stack unwound
     for i in range(5):
         with tr.span(f"s{i}"):
             pass
     assert len(tr.events) == 2 and tr.dropped == 4
+    assert [s.name for s in tr.events] == ["s3", "s4"]  # the newest stay
 
 
 def test_tracer_annotate_passthrough_smoke():
@@ -413,7 +415,140 @@ def test_tracer_annotate_passthrough_smoke():
     tr = obs.Tracer(annotate=True)
     with tr.span("annotated"):
         pass
-    assert tr.events[-1].path == "annotated"
+    assert tr.events[-1].name == "annotated"
+
+
+def test_tracer_ids_parents_keys_and_counts():
+    """Ids are process-wide and rise in opening order; a span's parent is
+    the span open on ITS thread; ``key`` and ``attrs`` are set at open or
+    before close; nothing of a default tracer feeds a registry."""
+    import threading
+
+    a, b = obs.Tracer(annotate=False), obs.Tracer(annotate=False)
+    with a.span("step", step=7) as outer:
+        with b.span("other") as foreign:  # another tracer, another stack
+            pass
+        with a.span("prefill", key=41, q_tokens=32) as inner:
+            inner.attrs["context"] = 96
+        got = {}
+
+        def worker():
+            with a.span("side") as sp:
+                got["span"] = sp
+
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    assert outer.id < foreign.id < inner.id < got["span"].id
+    assert foreign.parent is None and foreign.name == "other"
+    assert (inner.parent, inner.key, inner.attrs) == (
+        outer.id, 41, {"q_tokens": 32, "context": 96})
+    assert (outer.parent, outer.key, outer.attrs) == (None, None, {"step": 7})
+    # the other thread's span did not nest under this thread's open span
+    assert got["span"].parent is None and got["span"].name == "side"
+    assert [s.name for s in a.events] == ["step.prefill", "side", "step"]
+    assert a.registry is None and a.dropped == 0
+
+
+def test_tracer_record_takes_stamps_and_reads_no_clock():
+    reads = []
+
+    def clock():
+        reads.append(1)
+        return 100.0 + len(reads)
+
+    tr = obs.Tracer(annotate=False, clock=clock)
+    with tr.span("serve.step") as step:
+        sp = tr.record("serve.request.queue", 3.0, 4.5, key=9, parent=None)
+        child = tr.record("compile.backend", 101.25, 101.5, parent=step.id)
+    assert len(reads) == 2  # the open span's two ends, nothing else
+    assert (sp.name, sp.start, sp.end, sp.key, sp.parent) == (
+        "serve.request.queue", 3.0, 4.5, 9, None)  # taken as given
+    assert child.parent == step.id and child.name == "compile.backend"
+    assert [s.id for s in tr.events] == [sp.id, child.id, step.id]
+
+
+def test_tracer_dump_round_trip(tmp_path):
+    import json
+
+    tr = obs.Tracer(annotate=False, max_events=3)
+    with tr.span("serve.step", queued=2) as step:
+        with tr.span("prefill", key=5, q_tokens=8):
+            pass
+    tr.record("serve.request.decode", 1.0, 2.0, key=5, tokens=4)
+    tr.record("odd", 2.0, 3.0, key=("not", "json"), what=object())
+    path = tr.dump(str(tmp_path / "spans.jsonl"))
+    lines = [json.loads(line) for line in open(path)]
+    header, rows = lines[0], lines[1:]
+    assert header["schema"] == "dtf-spans-1"
+    assert (header["spans"], header["dropped"], header["capacity"]) == (3, 1, 3)
+    assert set(header["clock_origin"]) == {"clock", "unix"}
+    assert [r["name"] for r in rows] == [
+        "serve.step", "serve.request.decode", "odd"]
+    assert rows[0] == {"id": step.id, "parent": None, "name": "serve.step",
+                       "start": step.start, "end": step.end, "key": None,
+                       "attrs": {"queued": 2}}
+    assert rows[1]["attrs"] == {"tokens": 4} and rows[1]["key"] == 5
+    assert isinstance(rows[2]["attrs"]["what"], str)  # repr'd, not raised
+    assert not (tmp_path / "spans.jsonl.tmp").exists()
+
+
+def test_tracer_annotation_is_named_path_dot_id(monkeypatch):
+    """The profiler's event carries the span's id: ``<path>.<id>``; a
+    tracer with annotate=False makes none."""
+    from distributed_tensorflow_tpu.obs import trace as trace_lib
+
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(trace_lib.jax.profiler, "TraceAnnotation", Annotation)
+    tr = obs.Tracer()
+    with tr.span("serve.step") as outer:
+        with tr.span("decode") as inner:
+            pass
+    assert seen == [("enter", f"serve.step.{outer.id}"),
+                    ("enter", f"serve.step.decode.{inner.id}"),
+                    ("exit", f"serve.step.decode.{inner.id}"),
+                    ("exit", f"serve.step.{outer.id}")]
+    seen.clear()
+    with obs.Tracer(annotate=False).span("quiet"):
+        pass
+    assert seen == []
+
+
+def test_default_tracer_records_compiles_under_the_open_span():
+    """The jax.monitoring listener, installed with the default tracer: a
+    program compiled inside a span leaves compile.trace / .lower /
+    .backend spans whose parent is that span; the traces of the jitted
+    functions it calls (jnp.sin, jnp.where: each a jit of its own) nest in
+    its trace and are not recorded again."""
+    import jax
+    import jax.numpy as jnp
+
+    tr = obs.default_tracer()
+    assert obs.default_tracer() is tr and tr.registry is None
+
+    def f(x):
+        return jnp.where(x > 2, jnp.sin(x), jnp.cos(x)) * 3 + 1
+
+    x = jnp.arange(5.0)  # an eager op compiles a program of its own
+    with tr.span("test.compile_here") as sp:
+        jax.jit(f)(x).block_until_ready()
+    mine = [s for s in tr.events if s.parent == sp.id]
+    assert sorted(s.name for s in mine if s.start >= sp.start) == [
+        "compile.backend", "compile.lower", "compile.trace"]
+    for s in mine:
+        assert s.end <= sp.end and s.end >= s.start
 
 
 # ---------------------------------------------------------------------------

@@ -3,6 +3,7 @@ forward (the numerics acceptance gate), scheduler invariants under a
 randomized request stream, cache sharding specs, and sampling."""
 
 import random
+import types
 
 import jax
 import jax.numpy as jnp
@@ -1054,3 +1055,240 @@ def test_paged_cache_specs_match_rules_table():
     legacy = sh.spec_from_logical(kv.PAGED_CACHE_LOGICAL, sh.TP_RULES)
     assert table_specs.k == legacy and table_specs.v == legacy
     assert serve.paged_cache_specs(sh.TP_RULES) == table_specs
+
+
+# ---------------------------------------------------------------------------
+# Span tracing (obs/trace.py): the step's phases and counts, the request's
+# phases — structure and counts only, never a time under a device's name
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def traced_run(decoder):
+    """A scripted paged run on its own tracer: a shared prefix, a prompt of
+    several chunks, a pool tight enough to force a preemption, and one
+    request cancelled while queued. The two jitted steps are wrapped with
+    a copy of the benchmark's `serve_driver.Calls` arithmetic, which reads
+    each call's work from the call's own arguments."""
+    from distributed_tensorflow_tpu import obs
+
+    cfg, _, params = decoder
+    tracer = obs.Tracer(annotate=False)
+    eng = _paged_engine(cfg, params, num_slots=2, max_len=48, num_blocks=8,
+                        tracer=tracer)
+    records = []
+    prefill, decode, oob = eng._prefill_chunk_fn, eng._decode, eng._oob
+
+    def prefill_chunk(params, cache, table_row, buf, start, n):
+        records.append(("prefill", int(n), int(n * start + n * (n + 1) // 2),
+                        int(start + n), int(table_row.shape[0])))
+        return prefill(params, cache, table_row, buf, start, n)
+
+    def decode_step(params, cache, table, last, lens):
+        live = np.asarray(lens)
+        live = live[live != oob]
+        records.append(("decode", int(live.size), int((live + 1).sum()),
+                        int((live + 1).sum()), int(table.shape[1])))
+        return decode(params, cache, table, last, lens)
+
+    eng._prefill_chunk_fn, eng._decode = prefill_chunk, decode_step
+    prefix = list(range(1, 17))  # two full blocks
+    requests, steps = {}, []
+
+    def submit(prompt, n):
+        uid = eng.submit(prompt, max_new_tokens=n)
+        requests[uid] = eng._find(uid)
+        return uid
+
+    def run():
+        while eng.sched.has_work:
+            steps.append(eng.step())
+
+    submit(prefix + [50], 2)  # registers the prefix
+    run()
+    submit(prefix + list(range(60, 70)), 16)  # 26 tokens: 10 past the prefix
+    submit(prefix + [71], 24)
+    queued = submit([90, 91, 92], 4)  # no slot free: waits in the queue
+    steps.append(eng.step())
+    cancelled = submit([93, 94], 4)
+    assert eng.cancel(cancelled)
+    run()
+    eng.drain()
+    assert eng.alloc.blocks_free == eng.cache.num_blocks
+    assert tracer.dropped == 0
+    return types.SimpleNamespace(
+        eng=eng, spans=list(tracer.events), records=records,
+        requests=requests, steps=steps, queued=queued, cancelled=cancelled)
+
+
+def _named(run, name):
+    return [s for s in run.spans if s.name == name]
+
+
+def test_step_spans_count_what_each_call_was_asked(traced_run):
+    """`q_tokens/attended/context` of every `serve.step.prefill` and
+    `slots/kv_tokens` of every `serve.step.decode` equal, call for call,
+    what the call's arguments say; the table width is the one handed to
+    the kernel, and the walk is slots x width x block size."""
+    run = traced_run
+    assert sum(r.preemptions for r in run.requests.values()) >= 1
+    pre, dec = _named(run, "serve.step.prefill"), _named(
+        run, "serve.step.decode")
+    dec = [s for s in dec if "slots" in s.attrs]  # a call was made
+    want_pre = [r for r in run.records if r[0] == "prefill"]
+    want_dec = [r for r in run.records if r[0] == "decode"]
+    assert len(pre) == len(want_pre) > 4 and len(dec) == len(want_dec) > 10
+    assert [("prefill", s.attrs["q_tokens"], s.attrs["attended"],
+             s.attrs["context"], s.attrs["table_blocks"]) for s in pre] \
+        == want_pre
+    assert [("decode", s.attrs["slots"], s.attrs["kv_tokens"],
+             s.attrs["kv_tokens"], s.attrs["table_blocks"]) for s in dec] \
+        == want_dec
+    eng = run.eng
+    for s in dec:
+        assert s.attrs["kv_positions_walked"] == (
+            eng.sched.num_slots * s.attrs["table_blocks"] * eng.block_size)
+        assert 0 < s.attrs["kv_tokens"] <= s.attrs["kv_positions_walked"]
+    # a prompt of several chunks: consecutive chunks of one uid
+    longest = max(run.requests.values(), key=lambda r: len(r.prompt))
+    chunks = [s for s in pre if s.key == longest.uid]
+    # all of the prompt but what the prefix cache supplied, at least
+    assert len(chunks) >= 2 and sum(
+        c.attrs["q_tokens"] for c in chunks) >= len(longest.prompt) - 16
+
+
+def test_step_span_counts_add_up_to_the_engines_own(traced_run):
+    """What the spans count is what the registry counts: a prefill span a
+    chunk, a token a prefill fetch and a token a live slot of a decode."""
+    run = traced_run
+    reg = run.eng.registry
+    pre = _named(run, "serve.step.prefill")
+    assert len(pre) == reg.get("prefill_chunks_total").value \
+        == sum(st.prefill_chunks for st in run.steps)
+    decode_tokens = sum(s.attrs.get("slots", 0)
+                        for s in _named(run, "serve.step.decode"))
+    first_tokens = len(_named(run, "serve.step.prefill.fetch"))
+    assert decode_tokens + first_tokens \
+        == reg.get("serve_tokens_total").value
+    # one serve.step span per step() call, whose StepStats timing split
+    # is the spans' durations
+    steps = _named(run, "serve.step")
+    assert len(steps) == len(run.steps)
+    by_parent = {}
+    for s in run.spans:
+        by_parent.setdefault(s.parent, []).append(s)
+    for sp, st in zip(steps, run.steps):
+        kids = by_parent[sp.id]
+        assert st.wall_s == sp.duration
+        assert st.prefill_s == pytest.approx(sum(
+            k.duration for k in kids
+            if k.name in ("serve.step.admit", "serve.step.prefill")))
+        assert st.decode_s == sum(
+            k.duration for k in kids if k.name == "serve.step.decode")
+        assert st.decoded_slots == sum(
+            k.attrs.get("slots", 0) for k in kids
+            if k.name == "serve.step.decode")
+
+
+def test_request_spans_partition_submit_to_finish(traced_run):
+    """The serve.request.* spans of a finished request are its stamps:
+    contiguous from t_submit to t_finish, keyed by its uid; a phase it
+    never reached is left out."""
+    run = traced_run
+    by_uid = {}
+    for s in run.spans:
+        if s.name.startswith("serve.request."):
+            assert s.parent is None
+            by_uid.setdefault(s.key, []).append(s)
+    assert set(by_uid) == set(run.requests)
+    for uid, req in run.requests.items():
+        spans = by_uid[uid]
+        phases = [s.name.rsplit(".", 1)[1] for s in spans]
+        if uid == run.cancelled:
+            assert phases == ["queue"] and req.t_admit is None
+        else:
+            assert phases == ["queue", "prefill", "decode"]
+        assert spans[0].start == req.t_submit
+        assert spans[-1].end == req.t_finish
+        for a, b in zip(spans, spans[1:]):
+            assert a.end == b.start
+        if len(spans) == 3:
+            assert (spans[1].start, spans[2].start) == (
+                req.t_admit, req.t_first_token)
+        assert all(s.attrs == {} for s in spans)
+    waited = by_uid[run.queued][0]
+    assert waited.end - waited.start > 0  # it sat out at least one step
+
+
+def test_span_children_lie_inside_their_parents(traced_run):
+    run = traced_run
+    by_id = {s.id: s for s in run.spans}
+    nested = [s for s in run.spans if s.parent is not None]
+    assert len(nested) > 50
+    for s in nested:
+        p = by_id[s.parent]
+        assert p.start <= s.start <= s.end <= p.end, (s, p)
+        assert s.name.startswith(p.name + ".") \
+            and "." not in s.name[len(p.name) + 1:]
+    names = {s.name for s in run.spans}
+    assert names == {
+        "serve.step", "serve.step.admit", "serve.step.prefill",
+        "serve.step.prefill.stage", "serve.step.prefill.dispatch",
+        "serve.step.prefill.fetch", "serve.step.decode",
+        "serve.step.decode.stage", "serve.step.decode.dispatch",
+        "serve.step.decode.fetch", "serve.step.decode.deliver",
+        "serve.request.queue", "serve.request.prefill",
+        "serve.request.decode"}
+    uids = set(run.requests)
+    assert {s.key for s in _named(run, "serve.step.prefill")} <= uids
+
+
+@pytest.mark.parametrize("kw", [dict(paged=False),
+                                dict(paged=True, block_size=8,
+                                     prefill_chunk=8, spec_k=3)],
+                         ids=["dense", "speculative"])
+def test_dense_and_speculative_steps_leave_the_same_span_tree(decoder, kw):
+    from distributed_tensorflow_tpu import obs
+
+    cfg, _, params = decoder
+    tracer = obs.Tracer(annotate=False)
+    eng = serve.ServeEngine(cfg, params, num_slots=2, tracer=tracer, **kw)
+    uids = [eng.submit([7, 8, 9, 7, 8, 9, 7, 8], max_new_tokens=6)
+            for _ in range(2)]
+    done = eng.run()
+    names = {s.name for s in tracer.events}
+    assert {"serve.step.prefill.dispatch", "serve.step.prefill.fetch",
+            "serve.step.decode.stage", "serve.step.decode.dispatch",
+            "serve.step.decode.fetch", "serve.step.decode.deliver",
+            "serve.request.decode"} <= names
+    decodes = [s for s in tracer.events if s.name == "serve.step.decode"]
+    assert all(s.attrs["slots"] >= 1 and s.attrs["kv_tokens"] > 0
+               for s in decodes)
+    # every decode step that made a call delivered what it fetched
+    assert len([s for s in tracer.events
+                if s.name == "serve.step.decode.deliver"]) == len(decodes)
+    assert {s.key for s in tracer.events
+            if s.name == "serve.request.decode"} == set(uids)
+    assert all(len(done[u].generated) == 6 for u in uids)
+
+
+def test_request_spans_only_on_the_tracers_own_clock(decoder):
+    """`Request.t_*` are readings of the engine's clock; the ring has one
+    time axis. An engine on another clock than its tracer's records no
+    `serve.request.*`; the step's spans, on the tracer's clock, stay."""
+    from distributed_tensorflow_tpu import obs
+
+    from distributed_tensorflow_tpu.resilience import FaultClock
+
+    cfg, _, params = decoder
+    fake = FaultClock()
+    for tracer, want in ((obs.Tracer(annotate=False), 0),
+                         (obs.Tracer(annotate=False, clock=fake), 3)):
+        eng = serve.ServeEngine(cfg, params, num_slots=1, clock=fake,
+                                tracer=tracer)
+        eng.submit([5, 6, 7], max_new_tokens=3)
+        eng.run()
+        names = [s.name for s in tracer.events]
+        assert len([n for n in names
+                    if n.startswith("serve.request.")]) == want
+        assert "serve.step.decode.fetch" in names

@@ -423,3 +423,60 @@ def test_scheduler_priority_victim_selection():
     assert eng2._youngest_resident(exclude=-1) == slots2[max(a, b)]
     eng.drain()
     eng2.drain()
+
+
+def test_drained_replica_leaves_its_span_ring_beside_the_flight_recorder(
+        tmp_path):
+    """The serving counterpart of `Trainer._dump_postmortem`: a replica
+    process that drains writes `spans-w<i>i<k>.jsonl` beside
+    `flightrec-w<i>i<k>.jsonl`: the steps phase by phase, each call's
+    size, each request's phases under its uid."""
+    import json
+    import os
+    import subprocess
+    import sys
+    import time
+
+    workdir = str(tmp_path)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "distributed_tensorflow_tpu.serve.replica",
+         "--workdir", workdir, "--index", "0", "--slots", "2",
+         "--block-size", "8", "--prefill-chunk", "8"],
+        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL, env=env)
+    handle = sf.SubprocessReplica(proc, workdir, 0, 0)
+    try:
+        handle.send({"rid": 1, "prompt": list(range(1, 20)),
+                     "max_new_tokens": 4})
+        deadline = time.monotonic() + 120
+        finished = False
+        while not finished and time.monotonic() < deadline:
+            assert proc.poll() is None, "the replica died"
+            finished = any(e.get("kind") == "finish"
+                           for e in handle.poll_output())
+            time.sleep(0.05)
+        assert finished
+        handle.request_drain()
+        assert proc.wait(timeout=60) == 0
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+    assert os.path.exists(os.path.join(workdir, "flightrec-w0i0.jsonl"))
+    with open(os.path.join(workdir, "spans-w0i0.jsonl")) as f:
+        header, *spans = [json.loads(line) for line in f]
+    assert header["spans"] == len(spans) and header["dropped"] == 0
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    # 19 prompt tokens in chunks of 8: three calls, each with its size
+    assert [s["attrs"]["q_tokens"] for s in by_name["serve.step.prefill"]] \
+        == [8, 8, 3]
+    assert all(s["attrs"]["kv_tokens"] <= s["attrs"]["kv_positions_walked"]
+               for s in by_name["serve.step.decode"])
+    uid = by_name["serve.step.prefill"][0]["key"]
+    phases = [by_name[f"serve.request.{p}"][0]
+              for p in ("queue", "prefill", "decode")]
+    assert [p["key"] for p in phases] == [uid] * 3
+    assert phases[0]["end"] == phases[1]["start"] \
+        and phases[1]["end"] == phases[2]["start"]

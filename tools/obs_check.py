@@ -58,7 +58,7 @@ def check(verbose: bool = True) -> list[str]:
     with tracer.span("check"):
         with tracer.span("inner"):
             pass
-    if [s.path for s in tracer.events] != ["check.inner", "check"]:
+    if [s.name for s in tracer.events] != ["check.inner", "check"]:
         failures.append(f"tracer span paths wrong: {list(tracer.events)}")
 
     text = obs.render(reg)
