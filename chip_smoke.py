@@ -9,8 +9,10 @@ on every visible chip:
    and depth, 224x224, bf16, 256 images per chip: a few steps, one cadence
    save mid-run, the runner's final eval, then a second ``run_workload``
    that restores from that directory and continues;
-2. ``run_workload("gpt_lm")`` — GPT-2-small width uncut at 1024 tokens
-   through the compiled flash-attention kernels (forward and backward);
+2. the compiled flash-attention kernels against the plain reference at
+   the benchmark's training call and at a padded non-causal one, then
+   ``run_workload("gpt_lm")`` — GPT-2-small width uncut at 1024 tokens
+   through those kernels (forward and backward);
 3. ``ServeEngine`` — eight-slot paged serving at the same width, the
    compiled paged-attention kernel against the plain-XLA path on the
    engine's live pool, and once more with ``spec_k=4``.
@@ -30,6 +32,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import glob
 import json
 import math
@@ -55,6 +58,10 @@ class Sizes:
     images_per_chip: int = 256
     gpt: tuple[str, ...] = ()
     seqs_per_chip: int = 8
+    #: (B, H, S, D), causal, padded kv_mask: the benchmark's training call
+    #: and a BERT-like padded one whose S is an odd multiple of 128
+    flash_calls: tuple = (((8, 16, 1024, 64), True, False),
+                          ((2, 16, 384, 64), False, True))
     serve_cache_dtype: str = "bfloat16"
     #: how far the first loss may sit from ln(classes) / ln(vocab): a
     #: forward pass that is wrong at full width shows here (the warm-up
@@ -79,6 +86,8 @@ class ToySizes(Sizes):
         "--model.xent_chunk=128", "--model.dtype=float32",
         "--data.vocab_size=512", "--data.seq_len=256")
     seqs_per_chip: int = 1
+    flash_calls: tuple = (((1, 2, 256, 64), True, False),
+                          ((2, 2, 128, 64), False, True))
     serve_cache_dtype: str = "float32"
     # a handful of toy-width examples is a noisy estimate of ln(classes)
     resnet_tol: float = 1.0
@@ -291,6 +300,49 @@ def gpt_leg(say, sizes: Sizes, n_chips: int, dump_dir: str) -> None:
           f"see chiprun_out/chip_smoke/gpt_step_mosaic_calls.txt")
 
 
+def flash_parity_leg(say, sizes: Sizes) -> None:
+    """The flash kernels, compiled (the interpreter checks none of Mosaic's
+    layouts), against the plain reference in float32 on the same bf16
+    inputs: the output and dq, dk, dv, each gap as the largest difference
+    over the reference's largest entry."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_tensorflow_tpu.ops import (
+        attention_reference, flash_attention)
+
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    for (B, H, S, D), causal, padded in sizes.flash_calls:
+        q, k, v, w = (
+            jax.random.normal(key, (B, H, S, D), jnp.bfloat16)
+            for key in jax.random.split(jax.random.PRNGKey(S), 4))
+        kv_mask = None
+        if padded:  # a ragged tail per row; the first row keeps every key
+            lens = S - (jnp.arange(B) * (S // 3 + 5)) % S
+            kv_mask = jnp.arange(S)[None, :] < lens[:, None]
+
+        def run(attend, q, k, v):
+            def loss(q, k, v):
+                out = attend(q, k, v, causal=causal, kv_mask=kv_mask)
+                return (f32(out) * f32(w)).sum(), out
+            return jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(q, k, v)
+
+        (_, out), grads = jax.jit(
+            functools.partial(run, flash_attention))(q, k, v)
+        (_, want), want_grads = jax.jit(functools.partial(
+            run, attention_reference))(f32(q), f32(k), f32(v))
+        gaps = {
+            name: float(jnp.abs(f32(a) - b).max() / jnp.abs(b).max())
+            for name, a, b in zip(("out", "dq", "dk", "dv"),
+                                  (out, *grads), (want, *want_grads))}
+        say(f"flash parity {(B, H, S, D)} causal={causal} padded={padded}: "
+            + " ".join(f"{n} {g:.2e}" for n, g in gaps.items()))
+        # bf16 holds 8 bits: an output rounded once reads ~4e-3 of the
+        # largest entry; a wrong tile or mask reads ~1
+        check(max(gaps.values()) < 3e-2,
+              f"flash kernels differ from the reference: {gaps}")
+
+
 def serve_requests(eng, resident=lambda: None):
     """Six greedy requests of 20-200 tokens, 32 new tokens each; the last
     shares a 64-token prefix with an earlier one and is submitted after
@@ -480,6 +532,7 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     with tempfile.TemporaryDirectory(prefix="chip_smoke_ckpt_") as ckpt:
         resnet_leg(say, sizes, n_chips, ckpt)
+    flash_parity_leg(say, sizes)
     gpt_leg(say, sizes, n_chips, dump_dir)
     serve_leg(say, sizes, dump_dir)
     say(f"compile cache: {entries()} entries at end ({before} at start); "

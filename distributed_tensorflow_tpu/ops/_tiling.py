@@ -1,11 +1,22 @@
-"""Shared VMEM-budget tile selection for the fused Pallas matmul kernels
-(ops/fused_conv_bn.py, ops/fused_ln_matmul.py)."""
+"""Shared VMEM-budget tile selection for the Pallas kernels
+(ops/fused_conv_bn.py, ops/fused_ln_matmul.py, ops/flash_attention.py)."""
 
 from __future__ import annotations
 
+import functools
+import logging
+from typing import NamedTuple
+
 import jax
 
+logger = logging.getLogger(__name__)
+
 VMEM_BUDGET = 10 * 1024 * 1024  # leave headroom under ~16 MB/core
+# for the pickers that account for every buffer and temporary of a step:
+# ~3 MB slack under the 16 MB scoped limit
+FULL_VMEM_BUDGET = 13 * 1024 * 1024
+FLASH_MAX_BLOCK = 512  # widest flash row block (a 512 x 512 f32 tile is 1 MB)
+FLASH_HEADS_PER_STEP = (4, 2, 1)  # heads sharing a grid step, in preference
 
 
 def on_tpu() -> bool:
@@ -30,6 +41,126 @@ def paged_attn_vmem_ok(S: int, block_size: int, D: int,
     resident = 3 * S * D * 4 + 2 * S * lanes * 4
     stream = 2 * 2 * block_size * D * 4
     return resident + stream <= VMEM_BUDGET
+
+
+class FlashTilePlan(NamedTuple):
+    """How the flash-attention kernels tile one call. A grid step owns
+    ``hb`` heads of one ``block_q`` (forward, dQ) or ``block_k`` (dKV) row
+    block and sweeps the other axis itself in chunks, over ``kv_span`` /
+    ``q_span`` resident rows (the whole sequence unless it does not fit)."""
+
+    block_q: int
+    block_k: int
+    hb: int
+    q_span: int
+    kv_span: int
+    resident: bool  # both spans cover their whole sequence
+    grid_steps: int  # of the forward call
+    computed_over_needed: float  # score elements computed / kept by the mask
+
+
+def _lane_pad(n: int, lanes: int = 128) -> int:
+    return -(-n // lanes) * lanes
+
+
+def flash_vmem_bytes(block_q: int, block_k: int, hb: int, q_span: int,
+                     kv_span: int, D: int, itemsize: int) -> int:
+    """Largest per-step VMEM footprint of the three flash kernels. Streamed
+    blocks are double-buffered, the head dim is padded to the lane width
+    (D = 64 costs what 128 does), [rows, 8] row statistics pad to a full
+    lane tile, and the score tile's temporaries are counted as three f32
+    tiles a head (the unmodelled scratch was the round-3 OOM). Mosaic's own
+    scoped allocation read 16.03 MB for the forward at (256, 256, hb 8) and
+    18.0 MB for dQ at (512, 256, hb 4), S 1024, D 64, bf16 (chip, PR 25);
+    this model (the largest of the three kernels) gives 23.1 and 18.9."""
+    row = _lane_pad(D) * itemsize
+    acc = _lane_pad(D) * 4
+    stat = 128 * 4  # one [.., 8]-wide f32 or int32 row, lane-padded
+    tiles = 3 * hb * block_q * block_k * 4
+    fwd = (2 * (hb * (2 * block_q * row + 2 * kv_span * row + block_q * stat)
+                + kv_span * stat)
+           + hb * (-(-D // 8) * 8 + 2 * 8) * block_q * 4)
+    dq = (2 * (hb * (3 * block_q * row + 2 * kv_span * row
+                     + 2 * block_q * stat) + 8 * kv_span * 4)
+          + hb * block_q * acc)
+    dkv = (2 * (hb * (4 * block_k * row + 2 * q_span * row
+                      + 2 * 8 * q_span * 4) + block_k * stat)
+           + 2 * hb * block_k * acc)
+    return max(fwd, dq, dkv) + tiles
+
+
+def _lane_blocks(S: int) -> list[int]:
+    """Row-block sizes a sequence of S admits, descending: the multiples of
+    the lane width that divide it, else S whole (short or odd S)."""
+    return [b for b in range(S // 128 * 128, 127, -128) if S % b == 0] or [S]
+
+
+def _largest_span(S: int, block: int, fits) -> int:
+    for span in range(S, block - 1, -block):
+        if S % span == 0 and fits(span):
+            return span
+    return block
+
+
+@functools.lru_cache(maxsize=None)
+def flash_tile_plan(B: int, H: int, Sq: int, Sk: int, D: int, itemsize: int,
+                    causal: bool, block_q: int | None = None,
+                    block_k: int | None = None) -> FlashTilePlan:
+    """Tiles for ``flash_attention`` from the call's shape alone.
+
+    Rule (from the sweep at (8, 16, 1024, 64) bf16 on a v5e, PERF.md §6
+    "PR 25"): a causal call computes 1 + max(block_q, block_k)/S of the
+    score elements it needs, so its blocks stay at or under a quarter of
+    the sequence; a non-causal call wastes nothing and takes the largest
+    block up to FLASH_MAX_BLOCK. As many heads share a grid step as the
+    budget allows: a chunk's heads are one batched matmul, and a chunk
+    of one 256 x 256 head spends as long on its matmuls' latency as on its
+    arithmetic. K/V (forward, dQ) and q/dO (dKV)
+    stay whole in VMEM where they fit, else the largest span that does
+    becomes a grid axis of its own. Explicit ``block_q``/``block_k`` (the
+    CPU tests force several chunks at small S) override only the blocks."""
+    budget = FULL_VMEM_BUDGET
+    vmem = functools.partial(flash_vmem_bytes, D=D, itemsize=itemsize)
+
+    def pick(S):
+        cap = FLASH_MAX_BLOCK
+        if causal:
+            cap = min(max(128, S // 4), cap)
+        blocks = _lane_blocks(S)
+        return next((b for b in blocks
+                     if b <= cap and vmem(b, b, 1, b, b) <= budget),
+                    blocks[-1])
+
+    block_q = block_q or pick(Sq)
+    block_k = block_k or pick(Sk)
+
+    def fits(hb, q_span, kv_span):
+        return vmem(block_q, block_k, hb, q_span, kv_span) <= budget
+
+    hb = next((h for h in FLASH_HEADS_PER_STEP
+               if H % h == 0 and fits(h, Sq, Sk)), 1)
+    q_span = _largest_span(Sq, block_q, lambda s: fits(hb, s, block_k))
+    kv_span = _largest_span(Sk, block_k, lambda s: fits(hb, q_span, s))
+    computed = 0
+    for i in range(Sq // block_q):
+        q_hi = (i + 1) * block_q - 1 + (Sk - Sq)
+        chunks = Sk // block_k
+        if causal:
+            chunks = min(max(q_hi + block_k, 0) // block_k, chunks)
+        computed += chunks * block_q * block_k
+    if causal:
+        needed = sum(min(max(r + 1 + Sk - Sq, 0), Sk) for r in range(Sq))
+    else:
+        needed = Sq * Sk
+    plan = FlashTilePlan(
+        block_q=block_q, block_k=block_k, hb=hb, q_span=q_span,
+        kv_span=kv_span, resident=(q_span == Sq and kv_span == Sk),
+        grid_steps=B * (H // hb) * (Sq // block_q) * (Sk // kv_span),
+        computed_over_needed=computed / max(needed, 1),
+    )
+    logger.info("flash_attention B=%d H=%d Sq=%d Sk=%d D=%d itemsize=%d "
+                "causal=%s: %s", B, H, Sq, Sk, D, itemsize, causal, plan)
+    return plan
 
 
 def pick_block_m(M: int, k: int, n: int, *, name: str) -> int:
@@ -81,7 +212,7 @@ def pick_dw_tiles(M: int, cin: int, cout: int, *, in_bytes: int,
     x, so fewer column tiles = less HBM traffic), then largest bm; bm is
     kept >= 128 where possible so the row-contraction feeds the MXU full
     tiles."""
-    budget = 13 * 1024 * 1024  # ~3 MB slack under the 16 MB scoped limit
+    budget = FULL_VMEM_BUDGET
 
     def tile_bytes(bm: int, bn: int) -> int:
         stream = 2 * (bm * cin * in_bytes + 2 * bm * bn * in_bytes)
@@ -138,7 +269,7 @@ def pick_single_pass_bm(M: int, cin: int, cout: int, *, in_bytes: int,
     round-3 OOM was exactly an unmodeled-scratch miss) and the in-dtype
     casts of h and g.
     """
-    budget = 13 * 1024 * 1024
+    budget = FULL_VMEM_BUDGET
     resident = (cin * cout * in_bytes          # w
                 + 2 * cin * cout * 4)          # dw accumulator + dot temp
 

@@ -7,30 +7,50 @@ reference framework dropped to hand-written CUDA for its hot ops
 (SURVEY.md §2b native rows), the TPU-native equivalent is a Pallas kernel
 compiled to Mosaic (SURVEY.md §5.8 native-code policy).
 
-Design (standard FlashAttention-2 tiling, adapted to TPU tiles):
+Design (FlashAttention-2, with the sweep inside the kernel):
 
-- Layout [B, H, S, D]: the grid iterates (batch, head, q-block, kv-block)
-  with the kv-block innermost; each kernel instance owns one
-  (block_q × D) output tile held in VMEM f32 scratch across the kv sweep,
-  with running max ``m`` and denominator ``l`` as (block_q × LANES)
-  broadcast-tiles (TPU scratch wants 2-D lane-aligned shapes).
+- Layout [B, H, S, D]. A grid step owns ``hb`` heads of one row block and
+  sweeps the other axis itself, in a rolled ``fori_loop`` over chunks of
+  operands that stay in VMEM: the forward and dQ own a ``block_q`` block of
+  queries and loop over ``block_k`` chunks of the heads' K/V; dKV owns a
+  ``block_k`` block of keys and loops over ``block_q`` chunks of the heads'
+  q/dO. The block index of the resident operands does not depend on the
+  owned block, so Pallas fetches them once per head group. Where a whole
+  sequence does not fit the VMEM budget (ring attention's long per-device
+  blocks) the largest span that does becomes a last grid axis, inside
+  which the same loop runs. ``ops/_tiling.flash_tile_plan`` picks all of
+  it from the shape. A one-tile-a-step grid was bound by the pipeline's
+  per-step cost, and one head's tile a chunk by the latency of its chain
+  of matmuls, which the rolled loop does not overlap: the ``hb`` heads of
+  a chunk are one batched matmul, independent work for the scheduler
+  (PERF.md §6 "PR 25").
+- Causal: the loop's trip count stops at the diagonal, so blocks above it
+  cost neither a step nor a DMA; only the chunks the diagonal crosses
+  build the triangular mask (a second loop over the same body).
+- The forward and dKV work on the transposed tile sᵀ = k·qᵀ [bk, bq]. In
+  the forward the running max ``m`` and denominator ``l`` are then
+  [1, bq] rows reduced over sublanes (as [bq, 1] columns each costs bq/8
+  vregs and their update as much as the tile's own arithmetic at
+  block_k = 256), and the accumulator is oᵀ, transposed once per block;
+  in dKV dV = pᵀ·dO and dK = dsᵀ·q are plain matmuls. All accumulators are
+  f32 VMEM scratch.
 - The forward also emits LSE = m + log l at sublane width
   ([B,H,Sq,STAT_DIM], STAT_DIM=8 — lane-broadcasting the row stat 128-wide
-  would cost 16× HBM for long sequences). The backward is two more pallas
-  calls (dKV with q-block innermost; dQ with kv-block innermost), the
-  FlashAttention-2 split that keeps every accumulator local to one grid
-  cell (no cross-instance atomics, which TPU does not have); each
-  recomputes delta = rowsum(dO·O) per tile instead of materializing it.
-- Causal masking skips fully-masked kv blocks via ``pl.when`` (no MXU work
-  issued), and applies the triangular mask inside diagonal blocks.
+  would cost 16x HBM for long sequences). The backward is two more pallas
+  calls, the FlashAttention-2 split that keeps every accumulator local to
+  one grid cell (no cross-instance atomics, which TPU does not have);
+  delta = rowsum(dO·O) is one plain reduction before them.
 - ``kv_mask`` [B, Sk] covers padding (BERT-style); mask semantics match
-  ops/attention.py (True = attend).
+  ops/attention.py (True = attend). A call without one compiles kernels
+  with no mask select.
 - On non-TPU backends ``interpret=True`` runs the same kernels through the
   Pallas interpreter — this is how CI (8 fake CPU devices, SURVEY.md §4.2)
   tests the exact kernel code path without TPU hardware.
 
-bf16 inputs are upcast per-tile; all accumulation is f32 (online-softmax
-numerics, SURVEY.md §7 "hard parts" #3).
+Matmul operands go to the MXU in the input dtype (bf16 q/k/v/dO as
+loaded, ``p`` and ``ds`` cast to it) with f32 accumulation; softmax, LSE,
+delta and every accumulator are f32 (online-softmax numerics, SURVEY.md §7
+"hard parts" #3). f32 inputs keep f32 operands.
 """
 
 from __future__ import annotations
@@ -43,10 +63,32 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._tiling import flash_tile_plan
 from .attention import NEG_INF
 
-LANES = 128  # TPU lane width (scratch row-stat tiles)
+LANES = 128  # TPU lane width
 STAT_DIM = 8  # f32 sublane width (HBM row-stat storage)
+
+
+def _dot_nt(a, b):
+    """a · bᵀ per head: [hb, m, d] x [hb, n, d] → [hb, m, n], f32."""
+    return jax.lax.dot_general(
+        a, b, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+
+
+def _dot_nn(a, b):
+    """a · b per head: [hb, m, n] x [hb, n, d] → [hb, m, d], f32."""
+    return jax.lax.dot_general(
+        a, b, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+
+
+def _dot_tn(a, b):
+    """aᵀ · b per head: [hb, n, d] x [hb, n, m] → [hb, d, m], f32."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
 
 
 def _on_tpu() -> bool:
@@ -59,12 +101,48 @@ def _dot(a, b, dims):
     )
 
 
-def _causal_mask(q_start, kj, block_q, block_k):
-    qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    kpos = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-    return kpos <= qpos
+def _loop(lo, hi, body):
+    """Rolled loop for its side effects on refs."""
+    jax.lax.fori_loop(lo, hi, lambda i, c: (body(i), c)[1], 0)
+
+
+def _kv_chunk_range(q_lo, block_q, kv_base, block_k, n_chunks):
+    """For query positions [q_lo, q_lo + block_q) over ``n_chunks`` key
+    chunks starting at position ``kv_base``: (chunks wholly at or below the
+    diagonal, chunks with any key a query may attend). The chunks between
+    the two need the triangular mask; those beyond the second, nothing."""
+    q_hi = q_lo + block_q - 1
+    need = jnp.minimum(
+        jnp.maximum(q_hi - kv_base + block_k, 0) // block_k, n_chunks)
+    full = jnp.minimum(jnp.maximum(q_lo - kv_base + 1, 0) // block_k, need)
+    return full, need
+
+
+def _q_chunk_range(k_lo, block_k, q_base, block_q, n_chunks):
+    """For key positions [k_lo, k_lo + block_k) over ``n_chunks`` query
+    chunks starting at position ``q_base``: (first chunk with a query that
+    may attend, first chunk wholly at or below the diagonal)."""
+    k_hi = k_lo + block_k - 1
+    first = jnp.minimum(jnp.maximum(k_lo - q_base, 0) // block_q, n_chunks)
+    full = jnp.clip(
+        (jnp.maximum(k_hi - q_base, 0) + block_q - 1) // block_q,
+        first, n_chunks)
+    return first, full
+
+
+def _tile_mask(kv_mask, causal_at, shape, q_dim):
+    """AND of the padding mask (already broadcastable to the [hb, rows,
+    cols] ``shape``, or None) and, where ``causal_at`` = (first query
+    position, first key position) is given, key <= query along dims
+    ``q_dim`` and the other of (1, 2); None when neither applies."""
+    if causal_at is None:
+        return kv_mask
+    q0, k0 = causal_at
+    shape = (1, *shape[1:])
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 3 - q_dim)
+    tri = kpos <= qpos
+    return tri if kv_mask is None else kv_mask & tri
 
 
 # ---------------------------------------------------------------------------
@@ -76,12 +154,22 @@ def _fwd_kernel(
     q_ref, k_ref, v_ref, mask_ref,
     o_ref, lse_ref,
     acc_ref, m_ref, l_ref,
-    *, sm_scale, causal, block_q, block_k, q_offset,
+    *, sm_scale, causal, has_mask, block_k, q_offset,
 ):
-    qi = pl.program_id(2)
-    kj = pl.program_id(3)
-    nk = pl.num_programs(3)
-    q_start = qi * block_q + q_offset
+    """On the transposed tile sᵀ = k·qᵀ [hb, bk, bq]: m and l are [1, bq]
+    rows reduced over sublanes, the accumulator is oᵀ [D, bq] (module
+    docstring)."""
+    block_q = q_ref.shape[2]
+    kv_span = k_ref.shape[2]
+    n_chunks = kv_span // block_k
+    qi, kj, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    q_lo = qi * block_q + q_offset
+    kv_base = kj * kv_span
+    if causal:
+        full, need = _kv_chunk_range(q_lo, block_q, kv_base, block_k,
+                                     n_chunks)
+    else:
+        full = need = n_chunks
 
     @pl.when(kj == 0)
     def _init():
@@ -89,187 +177,234 @@ def _fwd_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [bq, D]
-        k = k_ref[0, 0].astype(jnp.float32)  # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        logits = _dot(q, k, ((1,), (1,))) * sm_scale  # [bq, bk]
-        mask = mask_ref[0, 0].astype(jnp.bool_)[None, :]
-        if causal:
-            mask = mask & _causal_mask(q_start, kj, block_q, block_k)
-        logits = jnp.where(mask, logits, NEG_INF)
+    q = q_ref[0]  # [hb, bq, D]
 
-        m_prev = m_ref[...]  # [bq, LANES] (row stat broadcast over lanes)
-        l_prev = l_ref[...]
-        m_cur = logits.max(axis=1)[:, None]  # [bq, 1]
-        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-        # explicit zero under the mask: for fully-masked rows m stays
-        # NEG_INF and exp(NEG_INF - NEG_INF) would be 1, poisoning l
-        p = jnp.where(mask, jnp.exp(logits - m_new[:, :1]), 0.0)  # [bq, bk]
-        correction = jnp.exp(m_prev - m_new)  # [bq, LANES]
-        l_ref[...] = l_prev * correction + jnp.broadcast_to(
-            p.sum(axis=1)[:, None], l_prev.shape
-        )
-        acc_ref[...] = acc_ref[...] * correction[:, :1] + _dot(
-            p, v, ((1,), (0,))
-        )
+    def chunk(c, *, diagonal):
+        start = pl.multiple_of(c * block_k, block_k)
+        k = k_ref[0, :, pl.ds(start, block_k), :]  # [hb, bk, D]
+        v = v_ref[0, :, pl.ds(start, block_k), :]
+        logits = _dot_nt(k, q) * sm_scale  # [hb, bk, bq]
+        mask = _tile_mask(
+            mask_ref[:, pl.ds(start, block_k), :][:, :, :1] != 0
+            if has_mask else None,
+            (q_lo, kv_base + start) if diagonal else None, logits.shape, 2)
+        if mask is not None:
+            logits = jnp.where(mask, logits, NEG_INF)
+        m_prev = m_ref[...]  # [hb, 1, bq]
+        m_new = jnp.maximum(m_prev, logits.max(axis=1, keepdims=True))
+        p = jnp.exp(logits - m_new)  # [hb, bk, bq]
+        if mask is not None:
+            # explicit zero under the mask: for fully-masked rows m stays
+            # NEG_INF and exp(NEG_INF - NEG_INF) would be 1, poisoning l
+            p = jnp.where(mask, p, 0.0)
+        correction = jnp.exp(m_prev - m_new)  # [hb, 1, bq]
+        l_ref[...] = l_ref[...] * correction + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * correction + _dot_tn(
+            v, p.astype(v.dtype))  # [hb, D, bq]
         m_ref[...] = m_new
 
+    _loop(0, full, functools.partial(chunk, diagonal=False))
     if causal:
-        # skip kv blocks strictly above the diagonal band (no MXU work)
-        pl.when(kj * block_k <= q_start + block_q - 1)(compute)
-    else:
-        compute()
+        _loop(full, need, functools.partial(chunk, diagonal=True))
 
     @pl.when(kj == nk - 1)
     def _finalize():
-        l = l_ref[...][:, :1]  # [bq, 1]
+        l = l_ref[...]  # [hb, 1, bq]
         # all-masked rows (l==0) → zero output, lse = NEG_INF
         safe_l = jnp.maximum(l, 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = jnp.swapaxes(acc_ref[...] / safe_l, 1, 2).astype(
+            o_ref.dtype)
         lse = jnp.where(l > 0.0, m_ref[...] + jnp.log(safe_l), NEG_INF)
-        lse_ref[0, 0] = lse[:, :STAT_DIM].astype(lse_ref.dtype)
+        lse_ref[0] = jnp.swapaxes(
+            jnp.broadcast_to(lse, (lse.shape[0], STAT_DIM, block_q)), 1, 2
+        ).astype(lse_ref.dtype)
 
 
-def _fwd_call(
-    q, k, v, kv_mask, *, sm_scale, causal, q_offset, block_q, block_k,
-    interpret
-):
+def _kv_span_index(causal, plan, q_offset):
+    """(q block i, kv span j) → the kv span to fetch. A causal q block
+    needs no span past the one its last query sits in: later ones map onto
+    that one, so the pipeline sees an unchanged block and starts no DMA
+    (the kernel's loop runs no chunk there)."""
+    if not causal:
+        return lambda i, j: j
+
+    def index(i, j):
+        q_hi = (i + 1) * plan.block_q - 1 + q_offset
+        return jnp.minimum(j, jnp.maximum(q_hi // plan.kv_span, 0))
+    return index
+
+
+def _chunked_mask(kv_mask, plan):
+    """[B, 1, Sk] → [B, spans, chunks, block_k]: a chunk's mask is one row
+    of a block whose last two dims are whole (a dynamic sublane index; a
+    dynamic lane offset into [1, Sk] is not something Mosaic takes)."""
+    B = kv_mask.shape[0]
+    return kv_mask.reshape(
+        B, -1, plan.kv_span // plan.block_k, plan.block_k)
+
+
+def _column_mask(kv_mask):
+    """[B, 1, Sk] → [B, Sk, STAT_DIM]: keys along sublanes, for the kernels
+    whose tile is k·qᵀ."""
+    B, _, Sk = kv_mask.shape
+    return jnp.broadcast_to(kv_mask[:, 0, :, None], (B, Sk, STAT_DIM))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "causal", "has_mask", "q_offset", "plan", "interpret"))
+def _fwd_call(q, k, v, kv_mask, *, sm_scale, causal, has_mask, q_offset,
+              plan, interpret):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    grid = (B, H, Sq // block_q, Sk // block_k)
+    bq, bk, hb, span = plan.block_q, plan.block_k, plan.hb, plan.kv_span
+    kv_j = _kv_span_index(causal, plan, q_offset)
+    qspec = lambda b, h, i, j: (b, h, i, 0)  # noqa: E731
+    kspec = lambda b, h, i, j: (b, h, kv_j(i, j), 0)  # noqa: E731
 
-    kernel = functools.partial(
-        _fwd_kernel,
-        sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, q_offset=q_offset,
-    )
     out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(
+            _fwd_kernel, sm_scale=sm_scale, causal=causal,
+            has_mask=has_mask, block_k=bk, q_offset=q_offset),
+        grid=(B, H // hb, Sq // bq, Sk // span),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, j)),
+            pl.BlockSpec((1, hb, bq, D), qspec),
+            pl.BlockSpec((1, hb, span, D), kspec),
+            pl.BlockSpec((1, hb, span, D), kspec),
+            pl.BlockSpec((1, span, STAT_DIM),
+                         lambda b, h, i, j: (b, kv_j(i, j), 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec(
-                (1, 1, block_q, STAT_DIM), lambda b, h, i, j: (b, h, i, 0)
-            ),
+            pl.BlockSpec((1, hb, bq, D), qspec),
+            pl.BlockSpec((1, hb, bq, STAT_DIM), qspec),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, Sq, STAT_DIM), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
+            pltpu.VMEM((hb, D, bq), jnp.float32),
+            pltpu.VMEM((hb, 1, bq), jnp.float32),
+            pltpu.VMEM((hb, 1, bq), jnp.float32),
         ],
         interpret=interpret,
         name="flash_attention_fwd",
-    )(q, k, v, kv_mask)
+    )(q, k, v, _column_mask(kv_mask))
     return out, lse
 
 
 # ---------------------------------------------------------------------------
-# Backward: dKV kernel (kv block resident, q innermost) and
-#           dQ kernel (q block resident, kv innermost)
+# Backward: dKV kernel (kv block owned, q chunks swept) and
+#           dQ kernel (q block owned, kv chunks swept)
 # ---------------------------------------------------------------------------
 
 
-def _bwd_p_ds(q_ref, k_ref, v_ref, mask_ref, do_ref, o_ref, lse_ref,
-              *, sm_scale, causal, q_start, kj, block_q, block_k):
-    """Shared tile math: recompute p and ds for one (q-block, kv-block)."""
-    q = q_ref[0, 0].astype(jnp.float32)  # [bq, D]
-    k = k_ref[0, 0].astype(jnp.float32)  # [bk, D]
-    v = v_ref[0, 0].astype(jnp.float32)
-    do = do_ref[0, 0].astype(jnp.float32)  # [bq, D]
-    o = o_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0][:, :1]  # [bq, 1]
-    delta = jnp.sum(do * o, axis=1, keepdims=True)  # [bq, 1]
-
-    logits = _dot(q, k, ((1,), (1,))) * sm_scale  # [bq, bk]
-    mask = mask_ref[0, 0].astype(jnp.bool_)[None, :]
-    if causal:
-        mask = mask & _causal_mask(q_start, kj, block_q, block_k)
-    # p = exp(logits - lse); all-masked rows have lse=NEG_INF → force 0
-    p = jnp.where(mask, jnp.exp(logits - lse), 0.0)  # [bq, bk]
-    dp = _dot(do, v, ((1,), (1,)))  # [bq, bk]
-    ds = p * (dp - delta) * sm_scale
-    return q, do, p, ds
-
-
 def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, mask_ref, do_ref, o_ref, lse_ref,
+    q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref,
     dk_acc, dv_acc,
-    *, sm_scale, causal, block_q, block_k, q_offset,
+    *, sm_scale, causal, has_mask, block_q, q_offset,
 ):
-    kj = pl.program_id(2)
-    qi = pl.program_id(3)
-    nq = pl.num_programs(3)
-    q_start = qi * block_q + q_offset
+    """On the transposed tile sᵀ = k·qᵀ [hb, bk, bq]: dV = pᵀ·dO and
+    dK = dsᵀ·q are then plain matmuls, and lse/delta are [1, bq] rows."""
+    block_k = k_ref.shape[2]
+    q_span = q_ref.shape[2]
+    n_chunks = q_span // block_q
+    kj, qj, nq = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    k_lo = kj * block_k
+    q_base = qj * q_span + q_offset
+    if causal:
+        first, full = _q_chunk_range(k_lo, block_k, q_base, block_q,
+                                     n_chunks)
+    else:
+        first = full = 0
 
-    @pl.when(qi == 0)
+    @pl.when(qj == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def compute():
-        q, do, p, ds = _bwd_p_ds(
-            q_ref, k_ref, v_ref, mask_ref, do_ref, o_ref, lse_ref,
-            sm_scale=sm_scale, causal=causal, q_start=q_start, kj=kj,
-            block_q=block_q, block_k=block_k,
-        )
-        dv_acc[...] += _dot(p, do, ((0,), (0,)))  # pᵀ·dO → [bk, D]
-        dk_acc[...] += _dot(ds, q, ((0,), (0,)))  # dsᵀ·q → [bk, D]
+    k = k_ref[0]  # [hb, bk, D]
+    v = v_ref[0]
+    kv_mask = mask_ref[...][:, :, :1] != 0 if has_mask else None  # [1, bk, 1]
+
+    def chunk(c, *, diagonal):
+        start = pl.multiple_of(c * block_q, block_q)
+        q = q_ref[0, :, pl.ds(start, block_q), :]  # [hb, bq, D]
+        do = do_ref[0, :, pl.ds(start, block_q), :]
+        lse = lse_ref[0, :, 0, pl.ds(c, 1), :]  # [hb, 1, bq]
+        delta = delta_ref[0, :, 0, pl.ds(c, 1), :]
+        logits = _dot_nt(k, q) * sm_scale  # [hb, bk, bq]
+        p = jnp.exp(logits - lse)
+        mask = _tile_mask(
+            kv_mask, (q_base + start, k_lo) if diagonal else None,
+            logits.shape, 2)
+        if mask is not None:
+            # all-masked rows have lse = NEG_INF: force their p to 0
+            p = jnp.where(mask, p, 0.0)
+        dp = _dot_nt(v, do)  # [hb, bk, bq]
+        ds = p * (dp - delta)
+        dv_acc[...] += _dot_nn(p.astype(do.dtype), do)  # [hb, bk, D]
+        dk_acc[...] += _dot_nn(ds.astype(q.dtype), q)
 
     if causal:
-        pl.when(kj * block_k <= q_start + block_q - 1)(compute)
-    else:
-        compute()
+        _loop(first, full, functools.partial(chunk, diagonal=True))
+    _loop(full, n_chunks, functools.partial(chunk, diagonal=False))
 
-    @pl.when(qi == nq - 1)
+    @pl.when(qj == nq - 1)
     def _finalize():
-        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, mask_ref, do_ref, o_ref, lse_ref,
+    q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
     dq_ref,
     dq_acc,
-    *, sm_scale, causal, block_q, block_k, q_offset,
+    *, sm_scale, causal, has_mask, block_k, q_offset,
 ):
-    qi = pl.program_id(2)
-    kj = pl.program_id(3)
-    nk = pl.num_programs(3)
-    q_start = qi * block_q + q_offset
+    block_q = q_ref.shape[2]
+    kv_span = k_ref.shape[2]
+    n_chunks = kv_span // block_k
+    qi, kj, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    q_lo = qi * block_q + q_offset
+    kv_base = kj * kv_span
+    if causal:
+        full, need = _kv_chunk_range(q_lo, block_q, kv_base, block_k,
+                                     n_chunks)
+    else:
+        full = need = n_chunks
 
     @pl.when(kj == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def compute():
-        k = k_ref[0, 0].astype(jnp.float32)
-        _, _, _, ds = _bwd_p_ds(
-            q_ref, k_ref, v_ref, mask_ref, do_ref, o_ref, lse_ref,
-            sm_scale=sm_scale, causal=causal, q_start=q_start, kj=kj,
-            block_q=block_q, block_k=block_k,
-        )
-        dq_acc[...] += _dot(ds, k, ((1,), (0,)))  # [bq, D]
+    q = q_ref[0]  # [hb, bq, D]
+    do = do_ref[0]
+    lse = lse_ref[0][:, :, :1]  # [hb, bq, 1]
+    delta = delta_ref[0][:, :, :1]
 
+    def chunk(c, *, diagonal):
+        start = pl.multiple_of(c * block_k, block_k)
+        k = k_ref[0, :, pl.ds(start, block_k), :]  # [hb, bk, D]
+        v = v_ref[0, :, pl.ds(start, block_k), :]
+        logits = _dot_nt(q, k) * sm_scale  # [hb, bq, bk]
+        p = jnp.exp(logits - lse)
+        mask = _tile_mask(
+            mask_ref[0, :, pl.ds(c, 1), :] != 0 if has_mask else None,
+            (q_lo, kv_base + start) if diagonal else None, logits.shape, 1)
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        dp = _dot_nt(do, v)  # [hb, bq, bk]
+        ds = p * (dp - delta)
+        dq_acc[...] += _dot_nn(ds.astype(k.dtype), k)  # [hb, bq, D]
+
+    _loop(0, full, functools.partial(chunk, diagonal=False))
     if causal:
-        pl.when(kj * block_k <= q_start + block_q - 1)(compute)
-    else:
-        compute()
+        _loop(full, need, functools.partial(chunk, diagonal=True))
 
     @pl.when(kj == nk - 1)
     def _finalize():
-        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -278,88 +413,120 @@ def _bwd_dq_kernel(
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, kv_mask, sm_scale, causal, block_q, block_k, interpret,
+def _flash(q, k, v, kv_mask, sm_scale, causal, has_mask, plan, interpret,
            q_offset):
     out, _ = _fwd_call(
         q, k, v, kv_mask,
-        sm_scale=sm_scale, causal=causal, q_offset=q_offset,
-        block_q=block_q, block_k=block_k, interpret=interpret,
+        sm_scale=sm_scale, causal=causal, has_mask=has_mask,
+        q_offset=q_offset, plan=plan, interpret=interpret,
     )
     return out
 
 
-def _flash_fwd(q, k, v, kv_mask, sm_scale, causal, block_q, block_k,
+def _flash_fwd(q, k, v, kv_mask, sm_scale, causal, has_mask, plan,
                interpret, q_offset):
     out, lse = _fwd_call(
         q, k, v, kv_mask,
-        sm_scale=sm_scale, causal=causal, q_offset=q_offset,
-        block_q=block_q, block_k=block_k, interpret=interpret,
+        sm_scale=sm_scale, causal=causal, has_mask=has_mask,
+        q_offset=q_offset, plan=plan, interpret=interpret,
     )
     return out, (q, k, v, kv_mask, out, lse)
 
 
-def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, q_offset,
+def _flash_bwd(sm_scale, causal, has_mask, plan, interpret, q_offset,
                res, do):
     q, k, v, kv_mask, out, lse = res
+    dq, dk, dv = _bwd_call(
+        q, k, v, kv_mask, out, lse, do,
+        sm_scale=sm_scale, causal=causal, has_mask=has_mask,
+        q_offset=q_offset, plan=plan, interpret=interpret,
+    )
+    return dq, dk, dv, np.zeros(kv_mask.shape, jax.dtypes.float0)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "causal", "has_mask", "q_offset", "plan", "interpret"))
+def _bwd_call(q, k, v, kv_mask, out, lse, do, *, sm_scale, causal, has_mask,
+              q_offset, plan, interpret):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    common = dict(
-        sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, q_offset=q_offset,
-    )
+    bq, bk, hb = plan.block_q, plan.block_k, plan.hb
+    common = dict(sm_scale=sm_scale, causal=causal, has_mask=has_mask,
+                  q_offset=q_offset)
+    # once per call, not once per tile in each kernel; `out` goes no further
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
 
-    qspec = lambda b, h, j, i: (b, h, i, 0)  # noqa: E731
+    # dKV: owns kv block j, sweeps the q chunks of span i from the diagonal
+    # down; a causal kv block needs no q span before the one holding its
+    # first key's own query, so earlier spans map onto that one (no DMA)
+    span = plan.q_span
+    if causal:
+        q_i = lambda j, i: jnp.maximum(  # noqa: E731
+            i, jnp.maximum(j * bk - q_offset, 0) // span)
+    else:
+        q_i = lambda j, i: i  # noqa: E731
+    qspec = lambda b, h, j, i: (b, h, q_i(j, i), 0)  # noqa: E731
     kspec = lambda b, h, j, i: (b, h, j, 0)  # noqa: E731
+    rowspec = pl.BlockSpec(
+        (1, hb, 1, span // bq, bq),
+        lambda b, h, j, i: (b, h, q_i(j, i), 0, 0))
+    rows = lambda x: x.reshape(B, H, -1, span // bq, bq)  # noqa: E731
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **common),
-        grid=(B, H, Sk // block_k, Sq // block_q),
+        functools.partial(_bwd_dkv_kernel, block_q=bq, **common),
+        grid=(B, H // hb, Sk // bk, Sq // span),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), qspec),
-            pl.BlockSpec((1, 1, block_k, D), kspec),
-            pl.BlockSpec((1, 1, block_k, D), kspec),
-            pl.BlockSpec((1, 1, block_k), lambda b, h, j, i: (b, 0, j)),
-            pl.BlockSpec((1, 1, block_q, D), qspec),
-            pl.BlockSpec((1, 1, block_q, D), qspec),
-            pl.BlockSpec((1, 1, block_q, STAT_DIM), qspec),
+            pl.BlockSpec((1, hb, span, D), qspec),
+            pl.BlockSpec((1, hb, bk, D), kspec),
+            pl.BlockSpec((1, hb, bk, D), kspec),
+            pl.BlockSpec((1, bk, STAT_DIM), lambda b, h, j, i: (b, j, 0)),
+            pl.BlockSpec((1, hb, span, D), qspec),
+            rowspec,
+            rowspec,
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_k, D), kspec),
-            pl.BlockSpec((1, 1, block_k, D), kspec),
+            pl.BlockSpec((1, hb, bk, D), kspec),
+            pl.BlockSpec((1, hb, bk, D), kspec),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((hb, bk, D), jnp.float32),
+            pltpu.VMEM((hb, bk, D), jnp.float32),
         ],
         interpret=interpret,
         name="flash_attention_bwd_dkv",
-    )(q, k, v, kv_mask, do, out, lse)
+    )(q, k, v,
+      _column_mask(kv_mask), do, rows(lse[..., 0]), rows(delta))
 
-    qspec2 = lambda b, h, i, j: (b, h, i, 0)  # noqa: E731
-    kspec2 = lambda b, h, i, j: (b, h, j, 0)  # noqa: E731
+    # dQ: the forward's sweep
+    span = plan.kv_span
+    kv_j = _kv_span_index(causal, plan, q_offset)
+    qspec = lambda b, h, i, j: (b, h, i, 0)  # noqa: E731
+    kspec = lambda b, h, i, j: (b, h, kv_j(i, j), 0)  # noqa: E731
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **common),
-        grid=(B, H, Sq // block_q, Sk // block_k),
+        functools.partial(_bwd_dq_kernel, block_k=bk, **common),
+        grid=(B, H // hb, Sq // bq, Sk // span),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), qspec2),
-            pl.BlockSpec((1, 1, block_k, D), kspec2),
-            pl.BlockSpec((1, 1, block_k, D), kspec2),
-            pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, j)),
-            pl.BlockSpec((1, 1, block_q, D), qspec2),
-            pl.BlockSpec((1, 1, block_q, D), qspec2),
-            pl.BlockSpec((1, 1, block_q, STAT_DIM), qspec2),
+            pl.BlockSpec((1, hb, bq, D), qspec),
+            pl.BlockSpec((1, hb, span, D), kspec),
+            pl.BlockSpec((1, hb, span, D), kspec),
+            pl.BlockSpec((1, 1, span // bk, bk),
+                         lambda b, h, i, j: (b, kv_j(i, j), 0, 0)),
+            pl.BlockSpec((1, hb, bq, D), qspec),
+            pl.BlockSpec((1, hb, bq, STAT_DIM), qspec),
+            pl.BlockSpec((1, hb, bq, STAT_DIM), qspec),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, D), qspec2),
+        out_specs=pl.BlockSpec((1, hb, bq, D), qspec),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, bq, D), jnp.float32)],
         interpret=interpret,
         name="flash_attention_bwd_dq",
-    )(q, k, v, kv_mask, do, out, lse)
+    )(q, k, v, _chunked_mask(kv_mask, plan), do, lse,
+      jnp.broadcast_to(delta[..., None], lse.shape))
 
-    return dq, dk, dv, np.zeros(kv_mask.shape, jax.dtypes.float0)
+    return dq, dk, dv
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -530,52 +697,25 @@ def flash_attention(
     block sizes (callers pad + pass kv_mask; models/transformer.py does)."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    # env-tunable so on-chip sessions can sweep tile sizes without a code
-    # change (DTF_FLASH_BLOCK_Q/K); 128x128 is the safe default, larger K
-    # tiles cut grid overhead at long seq once measured. The env knobs are
-    # process-global and read at TRACE time, so a sweep value tuned for the
-    # bench shape must not break other call sites (e.g. Sq=384 under a
-    # 256 block): an env block that doesn't divide falls back to the 128
-    # default with a warning instead of raising — only an EXPLICIT
-    # block_q/block_k argument keeps the hard divisibility error.
-    import os
-
-    from_env_q = block_q is None and "DTF_FLASH_BLOCK_Q" in os.environ
-    from_env_k = block_k is None and "DTF_FLASH_BLOCK_K" in os.environ
-    if block_q is None:
-        block_q = int(os.environ.get("DTF_FLASH_BLOCK_Q", "128"))
-    if block_k is None:
-        block_k = int(os.environ.get("DTF_FLASH_BLOCK_K", "128"))
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    # fall back only when the env var was actually set AND the 128
-    # default would work — otherwise let the hard error below name the
-    # real problem (an unpadded sequence)
-    if from_env_q and Sq % block_q and Sq % min(128, Sq) == 0:
-        import warnings
-
-        warnings.warn(
-            f"DTF_FLASH_BLOCK_Q={block_q} does not divide Sq={Sq}; "
-            f"falling back to 128 for this call site")
-        block_q = min(128, Sq)
-    if from_env_k and Sk % block_k and Sk % min(128, Sk) == 0:
-        import warnings
-
-        warnings.warn(
-            f"DTF_FLASH_BLOCK_K={block_k} does not divide Sk={Sk}; "
-            f"falling back to 128 for this call site")
-        block_k = min(128, Sk)
-    if Sq % block_q or Sk % block_k:
-        raise ValueError(
-            f"seq lens ({Sq=}, {Sk=}) must be multiples of block sizes "
-            f"({block_q=}, {block_k=}); pad and pass kv_mask"
-        )
+    for name, block, S in (("block_q", block_q, Sq), ("block_k", block_k, Sk)):
+        if block is not None and S % min(block, S):
+            raise ValueError(
+                f"seq lens ({Sq=}, {Sk=}) must be multiples of block sizes "
+                f"({name}={block}); pad and pass kv_mask"
+            )
+    plan = flash_tile_plan(
+        B, H, Sq, Sk, D, q.dtype.itemsize, causal,
+        None if block_q is None else min(block_q, Sq),
+        None if block_k is None else min(block_k, Sk),
+    )
+    block_q, block_k = plan.block_q, plan.block_k
     if interpret is None:
         interpret = not _on_tpu()
     if not interpret:
         # Mosaic lane/sublane layout constraints (the interpreter has none):
-        # the kv-mask block's lane dim is block_k, the q tile's sublane dim
-        # is block_q. Sub-128 kv blocks would also waste the 128×128 MXU.
+        # a kv chunk's mask row has block_k lanes, a q chunk is a sublane
+        # slice of block_q rows. Sub-128 kv blocks would also waste the
+        # 128×128 MXU.
         if block_k % LANES and block_k != Sk:
             raise ValueError(
                 f"on TPU, block_k ({block_k}) must be a multiple of {LANES} "
@@ -586,11 +726,11 @@ def flash_attention(
                 f"on TPU, block_q ({block_q}) must be a multiple of "
                 f"{STAT_DIM} or equal to Sq ({Sq})"
             )
+    has_mask = kv_mask is not None
     if kv_mask is None:
         kv_mask = jnp.ones((B, 1, Sk), jnp.int32)
     else:
-        # bool refs are awkward on TPU; [B,1,Sk] keeps the block 3-D with a
-        # full-size middle dim (TPU tiling wants the 2nd-to-last dim full)
+        # bool refs are awkward on TPU
         kv_mask = kv_mask.astype(jnp.int32)[:, None, :]
     scale = sm_scale if sm_scale is not None else D**-0.5
     # causal alignment: last query attends the last key (self-attn; also
@@ -598,5 +738,5 @@ def flash_attention(
     # parallelism) cannot be a static kernel param — those paths use the
     # dense position-aware fallback in parallel/ring_attention.py.
     q_offset = Sk - Sq
-    return _flash(q, k, v, kv_mask, scale, causal, block_q, block_k,
+    return _flash(q, k, v, kv_mask, scale, causal, has_mask, plan,
                   interpret, q_offset)

@@ -131,22 +131,119 @@ def test_blockwise_fully_masked_rows_are_zero():
     np.testing.assert_allclose(out, np.zeros_like(out), atol=1e-6)
 
 
-def test_flash_env_block_fallback(monkeypatch):
-    # DTF_FLASH_BLOCK_Q/K are process-global trace-time knobs;
-    # a sweep value that doesn't divide some OTHER call site's seq len
-    # must fall back to the 128 default with a warning, not raise.
-    # 384 % 256 != 0 (and 256 < 384, so min() doesn't clamp it away),
-    # while the 128 fallback divides
-    q, k, v = make_qkv(jax.random.PRNGKey(7), B=1, H=2, S=384)
-    ref = attention_reference(q, k, v)
-    monkeypatch.setenv("DTF_FLASH_BLOCK_Q", "256")
-    monkeypatch.setenv("DTF_FLASH_BLOCK_K", "256")
-    with pytest.warns(UserWarning, match="falling back to 128"):
-        out = flash_attention(q, k, v)
-    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-    # an EXPLICIT non-dividing block argument still errors loudly
-    with pytest.raises(ValueError, match="multiples of block sizes"):
-        flash_attention(q, k, v, block_q=256, block_k=256)
+def _out_and_grads(attend, q, k, v, w, **kw):
+    """[out, dq, dk, dv] in float32 under the cotangent ``w``."""
+    def loss(q, k, v):
+        out = attend(q, k, v, **kw)
+        return (out.astype(jnp.float32) * w).sum(), out
+    (_, out), grads = jax.value_and_grad(
+        loss, (0, 1, 2), has_aux=True)(q, k, v)
+    return [np.asarray(x.astype(jnp.float32)) for x in (out, *grads)]
+
+
+def _masked(mask, B, Sk):
+    if mask == "padded":  # a ragged tail per batch row (BERT-style padding)
+        lens = np.array([Sk, Sk // 2 + 3])
+    elif mask == "row_fully_masked":  # nothing to attend to in batch row 1
+        lens = np.array([Sk, 0])
+    else:
+        return None
+    return jnp.asarray(np.arange(Sk)[None, :] < lens[:B, None])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("blocks", ["picked", "forced_chunks"])
+@pytest.mark.parametrize(
+    "Sq,Sk", [(128, 128), (384, 384), (512, 512), (256, 512)])
+@pytest.mark.parametrize("mask", ["none", "padded", "row_fully_masked"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_flash_parity_forward_and_grads(causal, mask, Sq, Sk, blocks, dtype):
+    """Output and dq, dk, dv against the O(S^2) reference, over the picker's
+    own tiles and over blocks that force several chunks either side of the
+    diagonal. bf16 inputs go to the matmuls as bf16 and are held to the
+    float32 reference at test_flash_bf16_close_to_f32_reference's tolerance."""
+    B, H, D = 2, 2, 64
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(Sq + Sk), 4)
+    q = jax.random.normal(kq, (B, H, Sq, D), dtype)
+    k = jax.random.normal(kk, (B, H, Sk, D), dtype)
+    v = jax.random.normal(kv, (B, H, Sk, D), dtype)
+    w = jax.random.normal(kw, (B, H, Sq, D), jnp.float32)  # the cotangent
+    kv_mask = _masked(mask, B, Sk)
+    forced = {} if blocks == "picked" else dict(
+        block_q=min(128, Sq // 2), block_k=min(128, Sk // 2))
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+
+    want = _out_and_grads(attention_reference, f32(q), f32(k), f32(v), w,
+                          causal=causal, kv_mask=kv_mask)
+    got = _out_and_grads(flash_attention, q, k, v, w,
+                         causal=causal, kv_mask=kv_mask, **forced)
+    tol = 1e-4 if dtype == jnp.float32 else 3e-2
+    rows = slice(0, 1) if mask == "row_fully_masked" else slice(None)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(
+            a[rows], b[rows], atol=tol, rtol=tol, err_msg=name)
+        if mask == "row_fully_masked":
+            # the contract, not the reference's (a softmax over nothing is
+            # uniform there): zero output, so nothing flows back either
+            np.testing.assert_array_equal(a[1], 0.0, err_msg=name)
+
+
+@pytest.mark.parametrize("block_q,block_k", [
+    (128, 128), (128, 256), (256, 128), (64, 128), (None, None)])
+@pytest.mark.parametrize("Sq,Sk", [(512, 512), (256, 512)])
+def test_flash_causal_loop_ends_on_the_diagonal(Sq, Sk, block_q, block_k):
+    """With q = 0 every kept key weighs exp(0) = 1, so row r is exactly
+    sum(v[:r+1]) / (r+1) with integer sums: a loop one chunk short loses a
+    whole chunk of keys for the rows of a block (row block_q*i would miss its
+    own key), one chunk long without the triangle lets row block_q*i - 1 see
+    past itself; either moves the count, and the comparison is exact."""
+    D = 64
+    q = jnp.zeros((1, 1, Sq, D), jnp.float32)
+    k = jax.random.normal(jax.random.PRNGKey(0), (1, 1, Sk, D), jnp.float32)
+    v = jnp.broadcast_to(
+        jnp.arange(1, Sk + 1, dtype=jnp.float32)[:, None], (1, 1, Sk, D))
+    out = flash_attention(
+        q, k, v, causal=True, block_q=block_q, block_k=block_k)
+    kept = np.arange(Sq) + (Sk - Sq) + 1  # keys row r may attend
+    want = (np.cumsum(np.arange(1, Sk + 1, dtype=np.float32))[kept - 1]
+            / kept.astype(np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(out)[0, 0], np.broadcast_to(want[:, None], (Sq, D)))
+
+
+@pytest.mark.parametrize("mask", ["none", "padded"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("Sq,Sk", [(512, 512), (256, 512)])
+def test_flash_spans_that_do_not_fit_vmem(monkeypatch, Sq, Sk, causal, mask):
+    """Ring attention's long per-device blocks: where a head's whole K/V
+    (forward, dQ) or q/dO (dKV) do not fit the budget, the largest span
+    that does becomes a grid axis and the same loops run inside it. A
+    budget of two chunks stands in for a long sequence."""
+    from distributed_tensorflow_tpu.ops import _tiling
+
+    B, H, D = 2, 2, 64
+    monkeypatch.setattr(_tiling, "FULL_VMEM_BUDGET", _tiling.flash_vmem_bytes(
+        128, 128, 1, 256, 256, D, 4))
+    _tiling.flash_tile_plan.cache_clear()
+    try:
+        plan = _tiling.flash_tile_plan(B, H, Sq, Sk, D, 4, causal)
+        assert not plan.resident and plan.kv_span == 256
+        kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(11), 4)
+        q = jax.random.normal(kq, (B, H, Sq, D))
+        k = jax.random.normal(kk, (B, H, Sk, D))
+        v = jax.random.normal(kv, (B, H, Sk, D))
+        w = jax.random.normal(kw, (B, H, Sq, D))
+        kv_mask = _masked(mask, B, Sk)
+
+        got, want = (
+            _out_and_grads(attend, q, k, v, w, causal=causal, kv_mask=kv_mask)
+            for attend in (flash_attention, attention_reference))
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4,
+                                       err_msg=name)
+    finally:
+        _tiling.flash_tile_plan.cache_clear()
 
 
 def test_paged_attention_impls_match_gather_oracle():

@@ -75,3 +75,53 @@ def test_resolve_bwd_impl_policy(monkeypatch):
     assert _tiling.resolve_bwd_impl("xla") == "xla"  # explicit arg wins
     with pytest.raises(ValueError, match="bwd_impl"):
         _tiling.resolve_bwd_impl("cuda")
+
+
+# --- flash attention tiles (flash_tile_plan) ------------------------------
+
+FLASH_S = (128, 256, 384, 512, 640, 1024, 2048, 4096, 8192)
+
+
+def test_flash_plan_for_the_benchmark_training_call():
+    """(8, 16, 1024, 64) bf16 causal: the grid was 8,192 steps a call, 44 %
+    of them above the diagonal."""
+    plan = _tiling.flash_tile_plan(8, 16, 1024, 1024, 64, 2, True)
+    assert plan.resident
+    assert plan.grid_steps <= 512
+    assert plan.computed_over_needed <= 1.3
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("S", [384, 640])
+def test_flash_blocks_divide_bert_padded_lengths(S, causal):
+    plan = _tiling.flash_tile_plan(8, 12, S, S, 64, 2, causal)
+    for block in (plan.block_q, plan.block_k):
+        assert S % block == 0 and block % 128 == 0
+    assert 12 % plan.hb == 0
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("S", FLASH_S)
+def test_flash_plan_fits_vmem_and_falls_back_only_when_it_must(S, D, causal):
+    plan = _tiling.flash_tile_plan(1, 8, S, S, D, 2, causal)
+    used = _tiling.flash_vmem_bytes(
+        plan.block_q, plan.block_k, plan.hb, plan.q_span, plan.kv_span, D, 2)
+    assert used <= _tiling.FULL_VMEM_BUDGET
+    assert S % plan.kv_span == 0 and plan.kv_span % plan.block_k == 0
+    assert S % plan.q_span == 0 and plan.q_span % plan.block_q == 0
+    # the kv-major axis engages exactly when a head's whole sequence does
+    # not fit beside the chosen tile
+    whole = _tiling.flash_vmem_bytes(
+        plan.block_q, plan.block_k, 1, S, S, D, 2)
+    assert plan.resident == (whole <= _tiling.FULL_VMEM_BUDGET)
+    assert plan.grid_steps == (
+        (8 // plan.hb) * (S // plan.block_q) * (S // plan.kv_span))
+
+
+def test_flash_plan_explicit_blocks_override_only_the_blocks():
+    plan = _tiling.flash_tile_plan(2, 4, 256, 512, 64, 4, True, 64, 128)
+    assert (plan.block_q, plan.block_k) == (64, 128)
+    assert plan.resident and 4 % plan.hb == 0
+    # Sq != Sk: the last query sits on the last key
+    assert 1.0 < plan.computed_over_needed < 1.3
