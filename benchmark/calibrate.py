@@ -38,7 +38,7 @@ def train(files, seeds, control_seeds, seconds, devices, say) -> list:
         t = time.perf_counter()
         win = train_driver.Window(cfg, job, seed, seconds, None, 0)
         result = program.run_training(
-            program.train_overrides(cfg, job, seed, len(devices)), win)
+            cfg, program.train_overrides(cfg, job, seed, len(devices)), win)
         del result
         ref_n = train_driver.reference_numbers(cfg, job, seed, win.batches,
                                                devices)
@@ -74,7 +74,7 @@ def serve(files, seeds, control_seeds, seconds, rates, say) -> list:
     from benchmark import harness, program, serve_driver
 
     cfg, mix = files["config"], dict(files["traffic"])
-    eng, calls = serve_driver.build(cfg, mix, seeds[0])
+    eng = serve_driver.build(cfg, mix, seeds[0])
     say({"setup_s": time.perf_counter() - harness.T_PROCESS_START})
     out = []
     if rates:
@@ -82,7 +82,7 @@ def serve(files, seeds, control_seeds, seconds, rates, say) -> list:
         for rate in rates:
             mix["rate_per_s"] = rate
             for seed in seeds:
-                got = serve_driver.drive(eng, calls, mix, seed, seconds)
+                got = serve_driver.drive(eng, mix, seed, seconds)
                 row = {"rate_per_s": rate, "seed": seed, **got["values"],
                        "ttft_p50_ms": got["ttft_p50_ms"],
                        "requests": got["requests"], "failed": got["failed"],
@@ -95,7 +95,7 @@ def serve(files, seeds, control_seeds, seconds, rates, say) -> list:
         t = time.perf_counter()
         eng.params = None
         eng.params = program.program_weights(cfg, seed)
-        got = serve_driver.drive(eng, calls, mix, seed, seconds)
+        got = serve_driver.drive(eng, mix, seed, seconds)
         numbers, notes = serve_driver.served_numbers(
             cfg, mix, seed, got["reqs"], got["done"])
         row = {"seed": seed, "program": _values(numbers), **notes,

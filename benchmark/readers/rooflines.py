@@ -2,15 +2,14 @@
 for the calls the trace shows (`counts.py`: the larger of FLOPs over peak
 FLOP/s and bytes over peak bytes/s), over the time the trace gives them."""
 
-from benchmark import counts, trace_reduce
+from benchmark import counts, families, trace_reduce
 from benchmark.readers import calls
 
 
 def _flash_shape(ctx):
-    cfg, job = ctx["cfg"], ctx["traffic"]
-    heads = cfg["n_head"]
-    return (job["sequences_per_chip"], heads, job["seq_len"],
-            cfg["n_embd"] // heads)
+    job = ctx["traffic"]
+    heads, head_dim = families.counts(ctx["cfg"]).attention_shape(ctx["cfg"])
+    return job["sequences_per_chip"], heads, job["seq_len"], head_dim
 
 
 def flash(ctx, kernels: list, count_calls_of: str, which: str):
@@ -40,9 +39,12 @@ def paged(ctx, kernel: str, prefill_module: str, decode_module: str):
     seconds, _ = trace_reduce.op_seconds(ctx["trace"], kernel)
     if not seconds:
         return None
-    layers = ctx["cfg"]["n_layer"]
-    byts = (layers * work["context_read"]
-            * counts.kv_bytes_per_token(ctx["cfg"]))
-    flops = 4.0 * ctx["cfg"]["n_embd"] * layers * work["attended"]
-    least = counts.roofline_seconds(flops, byts, ctx["device_kind"])
+    cfg, family = ctx["cfg"], families.counts(ctx["cfg"])
+    heads, head_dim = family.attention_shape(cfg)
+    flops, byts = counts.paged_attention(
+        heads * head_dim, family.kv_bytes_per_token(cfg), work["attended"],
+        work["context_read"])
+    layers = family.cache_layers(cfg)
+    least = counts.roofline_seconds(layers * flops, layers * byts,
+                                    ctx["device_kind"])
     return 100.0 * least / seconds

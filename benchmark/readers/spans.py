@@ -13,8 +13,7 @@ one's end) and returns None where the ring or the join is empty, as on a
 program without these spans.
 
 The spans come from `ctx["spans"]` where a test supplies them, else from the
-program's default tracer: the one import of the program under `benchmark/`
-besides `program.py`.
+program's ring as `program.span_ring()` hands it over.
 """
 
 import re
@@ -28,9 +27,9 @@ _ANNOTATION = re.compile(r"(.+)\.(\d+)")
 def ring(ctx) -> list:
     spans = ctx.get("spans")
     if spans is None:
-        from distributed_tensorflow_tpu import obs
+        from benchmark import program  # imports jax: not with this module
 
-        spans = list(obs.default_tracer().events)
+        spans = program.span_ring()
     # a ring of another layout (a program from before these spans) is no ring
     return [s for s in spans if hasattr(s, "attrs")]
 
@@ -77,6 +76,15 @@ class Mapped:
         lo, hi = self.window
         return [s for s in self.spans if s.name == name
                 and self.ns(s.start) >= lo and self.ns(s.end) <= hi]
+
+    def dispatched_in_trace(self, name: str) -> list:
+        """The spans that opened while the profiler ran and before the
+        device's last operation ended, in the order they opened: whatever
+        such a span sent to the device, the trace holds, and the spans of
+        the wait after the window lie past it."""
+        return sorted((s for s in self.spans if s.name == name
+                       and self.began <= self.ns(s.start) <= self.window[1]),
+                      key=lambda s: s.start)
 
     def before_trace(self, name: str) -> list:
         return [s for s in self.spans

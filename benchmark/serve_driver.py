@@ -26,59 +26,6 @@ def _p95(values) -> float:
     return float(np.percentile(np.asarray(values, np.float64), 95))
 
 
-class Calls:
-    """What the engine asked of the device: one record for every call of its
-    two compiled steps, read from the calls' own arguments, in call order.
-    The program has no spans or counters for this yet. The two steps are
-    wrapped in every run, so that traced and untraced runs reach the
-    compiled programs through the same frames (a Mosaic kernel carries its
-    call stack into the compile cache's key); only a `--trace 1` run
-    records, and an untraced run pays one Python call a step and reads
-    nothing from the device. The per-layer readers count needed FLOPs and
-    bytes from the records of the runs that the trace holds."""
-
-    def __init__(self, eng, recording: bool):
-        self.recording = recording
-        #: (kind, query tokens, keys attended summed over the query tokens,
-        #: live context the call reads)
-        self.records: list = []
-        #: most blocks of the pool that resident requests held at any call
-        #: since it was reset (blocks only the prefix cache holds are
-        #: evictable and do not count), and most blocks in use at all
-        self.pool_live_peak = self.pool_used_peak = 0
-        prefill, decode, oob = eng._prefill_chunk_fn, eng._decode, eng._oob
-        self.jitted = (prefill, decode)
-
-        def prefill_chunk(params, cache, table_row, buf, start, n):
-            out = prefill(params, cache, table_row, buf, start, n)
-            if self.recording:
-                # positions start..start+n-1 attend start+1..start+n keys
-                self.records.append(("prefill", int(n),
-                                     int(n * start + n * (n + 1) // 2),
-                                     int(start + n)))
-                self._note_pool(eng.alloc)
-            return out
-
-        def decode_step(params, cache, table, last, lens):
-            out = decode(params, cache, table, last, lens)
-            if self.recording:
-                live = np.asarray(lens)
-                live = live[live != oob]
-                self.records.append(("decode", int(live.size),
-                                     int((live + 1).sum()),
-                                     int((live + 1).sum())))
-                self._note_pool(eng.alloc)
-            return out
-
-        eng._prefill_chunk_fn, eng._decode = prefill_chunk, decode_step
-
-    def _note_pool(self, alloc) -> None:
-        used = alloc.blocks_in_use
-        self.pool_used_peak = max(self.pool_used_peak, used)
-        self.pool_live_peak = max(self.pool_live_peak,
-                                  used - alloc.evictable())
-
-
 def warm_up(eng, mix: dict, vocab: int) -> None:
     """Every shape the mix can reach, through the engine's own entry: one
     lone request per table-width bucket (its prefill walks the narrower
@@ -102,26 +49,24 @@ def warm_up(eng, mix: dict, vocab: int) -> None:
     eng.alloc.flush_prefix_cache()
 
 
-def build(cfg: dict, mix: dict, seed: int, record_calls: bool = False):
-    """Set-up: the engine on the seed's weights, every shape warmed. Returns
-    the engine and the wrapper around its two jitted steps."""
-    deploy = cfg["serving"]
-    if not mix["greedy"] or deploy["temperature"] != 0.0:
+def build(cfg: dict, mix: dict, seed: int):
+    """Set-up: the engine on the seed's weights, every shape warmed."""
+    if not mix["greedy"] or cfg["serving"]["temperature"] != 0.0:
         raise ValueError("the comparison with the reference needs greedy "
                          "tokens; mix greedy requests into a sampling cell")
-    eng = program.make_engine(cfg, deploy, seed)
-    calls = Calls(eng, record_calls)
+    eng = program.make_engine(cfg, seed)
     warm_up(eng, mix, mix["vocab"])
-    return eng, calls
+    return eng
 
 
-def drive(eng, calls: Calls, mix: dict, seed: int, seconds: float,
-          tracing=None) -> dict:
+def drive(eng, mix: dict, seed: int, seconds: float, tracing=None) -> dict:
     """One window of the mix on a warmed engine, then the wait for what was
     due in it. Returns what the users saw and what the readers need."""
     clock = eng.clock
-    compiled = _programs_compiled(calls)
-    calls.pool_live_peak = calls.pool_used_peak = 0
+    #: most blocks of the pool that resident requests held after any step
+    #: (blocks only the prefix cache holds are evictable and do not count),
+    #: and most blocks in use at all: the allocator's own counts
+    pool = {"live": 0, "used": 0}
     counters0 = _counters(eng)
     # the trace covers the END of the window: the engine is in its steady
     # state there, and the seconds `stop_trace` takes to write the trace
@@ -133,12 +78,18 @@ def drive(eng, calls: Calls, mix: dict, seed: int, seconds: float,
     else:
         reqs, source = [], traffic.stream(mix, seed, mix["vocab"])
 
-    jax.block_until_ready(eng.cache.k)
+    jax.block_until_ready(eng.cache)
     t0 = t_ready = clock()
     sent: dict = {}       # uid -> (index into reqs, seconds sent after t0)
-    calls_before_trace = 0
     tokens_in_window = 0
     nxt = 0
+
+    def step():
+        stats = eng.step()
+        used = eng.alloc.blocks_in_use
+        pool["used"] = max(pool["used"], used)
+        pool["live"] = max(pool["live"], used - eng.alloc.evictable())
+        return stats
 
     def offer(now: float) -> None:
         nonlocal nxt
@@ -166,10 +117,8 @@ def drive(eng, calls: Calls, mix: dict, seed: int, seconds: float,
         offer(now)
         if tracing is not None and tracing.t0 is None and now >= trace_from:
             tracing.start()
-            calls_before_trace = len(calls.records)
         if eng.sched.has_work:
-            with harness.annotate("bench.engine_step"):
-                stats = eng.step()
+            stats = step()
             if clock() - t0 <= seconds:
                 tokens_in_window += len(stats.tokens)
         else:
@@ -179,8 +128,6 @@ def drive(eng, calls: Calls, mix: dict, seed: int, seconds: float,
     t_close = clock()
     if tracing is not None and tracing.running:
         tracing.stop()
-    # the calls made so far are those whose runs the trace can hold
-    call_records = list(calls.records)
     backlog_at_close = len(eng.sched.queue) + sum(
         r is not None for r in eng.sched.slots)
     # requests that fell due while the last step ran are offered late; then
@@ -188,7 +135,7 @@ def drive(eng, calls: Calls, mix: dict, seed: int, seconds: float,
     if source is None:
         offer(seconds)
     while eng.sched.has_work and clock() - t_close < mix["drain_seconds"]:
-        eng.step()
+        step()
     finished = eng.sched.drain_finished()
     eng.alloc.flush_prefix_cache()
     counters1 = _counters(eng)
@@ -241,20 +188,26 @@ def drive(eng, calls: Calls, mix: dict, seed: int, seconds: float,
         "ttft_s": ttft_calm, "tpot_s": tpot_calm,
         "prefix_blocks_hit": counters1["prefix_reuse_hits_total"]
         - counters0["prefix_reuse_hits_total"],
-        "prompt_blocks": prompt_blocks}
-    if calls.recording:
-        reader_run.update(call_records=call_records,
-                          pool_live_peak=calls.pool_live_peak,
-                          pool_used_peak=calls.pool_used_peak,
-                          pool_blocks=eng.alloc.num_blocks)
+        "prompt_blocks": prompt_blocks, "pool_live_peak": pool["live"],
+        "pool_used_peak": pool["used"], "pool_blocks": eng.alloc.num_blocks}
+    # the program's ring shares the engine's clock. A program compiled in
+    # the window, or loaded from the compile cache there, is a shape that
+    # set-up did not warm (the reference's compiles come after the close)
+    ring = program.span_ring()
+    traced = tracing is not None and tracing.t1 is not None
     return {
         "t_ready": t_ready, "window_s": window_s, "values": values,
         "tokens_in_window": tokens_in_window, "reqs": reqs, "done": done,
         "requests": len(sent), "failed": failed,
         "backlog_at_close": backlog_at_close,
         "ttft_p50_ms": 1e3 * float(np.median(ttft)),
-        "compiled_in_window": _programs_compiled(calls) - compiled,
-        "calls_while_traced": len(call_records) - calls_before_trace,
+        "compiled_in_window": sum(
+            s.name == "compile.backend" and t0 <= s.start < t_close
+            for s in ring),
+        "steps_while_traced": {} if not traced else {
+            kind: sum(s.name == f"serve.step.{kind}"
+                      and tracing.t0 <= s.start < tracing.t1 for s in ring)
+            for kind in ("prefill", "decode")},
         "per_request": per_request,
         "reader_run": reader_run}
 
@@ -263,12 +216,12 @@ def run(files: dict, seed: int, seconds: float, trace: bool, devices,
         limits: dict) -> dict:
     cfg, mix, cell = files["config"], files["traffic"], files["cell"]
     t_build = time.perf_counter()
-    eng, calls = build(cfg, mix, seed, record_calls=trace)
+    eng = build(cfg, mix, seed)
     t_build = time.perf_counter() - t_build
     tracing = harness.Tracing(cell["name"]) if trace else None
-    got = drive(eng, calls, mix, seed, seconds, tracing)
+    got = drive(eng, mix, seed, seconds, tracing)
     device = harness.device_record(devices)
-    del eng, calls
+    del eng
     gc.collect()  # the engine holds cycles; its weights and pool go now
     got["values"]["setup_s"] = got["t_ready"] - harness.T_PROCESS_START
 
@@ -281,7 +234,7 @@ def run(files: dict, seed: int, seconds: float, trace: bool, devices,
                  engine_build_and_warm_up_seconds=t_build,
                  backlog_at_close=got["backlog_at_close"])
     if trace:  # to hold against the runs the trace's `XLA Modules` shows
-        notes.update(engine_calls_while_traced=got["calls_while_traced"])
+        notes.update(engine_steps_while_traced=got["steps_while_traced"])
     correct, rows = check.judge(
         numbers, limits,
         extra_ok=(got["failed"] == 0 and got["compiled_in_window"] == 0
@@ -310,12 +263,6 @@ def _counters(eng) -> dict:
     return {name: eng.registry.get(name).value
             for name in ("prefix_reuse_hits_total", "prefill_chunks_total",
                          "serve_tokens_total")}
-
-
-def _programs_compiled(calls: Calls) -> int:
-    """Programs the engine's two jitted steps hold: a number that grows
-    inside the window means a shape was not warmed."""
-    return sum(f._cache_size() for f in calls.jitted)
 
 
 def served_numbers(cfg: dict, mix: dict, seed: int, reqs: list, done: list,

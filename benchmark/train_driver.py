@@ -1,6 +1,6 @@
 """Traffic kind `train_job`: a training job through the program's own entry
-(`workloads.run_workload("gpt_lm")` -> `Trainer.fit` -> the compiled step),
-observed and steered from the callback seam.
+(`workloads.run_workload(<the family's workload>)` -> `Trainer.fit` -> the
+compiled step), observed and steered from the callback seam.
 
 Set-up builds ONE object, the compiled step with its state. The callback
 puts the benchmark's weights (made on the device from `--seed`) into that
@@ -19,14 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark import check, harness, program, reference
-
-
-def _leaf_norms(ref, tree) -> dict:
-    """Per-leaf norms of a program-layout tree, named as the reference
-    ``ref`` names them."""
-    return jax.device_get(jax.jit(
-        lambda t: ref.leaf_norms(program.from_program_tree(t)))(tree))
+from benchmark import check, families, harness, program, reference
 
 
 class Window(program.callback_base()):
@@ -35,6 +28,7 @@ class Window(program.callback_base()):
     def __init__(self, cfg, job, seed, seconds, tracing, trace_seconds):
         self.cfg, self.job, self.seed = cfg, job, seed
         self.ref = reference.for_config(cfg)
+        self.adapter = families.adapter(cfg)
         self.seconds, self.tracing = seconds, tracing
         self.trace_seconds = trace_seconds
         self.check_steps = job["check_steps"]
@@ -50,27 +44,22 @@ class Window(program.callback_base()):
         shardings = jax.tree.map(lambda x: x.sharding, trainer.state.params)
         params = program.program_weights(self.cfg, self.seed, shardings)
         trainer.state = trainer.state.replace(params=params)
-        put, step = trainer.put_batch, trainer.step_fn
+        put = trainer.put_batch
 
         def put_batch(batch):
             if len(self.batches) < self.check_steps:
                 self.batches.append(np.array(batch["input_ids"]))
-            with harness.annotate("bench.put_batch"):
-                return put(batch)
+            return put(batch)
 
-        def step_fn(state, batch):
-            with harness.annotate("bench.step_dispatch"):
-                return step(state, batch)
-
-        trainer.put_batch, trainer.step_fn = put_batch, step_fn
+        trainer.put_batch = put_batch
 
     def on_step_end(self, trainer, step, metrics):
         if step <= self.check_steps:
             self.read["losses"].append(float(metrics["loss"]))
             if step == 1:
                 self.t_first_step = time.perf_counter()
-                mu = _leaf_norms(self.ref,
-                                program.adam_mu(trainer.state.opt_state))
+                mu = self._leaf_norms(
+                    program.adam_mu(trainer.state.opt_state))
                 b1 = self.job["optimizer"]["b1"]
                 self.read["grad1"] = {k: v / (1.0 - b1) for k, v in mu.items()}
             if step == self.check_steps:
@@ -80,22 +69,31 @@ class Window(program.callback_base()):
                 if self.tracing is not None:
                     self.tracing.start()
             return
-        with harness.annotate("bench.on_step_end"):
-            # at most two steps in flight: the host still runs ahead of the
-            # device, and the window closes within two steps of its length
-            self._pending.append(metrics["loss"])
-            if len(self._pending) > 2:
-                self._pending.pop(0).block_until_ready()
-            now = time.perf_counter()
-            if (self.tracing is not None and self.tracing.running
-                    and now - self.t_open >= self.trace_seconds):
-                jax.block_until_ready(trainer.state)
-                self.tracing.stop()
-            if now - self.t_open >= self.seconds:
-                jax.block_until_ready(trainer.state)
-                self.t_close = time.perf_counter()
-                self.steps_in_window = step - self.check_steps
-                trainer.request_stop("benchmark window closed")
+        # at most two steps in flight: the host still runs ahead of the
+        # device, and the window closes within two steps of its length
+        self._pending.append(metrics["loss"])
+        if len(self._pending) > 2:
+            self._pending.pop(0).block_until_ready()
+        now = time.perf_counter()
+        if (self.tracing is not None and self.tracing.running
+                and now - self.t_open >= self.trace_seconds):
+            jax.block_until_ready(trainer.state)
+            self.tracing.stop()
+        if now - self.t_open >= self.seconds:
+            jax.block_until_ready(trainer.state)
+            self.t_close = time.perf_counter()
+            self.steps_in_window = step - self.check_steps
+            trainer.request_stop("benchmark window closed")
+
+    def _leaf_norms(self, tree, minus=None) -> dict:
+        """Per-leaf norms of a program-layout tree (less the tree ``minus``,
+        where given), named as the reference names them."""
+        def norms(t, m):
+            if m is not None:
+                t = jax.tree.map(jnp.subtract, t, m)
+            return self.ref.leaf_norms(self.adapter.from_program_tree(t))
+
+        return jax.device_get(jax.jit(norms)(tree, minus))
 
     def _param_change(self, trainer) -> dict:
         """Per-leaf norms of params(now) - params(seed), the seed's weights
@@ -103,9 +101,7 @@ class Window(program.callback_base()):
         params = trainer.state.params
         shardings = jax.tree.map(lambda x: x.sharding, params)
         w0 = program.program_weights(self.cfg, self.seed, shardings)
-        return jax.device_get(jax.jit(
-            lambda a, b: self.ref.leaf_norms(program.from_program_tree(
-                jax.tree.map(jnp.subtract, a, b))))(params, w0))
+        return self._leaf_norms(params, w0)
 
 
 def reference_numbers(cfg, job, seed, batches, devices, quant=None,
@@ -133,7 +129,7 @@ def run(files: dict, seed: int, seconds: float, trace: bool, devices,
     win = Window(cfg, job, seed, seconds, tracing,
                  min(job["trace_seconds"], seconds))
     result = program.run_training(
-        program.train_overrides(cfg, job, seed, n), win)
+        cfg, program.train_overrides(cfg, job, seed, n), win)
     if win.t_close is None:
         raise RuntimeError("the training loop ended before the window closed")
     setup_s = win.t_open - harness.T_PROCESS_START

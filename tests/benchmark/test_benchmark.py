@@ -5,11 +5,13 @@ comparison deciding `correct` passes the program and fails the control and
 each planted fault."""
 
 import copy
+import glob
 import json
 import os
 import re
 import subprocess
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -20,7 +22,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 BENCH = os.path.join(ROOT, "benchmark")
 
-from benchmark import check, counts, trace_reduce, traffic  # noqa: E402
+from benchmark import check, counts, families, trace_reduce, traffic  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -96,6 +98,45 @@ def test_each_per_layer_metric_moves_a_metric_its_cells_report():
                    for m in MANIFEST["per_layer"])
 
 
+def test_only_the_family_files_know_gpt2_and_only_the_adapters_the_program():
+    """The seam a later `model_config` PR stands on: a family is new files,
+    found by `model_type`; the shared files know no family's key names, and
+    only `program.py` and the adapters import the program."""
+    gpt2 = re.compile(r"n_embd|n_head|n_layer|n_inner|gelu_new|\"gpt_lm\"")
+    shared = glob.glob(os.path.join(BENCH, "*.py")) + glob.glob(
+        os.path.join(BENCH, "readers", "*.py"))
+    assert len(shared) > 15
+    for path in shared:
+        with open(path) as f:
+            assert not gpt2.search(f.read()), path
+    imports = re.compile(r"^\s*(from|import)\s+distributed_tensorflow_tpu",
+                         re.M)
+    # nor does anything reach into the engine or a jitted function
+    private = re.compile(r"\b(eng|engine|trainer)\._\w|\._cache_size\b"
+                         r"|call_records|class Calls")
+    for path in glob.glob(os.path.join(BENCH, "**", "*.py"), recursive=True):
+        rel = os.path.relpath(path, BENCH)
+        with open(path) as f:
+            text = f.read()
+        adapter = re.fullmatch(r"families/[^/]+/adapter\.py", rel)
+        if rel != "program.py" and not adapter:
+            assert not imports.search(text), rel
+        assert not private.search(text), rel
+    # the annotations that repeated a span of the program are gone
+    for name in ("bench.engine_step", "bench.put_batch",
+                 "bench.step_dispatch", "bench.on_step_end"):
+        for path in shared:
+            with open(path) as f:
+                assert name not in f.read(), (name, path)
+    for cfg in MANIFEST["configs"]:
+        body = load(ROOT, cfg["file"])
+        for kind in ("adapter", "counts"):
+            assert os.path.exists(os.path.join(
+                BENCH, "families", body["model_type"], f"{kind}.py"))
+        assert os.path.exists(os.path.join(
+            BENCH, "reference", f"{body['model_type']}.py"))
+
+
 # ---------------------------------------------------------------------------
 # trace reduction on a hand-built trace
 # ---------------------------------------------------------------------------
@@ -114,10 +155,30 @@ def hand_trace():
                 ("while.3", o + 45_000, o + 75_000),
                 (f"fusion.{20 + k}", o + 50_000, o + 70_000),
                 ("all-reduce.5", o + 60_000, o + 90_000)]
-        host.append(("bench.put_batch", o + 88_000, o + 100_000))
-        host.append(("bench.step_dispatch", o + 0, o + 30_000_000))
+        host.append((f"train.step.put_batch.{31 + k}", o + 88_000,
+                     o + 100_000))
+        host.append((f"train.step.dispatch.{41 + k}", o + 0, o + 30_000_000))
     return trace_reduce.Trace({"/device:TPU:0": {"ops": ops, "modules": mods}},
                               host)
+
+
+def ring_span(id, name, start_us, end_us, **attrs):
+    """A span of the program's ring as the readers see one, on a clock that
+    runs 3 s ahead of the trace's."""
+    return types.SimpleNamespace(
+        id=id, parent=None, name=name, start=3.0 + start_us * 1e-6,
+        end=3.0 + end_us * 1e-6, key=None, attrs=attrs)
+
+
+def with_host_events(ring, trace):
+    """The ring, and ``trace`` with the host event `<name>.<id>` that each
+    span leaves while the profiler runs (here: from the trace's first host
+    event on)."""
+    on = min((e[1] for e in trace.host), default=0)
+    host = [(f"{s.name}.{s.id}", round(1e9 * (s.start - 3.0)),
+             round(1e9 * (s.end - 3.0))) for s in ring
+            if 1e9 * (s.start - 3.0) >= on]
+    return ring, trace_reduce.Trace(trace.devices, trace.host + host)
 
 
 def test_trace_reduction_busy_kernel_and_exposed_collective_time():
@@ -141,7 +202,7 @@ def test_trace_reduction_busy_kernel_and_exposed_collective_time():
     assert trace_reduce.top_modules(t) == [
         ["jit_train_step", 2, pytest.approx(200e-6)]]
     gaps = dict(trace_reduce.idle_gaps(t))
-    assert gaps["bench.put_batch"] == pytest.approx(20e-6)  # 90..100, 0..10
+    assert gaps["train.step.put_batch"] == pytest.approx(20e-6)  # 90..100, 0..10
     one_chip = copy.deepcopy(t)
     for d in one_chip.devices.values():
         d["ops"] = [e for e in d["ops"] if not e[0].startswith("all-")]
@@ -166,8 +227,12 @@ def test_readers_return_nothing_where_there_is_nothing_to_read():
     # a kernel the trace does not hold gives no share, never 0
     assert rooflines.flash(ctx, ["flash_attention_bwd_dkv"],
                            "flash_attention_bwd_dkv", "bwd") is None
-    # a trace with no run of the serving programs gives no serving MFU
-    ctx["run"] = {"call_records": [("decode", 2, 30, 30)]}
+    # a trace with no run of the serving programs gives no serving MFU,
+    # whatever the ring holds
+    ctx["cfg"] = load(BENCH, "configs", "gpt2-xl.json")
+    ctx["spans"], ctx["trace"] = with_host_events(
+        [ring_span(7, "serve.step.decode", 20, 60, slots=2, kv_tokens=30)],
+        ctx["trace"])
     assert mfu.serve_step(ctx, **mods) is None
 
 
@@ -180,36 +245,47 @@ def test_mfu_takes_runs_and_seconds_from_the_trace():
     job = load(BENCH, "traffic", "train_1k.json")
     ctx = {"trace": hand_trace(), "run": {}, "cfg": cfg, "traffic": job,
            "chips": 1, "device_kind": "TPU v5 lite"}
-    flops = 2 * 8 * 1024 * counts.train_flops_per_token(cfg, 1024)
+    flops = 2 * 8 * 1024 * families.counts(cfg).train_flops_per_token(
+        cfg, 1024)
     assert mfu.train_step(ctx, "jit_train_step") == pytest.approx(
         100 * flops / (150e-6 * 197e12))
     # serving: the trace holds one decode run of 40 us busy and two prefill
-    # runs of 10 us; the records hold more calls than that (warm-up, the
-    # window before the profiler): the last ones of each kind are the runs
+    # runs of 10 us; the ring holds more steps than that (warm-up and the
+    # window before the profiler, the wait after the close): the spans that
+    # opened while the profiler ran and the device still worked are the runs
     xl = load(BENCH, "configs", "gpt2-xl.json")
     ops = [("paged_attention_fwd.1", 0, 10_000), ("fusion.2", 20_000, 30_000),
            ("paged_attention_fwd.3", 50_000, 90_000)]
     mods = [("jit_paged_prefill_chunk(1)", 0, 12_000),
             ("jit_paged_prefill_chunk(1)", 18_000, 31_000),
             ("jit_paged_decode_step(2)", 45_000, 95_000)]
-    trace = trace_reduce.Trace(
-        {"/device:TPU:0": {"ops": ops, "modules": mods}}, [])
-    records = [("prefill", 32, 528, 32), ("decode", 9, 999, 999),
-               ("prefill", 32, 528, 32), ("prefill", 8, 292, 40),
-               ("decode", 2, 300, 300)]
-    ctx = {"trace": trace, "run": {"call_records": records}, "cfg": xl,
+    ring = [
+        ring_span(1, "serve.step.prefill", -900, -800, q_tokens=32,
+                  attended=528, context=32),
+        ring_span(2, "serve.step.decode", -700, -100, slots=9, kv_tokens=999),
+        ring_span(3, "serve.step.prefill", -5, 8, q_tokens=32, attended=528,
+                  context=32),
+        ring_span(4, "serve.step.prefill", 9, 16, q_tokens=8, attended=292,
+                  context=40),
+        ring_span(5, "serve.step.decode", 17, 96, slots=2, kv_tokens=300),
+        ring_span(6, "serve.step.decode", 5000, 5100, slots=4, kv_tokens=77)]
+    spans, trace = with_host_events(ring, trace_reduce.Trace(
+        {"/device:TPU:0": {"ops": ops, "modules": mods}},
+        [("ProfilerStart", -50_000, -40_000)]))
+    ctx = {"trace": trace, "spans": spans, "run": {}, "cfg": xl,
            "traffic": {}, "chips": 1, "device_kind": "TPU v5 lite"}
     names = {"prefill_module": "jit_paged_prefill_chunk",
              "decode_module": "jit_paged_decode_step"}
-    want = counts.forward_flops(xl, 32 + 8 + 2, 528 + 292 + 300, 2 + 2)
+    gpt2 = families.counts(xl)
+    want = gpt2.forward_flops(xl, 32 + 8 + 2, 528 + 292 + 300, 2 + 2)
     assert mfu.serve_step(ctx, **names) == pytest.approx(
         100 * want / (60e-6 * 197e12))
     from benchmark.readers import rooflines
-    byts = xl["n_layer"] * (32 + 40 + 300) * counts.kv_bytes_per_token(xl)
+    byts = xl["n_layer"] * (32 + 40 + 300) * gpt2.kv_bytes_per_token(xl)
     assert rooflines.paged(ctx, "paged_attention_fwd", **names) == \
         pytest.approx(100 * (byts / 819e9) / 50e-6)
-    # fewer records than traced runs: nothing sound to read
-    ctx["run"] = {"call_records": records[:1]}
+    # fewer spans than traced runs: nothing sound to read
+    ctx["spans"] = [s for s in spans if s.id != 4]
     assert mfu.serve_step(ctx, **names) is None
 
 
@@ -233,13 +309,15 @@ def test_a_missing_or_misspelt_limit_is_an_error_not_a_pass():
 
 def test_counts_gpt2_medium_and_one_decode_step():
     cfg = load(BENCH, "configs", "gpt2-medium.json")
+    gpt2 = families.counts(cfg)
     # 24 x (4 x 1024^2 + 2 x 1024 x 4096) + 50304 x 1024 matmul parameters
-    assert counts.matmul_params(cfg) == 24 * 12_582_912 + 51_511_296
+    assert gpt2.matmul_params(cfg) == 24 * 12_582_912 + 51_511_296
     # 6 per matmul parameter + 3 x 24 x 2 x 1024 x 1024 causal attention
-    per_token = counts.train_flops_per_token(cfg, 1024)
+    per_token = gpt2.train_flops_per_token(cfg, 1024)
     assert per_token == 6 * 353_501_184 + 3 * 50_331_648
     assert per_token / 1e9 == pytest.approx(2.27, abs=0.005)
-    assert counts.param_count(cfg) == pytest.approx(355e6, rel=0.005)
+    assert gpt2.param_count(cfg) == pytest.approx(355e6, rel=0.005)
+    assert gpt2.attention_shape(cfg) == (16, 64)
     # flash forward at (8, 16, 1024, 64): 2 matmuls over half the square
     flops, byts = counts.flash_fwd(8, 16, 1024, 64)
     assert flops == 2 * 8 * 16 * 1024 * 1024 * 64
@@ -248,7 +326,10 @@ def test_counts_gpt2_medium_and_one_decode_step():
     # one paged decode step of gpt2-xl, 8 slots at 500 tokens of context:
     # per layer K and V of 4000 tokens x 1600 wide x 2 bytes each
     xl = load(BENCH, "configs", "gpt2-xl.json")
-    flops, byts = counts.paged_attention(xl, [500] * 8, q_tokens=1)
+    assert gpt2.attention_shape(xl) == (25, 64) and gpt2.cache_layers(xl) == 24
+    flops, byts = counts.paged_attention(
+        25 * 64, gpt2.kv_bytes_per_token(xl), attended=8 * 500 * 1,
+        context_read=8 * 500)
     assert byts == 2 * 1600 * 2 * 4000
     assert flops == 4 * 1600 * 4000
     assert counts.roofline_seconds(flops, byts, "TPU v5 lite") == \
@@ -384,6 +465,17 @@ def break_train_step(monkeypatch, fault, chips):
     monkeypatch.setattr(runner, "make_train_step", make)
 
 
+def on_chips(monkeypatch, devices, chips):
+    """The program builds its mesh over every device it is given: give it
+    the first ``chips`` of the rig's eight."""
+    from distributed_tensorflow_tpu.parallel import mesh as mesh_lib
+
+    real = mesh_lib.build_mesh
+    monkeypatch.setattr(
+        "distributed_tensorflow_tpu.workloads.runner.build_mesh",
+        lambda spec, devs=None: real(spec, devices[:chips]))
+
+
 @pytest.mark.parametrize("fault,chips", [
     (None, 4), ("state_unchanged", 1), ("half_batch", 1),
     ("no_exchange", 4)])
@@ -393,13 +485,7 @@ def test_training_run_is_correct_and_each_fault_is_not(
 
     if fault:
         break_train_step(monkeypatch, fault, chips)
-    if chips != len(devices):
-        # the program builds its mesh over every device it is given
-        from distributed_tensorflow_tpu.parallel import mesh as mesh_lib
-        real = mesh_lib.build_mesh
-        monkeypatch.setattr(
-            "distributed_tensorflow_tpu.workloads.runner.build_mesh",
-            lambda spec, devs=None: real(spec, devices[:chips]))
+    on_chips(monkeypatch, devices, chips)
     out = train_driver.run(tiny_files("train", chips), 2**31 + 5, 0.5, False,
                            devices[:chips], TRAIN_LIMITS)
     assert out["correct"] is (fault is None), out["checks"]
@@ -490,3 +576,91 @@ def test_backlog_stream_keeps_its_lengths():
     assert all(r.due_s is None for r in first)
     assert sorted(len(r.prompt) for r in first) == sorted(
         traffic.lengths(32, mix["prompt_tokens"]).tolist())
+
+
+# ---------------------------------------------------------------------------
+# a model family is new files: a second one, brought by this test alone
+# ---------------------------------------------------------------------------
+
+#: the toy family's key -> GPT-2's
+TOY_KEYS = {"depth": "n_layer", "width": "n_embd", "heads": "n_head",
+            "mlp_width": "n_inner", "context": "n_positions",
+            "act": "activation_function"}
+
+
+def toy_family(monkeypatch, tmp_path) -> dict:
+    """A family under `model_type: "toy"`: the GPT-2 block under other key
+    names. Its three modules (reference, adapter, counts) are injected where
+    the lookups by `model_type` find them and its configuration file is
+    written to ``tmp_path``; no file that is there is touched. Returns the
+    configuration as read back from its file."""
+    from benchmark.families.gpt2 import adapter, counts as gpt2_counts
+    from benchmark.reference import gpt2 as gpt2_reference
+
+    def as_gpt2(x):
+        if not (isinstance(x, dict) and x.get("model_type") == "toy"):
+            return x
+        return {TOY_KEYS.get(k, k): v for k, v in x.items()} | {
+            "model_type": "gpt2"}
+
+    def under_toy_keys(name, source):
+        module = types.ModuleType(name)
+        for key, value in vars(source).items():
+            if key.startswith("_"):
+                continue
+            if isinstance(value, types.FunctionType):
+                def value(*args, _f=value, **kw):
+                    return _f(*map(as_gpt2, args), **kw)
+            setattr(module, key, value)
+        monkeypatch.setitem(sys.modules, name, module)
+
+    under_toy_keys("benchmark.reference.toy", gpt2_reference)
+    under_toy_keys("benchmark.families.toy.adapter", adapter)
+    under_toy_keys("benchmark.families.toy.counts", gpt2_counts)
+    to_toy = {v: k for k, v in TOY_KEYS.items()}
+    cfg = {to_toy.get(k, k): v for k, v in tiny_config().items()} | {
+        "model_type": "toy"}
+    path = tmp_path / "toy.json"
+    path.write_text(json.dumps(cfg))
+    cfg = load(str(path))
+    assert not set(TOY_KEYS.values()) & set(cfg)
+    return cfg
+
+
+@pytest.mark.parametrize("kind", ["train", "serve"])
+def test_a_second_family_is_injected_modules_and_a_config_file(
+        monkeypatch, tmp_path, devices, kind):
+    files = tiny_files(kind)
+    files["config"] = toy_family(monkeypatch, tmp_path)
+    if kind == "train":
+        from benchmark import train_driver
+
+        on_chips(monkeypatch, devices, 1)
+        out = train_driver.run(files, 2**31 + 7, 0.5, False, devices[:1],
+                               TRAIN_LIMITS)
+        assert "train_tokens_per_s" in out["metrics"]
+    else:
+        from benchmark import serve_driver
+
+        out = serve_driver.run(files, 2**31 + 7, 2.0, False, devices[:1],
+                               {"served_gap_max": 1e-3})
+        assert out["failed"] == 0 and "tpot_p95_ms" in out["metrics"]
+    assert out["correct"] is True, out["checks"]
+    assert out["attempted"] > 0
+
+
+def test_the_yardstick_reads_a_second_family_through_its_own_counts(
+        monkeypatch, tmp_path):
+    from benchmark.readers import mfu, rooflines
+
+    toy = toy_family(monkeypatch, tmp_path)
+    job = load(BENCH, "traffic", "train_1k.json")
+    ctx = {"trace": hand_trace(), "run": {}, "cfg": toy, "traffic": job,
+           "chips": 1, "device_kind": "TPU v5 lite"}
+    as_gpt2 = dict(ctx, cfg=tiny_config())
+    got = mfu.train_step(ctx, "jit_train_step")
+    assert got is not None and got == mfu.train_step(as_gpt2, "jit_train_step")
+    args = (["flash_attention_fwd"], "flash_attention_fwd", "fwd")
+    assert rooflines.flash(ctx, *args) == rooflines.flash(as_gpt2, *args) > 0
+    with pytest.raises(ModuleNotFoundError):
+        families.counts({"model_type": "no_such_family"})

@@ -267,3 +267,146 @@ def test_manifest_lists_the_seven_span_metrics_last_and_finds_their_readers():
             {"per_layer": last}, cell, hand_ctx())
         assert len(got) == n and all(
             got[k]["value"] == pytest.approx(EXPECTED[k]) for k in got)
+
+
+# ---------------------------------------------------------------------------
+# the work of the traced runs: the span-fed `calls.traced` against the
+# records-fed reader it replaced (`serve_driver.Calls`, gone since PR 26)
+# ---------------------------------------------------------------------------
+
+MODULES = {"prefill_module": "jit_paged_prefill_chunk",
+           "decode_module": "jit_paged_decode_step"}
+
+
+def traced_from_records(trace, records):
+    """The reader as it was while `serve_driver.Calls` kept one record
+    (kind, query tokens, keys attended, context read) for every call of the
+    engine's two compiled steps, cut where the profiler was switched off:
+    the last records of each kind, as many as the trace holds runs."""
+    if not records:
+        return None
+    out = {"busy_s": 0.0, "attended": 0, "context_read": 0}
+    for kind in ("prefill", "decode"):
+        seconds, runs = trace_reduce.busy_in_runs(trace,
+                                                  MODULES[f"{kind}_module"])
+        mine = [r for r in records if r[0] == kind]
+        if runs > len(mine):
+            return None
+        mine = mine[len(mine) - runs:]
+        out["busy_s"] += seconds or 0.0
+        out[f"{kind}_calls"] = runs
+        out[f"{kind}_tokens"] = sum(r[1] for r in mine)
+        out["attended"] += sum(r[2] for r in mine)
+        out["context_read"] += sum(r[3] for r in mine)
+    if not out["prefill_tokens"] + out["decode_tokens"]:
+        return None
+    return out
+
+
+#: the engine's calls of one run, in call order: (kind, span start and end in
+#: us after the trace began, the device run's start and end or None where
+#: the trace holds no run of it, the counts). The profiler runs from 0 to
+#: 6000 us; the device works from 1000 to 5000 us.
+ENGINE_CALLS = [
+    # warm-up and the window before the profiler
+    ("prefill", -9000, -8000, None, (32, 528, 32)),
+    ("decode", -8000, -7000, None, (9, 999, 999)),
+    ("prefill", -700, -600, None, (8, 292, 40)),
+    # traced: a chunk that is not its prompt's last (no fetch: its run
+    # outlasts its span), a last chunk, a decode; then a decode-only step
+    ("prefill", 900, 1100, (1000, 1400), (32, 32 * 64 + 528, 96)),
+    ("prefill", 1100, 1900, (1400, 1800), (5, 5 * 96 + 15, 101)),
+    ("decode", 1900, 3000, (1900, 2900), (3, 300, 300)),
+    ("decode", 3100, 5050, (3200, 5000), (4, 417, 417)),
+    # the wait after the window: the profiler is off
+    ("decode", 9000, 9900, None, (4, 421, 421)),
+    ("prefill", 9900, 9990, None, (32, 528, 32)),
+]
+
+
+def serving_case(calls=ENGINE_CALLS, counts=True, offset_ns=7.5e12):
+    """(ctx for the span-fed reader, records for the records-fed one)."""
+    def ns(us):
+        return int(round(1e9 * (T0 + us * 1e-6) + offset_ns))
+
+    ring, records, mods, ops = [], [], [], []
+    host = [("ProfilerStart", ns(0), ns(100))]
+    for i, (kind, lo, hi, run, (tokens, attended, context)) in enumerate(
+            calls, start=100):
+        attrs = {} if not counts else (
+            {"q_tokens": tokens, "attended": attended, "context": context}
+            if kind == "prefill" else {"slots": tokens, "kv_tokens": attended})
+        ring.append(span(i, None, f"serve.step.{kind}", lo, hi, **attrs))
+        if lo < 6000:  # made before the profiler was switched off
+            records.append((kind, tokens, attended, context))
+        if 0 <= lo < 6000:
+            host.append((f"serve.step.{kind}.{i}", ns(lo) - 1500, ns(hi)))
+        if run is not None:
+            program = MODULES[f"{kind}_module"]
+            mods.append((f"{program}({i})", ns(run[0]), ns(run[1])))
+            ops.append((f"paged_attention_fwd.{i}", ns(run[0]) + 10_000,
+                        ns(run[1]) - 10_000))
+    ctx = {"trace": trace_reduce.Trace(
+        {"/device:TPU:0": {"ops": ops, "modules": mods}}, host),
+        "spans": ring, "run": {}, "cfg": {}, "traffic": {}, "chips": 1,
+        "device_kind": "TPU v5 lite"}
+    return ctx, records
+
+
+def without(kind_and_start, what):
+    """ENGINE_CALLS with one call's span (``what`` = "call") or device run
+    (``what`` = "run") taken away."""
+    out = []
+    for c in ENGINE_CALLS:
+        if (c[0], c[1]) == kind_and_start:
+            if what == "call":
+                continue
+            c = c[:3] + (None,) + c[4:]
+        out.append(c)
+    return out
+
+
+@pytest.mark.parametrize("case", [
+    "spans_match_runs", "other_clock_offset", "a_prefill_run_without_a_span",
+    "a_decode_run_without_a_span", "empty_ring", "ring_without_the_counts",
+    "last_span_sent_but_not_run"])
+def test_span_fed_work_of_the_traced_runs_against_the_records_fed(case):
+    from benchmark.readers import calls
+
+    ctx, records = serving_case(
+        offset_ns=-3.25e14 if case == "other_clock_offset" else 7.5e12)
+    if case == "a_prefill_run_without_a_span":
+        # the profiler caught a run that was sent before it came on
+        ring_ctx, _ = serving_case(without(("prefill", 900), "call"))
+        ctx["spans"], records = ring_ctx["spans"], records[:2]
+    elif case == "a_decode_run_without_a_span":
+        ring_ctx, _ = serving_case(without(("decode", 1900), "call"))
+        ctx["spans"], records = ring_ctx["spans"], [
+            r for r in records if r[0] == "prefill"] + records[-2:-1]
+    elif case == "empty_ring":
+        ctx["spans"], records = [], []
+    elif case == "ring_without_the_counts":
+        ctx["spans"], records = serving_case(counts=False)[0]["spans"], []
+    elif case == "last_span_sent_but_not_run":
+        # a chunk sent while the device still worked, whose run the
+        # profiler no longer caught: the records-fed reader took the LAST
+        # records and was off by one call; the spans keep their order
+        ctx, records = serving_case(
+            ENGINE_CALLS[:7] + [("prefill", 4950, 4990, None, (7, 735, 108))]
+            + ENGINE_CALLS[7:])
+    got = calls.traced(ctx, **MODULES)
+    want = traced_from_records(ctx["trace"], records)
+    if case in ("spans_match_runs", "other_clock_offset"):
+        assert want["prefill_calls"] == 2 and want["decode_calls"] == 2
+        assert got.pop("prefill_spans") == 2 and got.pop("decode_spans") == 2
+        assert got == want
+        assert got["prefill_tokens"] == 37 and got["decode_tokens"] == 7
+        assert got["attended"] == 32 * 64 + 528 + 5 * 96 + 15 + 717
+        assert got["context_read"] == 96 + 101 + 717
+        assert got["busy_s"] == pytest.approx((380 + 380 + 980 + 1780) * 1e-6)
+    elif case == "last_span_sent_but_not_run":
+        assert (got["prefill_spans"], got["prefill_calls"]) == (3, 2)
+        assert got["prefill_tokens"] == 37 and want["prefill_tokens"] == 12
+        assert got["context_read"] == 96 + 101 + 717
+    else:
+        assert got is None and want is None
