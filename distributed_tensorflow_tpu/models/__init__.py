@@ -9,6 +9,7 @@ from .mlp import MLP, MLPConfig  # noqa: F401
 from .cnn import CNN, CNNConfig  # noqa: F401
 from .resnet import ResNet, ResNet50, ResNetConfig  # noqa: F401
 from .wide_deep import WideDeep, WideDeepConfig  # noqa: F401
+from .olmo_hybrid import OlmoHybrid, OlmoHybridConfig  # noqa: F401
 from .transformer import (  # noqa: F401
     Transformer,
     TransformerConfig,

@@ -725,6 +725,39 @@ class Transformer(nn.Module):
             return logits + bias, new_cache
         return logits + bias
 
+    # -- what the paged engine asks of a model (serve/decode.py "the
+    #    serving protocol"): two steps over a block pool, and whether there
+    #    is recurrent state beside it ------------------------------------
+
+    #: no recurrent state: everything a request has lives in the K/V pool
+    has_state = False
+
+    @nn.nowrap
+    def prefill_chunk(self, params, cache, table_row, tokens, start, length,
+                      slot=None):
+        """One prefill chunk of one request through the block pool
+        (serve.decode.paged_prefill_chunk has the contract); ``slot`` is
+        for models with per-slot state and unused here."""
+        del slot
+        sentinel = table_row.shape[0] * cache.block_size
+        idx = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+        pos = jnp.where(idx < length, start + idx, sentinel)
+        logits, cache = self.apply(
+            {"params": params}, tokens[None], kv_cache=cache,
+            decode_pos=pos[None], block_table=table_row[None],
+        )
+        return logits[0, length - 1], cache
+
+    @nn.nowrap
+    def decode_step(self, params, cache, block_tables, tokens, lengths):
+        """One token for every slot over the block pool
+        (serve.decode.paged_decode_step has the contract)."""
+        logits, cache = self.apply(
+            {"params": params}, tokens[:, None], kv_cache=cache,
+            decode_pos=lengths[:, None], block_table=block_tables,
+        )
+        return logits[:, 0], cache
+
 
 # ---------------------------------------------------------------------------
 # Pipeline-parallel path (parallel/pipeline.py): same family, pipe layout

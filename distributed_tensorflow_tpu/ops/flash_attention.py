@@ -540,12 +540,14 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def _paged_fwd_kernel(
     bt_ref,  # scalar-prefetched block table [B, MB] (unused in the body —
     #          it drives the k/v index_maps; Pallas still passes it in)
-    q_ref, qpos_ref, k_ref, v_ref,
-    o_ref,
-    acc_ref, m_ref, l_ref,
-    *, sm_scale, block_size,
+    *refs,   # [layer_ref,] q, qpos, k, v, o, acc, m, l
+    sm_scale, block_size,
 ):
+    (*layer_ref, q_ref, qpos_ref, k_ref, v_ref, o_ref,
+     acc_ref, m_ref, l_ref) = refs
     del bt_ref
+    if layer_ref:  # pools with a layer axis in front: one more unit dim
+        k_ref, v_ref = k_ref.at[0], v_ref.at[0]
     j = pl.program_id(2)
     nb = pl.num_programs(2)
     S = q_ref.shape[2]
@@ -602,6 +604,7 @@ def paged_flash_attention(
     q_pos: jax.Array,
     sm_scale: float | None = None,
     interpret: bool | None = None,
+    layer: jax.Array | None = None,
 ) -> jax.Array:
     """Block-table-aware attention for paged decode — KV blocks are read
     IN PLACE from the pool (``k_pool``/``v_pool``
@@ -619,11 +622,16 @@ def paged_flash_attention(
     ``interpret=None`` auto-selects: compiled on TPU, Pallas interpreter
     elsewhere (slow; tests pin numerics against the gather path). On TPU
     the query tile pads to the f32 sublane width (padded rows get
-    ``q_pos = -1`` — attend nothing — and are sliced off)."""
+    ``q_pos = -1`` — attend nothing — and are sliced off).
+
+    With ``layer`` (a scalar) the pools are those of several layers,
+    ``[layers, num_blocks, H, block_size, D]``, and the kernel reads row
+    ``layer`` in place: a scan over layers carries one pool and never
+    slices a layer's copy out of it."""
     from ._tiling import pad_to_sublane, paged_attn_vmem_ok
 
     B, H, S, D = q.shape
-    NB, _, bs, _ = k_pool.shape
+    NB, _, bs, _ = k_pool.shape[-4:]
     MB = block_table.shape[1]
     if interpret is None:
         interpret = not _on_tpu()
@@ -642,17 +650,28 @@ def paged_flash_attention(
     # [B, Sp, LANES]: a (1, Sp) block of a [B, Sp] array would put 1 on
     # the sublane dim, neither a multiple of 8 nor the full extent
     qp = jnp.broadcast_to(qp[:, :, None], (B, Sp, LANES))
-    qspec = pl.BlockSpec((1, 1, Sp, D), lambda b, h, j, bt: (b, h, 0, 0))
-    kvspec = pl.BlockSpec(
-        (1, 1, bs, D),
-        lambda b, h, j, bt: (jnp.minimum(bt[b, j], NB - 1), h, 0, 0),
-    )
+    # the scalar-prefetched operands: the table and, where the pools have a
+    # layer axis, the layer; every index_map takes them after the grid ids
+    scalars = (block_table.astype(jnp.int32),)
+    if layer is None:
+        kvspec = pl.BlockSpec(
+            (1, 1, bs, D),
+            lambda b, h, j, bt: (jnp.minimum(bt[b, j], NB - 1), h, 0, 0),
+        )
+    else:
+        scalars += (jnp.asarray(layer, jnp.int32).reshape(1),)
+        kvspec = pl.BlockSpec(
+            (1, 1, 1, bs, D),
+            lambda b, h, j, bt, ly: (ly[0], jnp.minimum(bt[b, j], NB - 1),
+                                     h, 0, 0),
+        )
+    qspec = pl.BlockSpec((1, 1, Sp, D), lambda b, h, j, *_: (b, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(scalars),
         grid=(B, H, MB),
         in_specs=[
             qspec,
-            pl.BlockSpec((1, Sp, LANES), lambda b, h, j, bt: (b, 0, 0)),
+            pl.BlockSpec((1, Sp, LANES), lambda b, h, j, *_: (b, 0, 0)),
             kvspec,
             kvspec,
         ],
@@ -671,8 +690,98 @@ def paged_flash_attention(
         out_shape=jax.ShapeDtypeStruct((B, H, Sp, D), q.dtype),
         interpret=interpret,
         name="paged_attention_fwd",
-    )(block_table.astype(jnp.int32), q, qp, k_pool, v_pool)
+    )(*scalars, q, qp, k_pool, v_pool)
     return out[:, :, :S]
+
+
+def _paged_write_kernel(layer_ref, bid_ref, lo_ref, hi_ref, new_ref,
+                        pool_ref, out_ref):
+    """One touched block of one sequence, every head: rows ``lo <= r < hi``
+    of the block take the new K (or V), the others keep what they held."""
+    del layer_ref, bid_ref
+    b, j = pl.program_id(0), pl.program_id(1)
+    old = pool_ref[0, 0]                                  # [H, bs, D]
+    row = jax.lax.broadcasted_iota(jnp.int32, old.shape, 1)
+    new = jnp.broadcast_to(new_ref[0], old.shape)         # one row or bs rows
+    out_ref[0, 0] = jnp.where((row >= lo_ref[b, j]) & (row < hi_ref[b, j]),
+                              new, old)
+
+
+def paged_write_kv(
+    pool: jax.Array,
+    new: jax.Array,
+    block_table: jax.Array,
+    pos: jax.Array,
+    *,
+    layer: jax.Array,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Write ``new`` [B, H, S, D] into row ``layer`` of ``pool``
+    [layers, num_blocks, H, block_size, D], IN PLACE (the pool is aliased
+    to the output), through ``block_table`` [B, max_blocks]: the token at
+    absolute position ``pos[b, s]`` lands in physical block
+    ``block_table[b, p // block_size]`` at offset ``p % block_size``.
+
+    The padding contract is ``ops.attention.paged_append_kv``'s: a position
+    past the table or a table entry ``>= num_blocks`` writes nothing.
+    ``num_blocks`` is the pool's block count LESS ONE: the pool's last
+    physical block is a write-off block that no table names. Every grid
+    step reads a block and writes it back, so a step with nothing to write
+    needs a block that no other step of the call writes: sent to a real
+    block, its stale copy (fetched while an earlier step was still
+    computing) would land on top of that step's new row.
+    Either one token a sequence (decode), or ``S`` a multiple of the block
+    size with each sequence's positions consecutive from a block boundary
+    and the padding last (a prefill chunk).
+
+    A kernel rather than a scatter or ``dynamic_update_slice``: with those
+    XLA keeps a pool that a scan carries in the layout the write likes and
+    copies all of it back to the kernels' layout every layer."""
+    _, NB, H, bs, D = pool.shape
+    NB -= 1  # the last physical block takes the writes that go nowhere
+    B, _, S, _ = new.shape
+    MB = block_table.shape[1]
+    if S != 1 and S % bs:
+        raise ValueError(f"a write of {S} tokens a sequence is neither one "
+                         f"token nor whole blocks of {bs}")
+    if interpret is None:
+        interpret = not _on_tpu()
+    pos = pos.astype(jnp.int32)
+    n = max(S // bs, 1)
+    lb = pos[:, :1] // bs + jnp.arange(n)[None]            # [B, n] logical
+    bid = jnp.where(
+        lb < MB,
+        jnp.take_along_axis(block_table, jnp.clip(lb, 0, MB - 1), axis=1), NB)
+    live = bid < NB
+    if S == 1:
+        lo = jnp.where(live, pos % bs, 0)
+        hi = jnp.where(live, pos % bs + 1, 0)
+    else:
+        # real positions come first: count them block by block
+        real = (pos < MB * bs).reshape(B, n, bs).sum(-1)
+        lo, hi = jnp.zeros_like(real), jnp.where(live, real, 0)
+    rows = min(S, bs)
+    spec = pl.BlockSpec(
+        (1, 1, H, bs, D),
+        lambda b, j, ly, bid, lo, hi: (ly[0], bid[b, j], 0, 0, 0))
+    return pl.pallas_call(
+        _paged_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, n),
+            in_specs=[
+                pl.BlockSpec((1, H, rows, D), lambda b, j, *_: (b, 0, j, 0)),
+                spec,
+            ],
+            out_specs=spec,
+        ),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={5: 0},
+        interpret=interpret,
+        name="paged_kv_write",
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.minimum(bid, NB).astype(jnp.int32), lo.astype(jnp.int32),
+      hi.astype(jnp.int32), new.astype(pool.dtype), pool)
 
 
 def flash_attention(

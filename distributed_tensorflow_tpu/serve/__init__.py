@@ -38,11 +38,14 @@ from .kv_cache import (  # noqa: F401
     CACHE_LOGICAL,
     PAGED_CACHE_LOGICAL,
     BlockAllocator,
+    HybridCache,
     KVCache,
     NoFreeBlocks,
     PagedKVCache,
+    SnapshotTable,
     cache_specs,
     init_cache,
+    init_hybrid_cache,
     init_paged_cache,
     paged_cache_specs,
     shard_cache,

@@ -15,6 +15,14 @@ The two jit units of the serving engine:
   regardless of which subset of slots is live, which is the continuous-
   batching contract: admission/eviction never triggers a recompile.
 
+**The serving protocol.** The paged functions below do not know a model
+by name: they ask ``model.prefill_chunk(params, cache, table_row, tokens,
+start, length, slot)`` and ``model.decode_step(params, cache,
+block_tables, tokens, lengths)``, and the engine asks ``model.has_state``
+(recurrent state beside the K/V pool: snapshots for prefix reuse, no
+speculation). ``models.transformer.Transformer`` and
+``models.olmo_hybrid.OlmoHybrid`` answer it.
+
 Numerics: the cache path runs the same f32 masked softmax(QKᵀ)V as the
 dense reference (ops.attention.cached_attention docstring), so cached
 decode logits match the uncached full-context forward — asserted to
@@ -31,7 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..models.transformer import Transformer
-from .kv_cache import KVCache, PagedKVCache
+from .kv_cache import HybridCache, KVCache, PagedKVCache
 
 
 def prefill(
@@ -120,6 +128,7 @@ def paged_prefill_chunk(
     tokens: jax.Array,
     start: jax.Array,
     length: jax.Array,
+    slot: jax.Array | None = None,
 ) -> tuple[jax.Array, PagedKVCache]:
     """One fixed-size prefill chunk of ONE request: ``tokens`` [C] int32
     (chunk, zero-padded past ``length``) at absolute positions
@@ -133,16 +142,15 @@ def paged_prefill_chunk(
     prefill programs — one compiled program per TABLE-width bucket
     covers every prompt length (the engine trims ``table_row`` to the
     power-of-two width covering the slot's live blocks, so short
-    prompts attend far fewer positions than ``max_blocks``)."""
-    C = tokens.shape[0]
-    sentinel = table_row.shape[0] * cache.block_size
-    idx = jnp.arange(C, dtype=jnp.int32)
-    pos = jnp.where(idx < length, start + idx, sentinel)
-    logits, cache = model.apply(
-        {"params": params}, tokens[None], kv_cache=cache,
-        decode_pos=pos[None], block_table=table_row[None],
-    )
-    return logits[0, length - 1], cache
+    prompts attend far fewer positions than ``max_blocks``).
+
+    A model with recurrent layers (``has_state``) also needs the request's
+    ``slot``: the chunk advances that slot's recurrent state by ``length``
+    tokens, over a ``HybridCache``. The model does the step
+    (``model.prefill_chunk``): this function is the one name the engine
+    compiles whatever the model."""
+    return model.prefill_chunk(params, cache, table_row, tokens, start,
+                               length, slot)
 
 
 def paged_decode_step(
@@ -157,12 +165,9 @@ def paged_decode_step(
     [num_slots] is each slot's write position; idle and mid-prefill
     slots carry a past-the-table sentinel instead, so their garbage
     token writes NOTHING (a mid-prefill slot's frontier may sit in a
-    COW-shared block that a stray write must not touch)."""
-    logits, cache = model.apply(
-        {"params": params}, tokens[:, None], kv_cache=cache,
-        decode_pos=lengths[:, None], block_table=block_tables,
-    )
-    return logits[:, 0], cache
+    COW-shared block that a stray write must not touch); a model with
+    recurrent layers keeps such a slot's state as it was."""
+    return model.decode_step(params, cache, block_tables, tokens, lengths)
 
 
 def paged_verify_step(
@@ -206,6 +211,34 @@ def copy_block(
         k=cache.k.at[:, dst].set(cache.k[:, src]),
         v=cache.v.at[:, dst].set(cache.v[:, src]),
     )
+
+
+def take_snapshot(cache: HybridCache, slot: jax.Array,
+                  row: jax.Array) -> HybridCache:
+    """Copy ``slot``'s recurrent state and convolution window, every
+    linear layer's, into snapshot row ``row``."""
+    return dataclasses.replace(
+        cache,
+        snap_state=cache.snap_state.at[:, row].set(cache.state[:, slot]),
+        snap_conv=cache.snap_conv.at[:, row].set(cache.conv[:, slot]))
+
+
+def restore_snapshot(cache: HybridCache, slot: jax.Array,
+                     row: jax.Array) -> HybridCache:
+    """The inverse: a request admitted into ``slot`` behind a cached
+    prefix starts from the state snapshot row ``row`` holds."""
+    return dataclasses.replace(
+        cache,
+        state=cache.state.at[:, slot].set(cache.snap_state[:, row]),
+        conv=cache.conv.at[:, slot].set(cache.snap_conv[:, row]))
+
+
+def jit_take_snapshot():
+    return jax.jit(take_snapshot, donate_argnums=(0,))
+
+
+def jit_restore_snapshot():
+    return jax.jit(restore_snapshot, donate_argnums=(0,))
 
 
 def jit_paged_prefill_chunk(model: Transformer):

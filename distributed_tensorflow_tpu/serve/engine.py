@@ -19,7 +19,9 @@ is the serving analog of one train step:
 3. **Decode.** One fused decode step advances every decode-ready slot
    by one token ([num_slots, 1] inputs — idle and mid-prefill slots
    compute garbage that is never delivered and, on the paged path,
-   write through an out-of-bounds sentinel so it lands nowhere).
+   write through an out-of-bounds sentinel so it lands nowhere; the
+   same sentinel keeps the recurrent state of such a slot as it was,
+   for a model that has one).
 4. **Deliver + evict.** Sampled tokens are appended via the scheduler,
    which evicts finished requests (EOS / max-new / max-len) so their
    slots — and their KV blocks — are re-admissible on the NEXT step's
@@ -41,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models import olmo_hybrid
 from ..models.transformer import Transformer, TransformerConfig, make_init_fn
 from ..obs import flightrec as flightrec_lib
 from ..obs import trace as trace_lib
@@ -49,10 +52,13 @@ from . import decode as decode_lib
 from . import sampling
 from .kv_cache import (
     BlockAllocator,
+    HybridCache,
     KVCache,
     NoFreeBlocks,
     PagedKVCache,
+    SnapshotTable,
     init_cache,
+    init_hybrid_cache,
     init_paged_cache,
 )
 from .scheduler import (
@@ -88,7 +94,10 @@ class StepStats:
 
 
 class ServeEngine:
-    """KV-cached continuous-batching inference over a causal Transformer.
+    """KV-cached continuous-batching inference over a causal Transformer,
+    or over a hybrid decoder (``models.olmo_hybrid.OlmoHybridConfig``:
+    recurrent state beside the paged cache, docs/serving.md "Hybrid
+    cache"); the config's class picks the model.
 
     >>> eng = ServeEngine.with_random_params(cfg, num_slots=4)
     >>> uid = eng.submit([5, 17, 3], max_new_tokens=16)
@@ -98,7 +107,7 @@ class ServeEngine:
 
     def __init__(
         self,
-        cfg: TransformerConfig,
+        cfg: TransformerConfig | olmo_hybrid.OlmoHybridConfig,
         params,
         *,
         num_slots: int = 4,
@@ -110,6 +119,7 @@ class ServeEngine:
         num_blocks: int | None = None,
         prefill_chunk: int = 32,
         prefix_reuse: bool = True,
+        num_state_snapshots: int = 0,
         spec_k: int = 0,
         spec_ngram: int = 4,
         paged_impl: str | None = None,
@@ -139,11 +149,25 @@ class ServeEngine:
             )
         if spec_ngram < 1:
             raise ValueError(f"spec_ngram must be >= 1, got {spec_ngram}")
+        # the config's class picks the model; from here on the engine asks
+        # the model, not its name (serve/decode.py "the serving protocol")
+        self.model = (olmo_hybrid.OlmoHybrid(cfg)
+                      if isinstance(cfg, olmo_hybrid.OlmoHybridConfig)
+                      else Transformer(cfg))
+        #: recurrent layers beside the paged cache (HybridCache)
+        self.has_state = self.model.has_state
+        if self.has_state and not paged:
+            raise ValueError("a model with recurrent layers is served "
+                             "through the paged engine only")
+        if self.has_state and spec_k > 0:
+            raise ValueError(
+                "speculative decoding (spec_k > 0) is refused for a model "
+                "with recurrent layers: a rejected draft cannot be rolled "
+                "back out of a recurrent state")
         self.spec_k = spec_k
         self.spec_ngram = spec_ngram
         self.cfg = cfg
         self.params = params
-        self.model = Transformer(cfg)
         M = cfg.max_len if max_len is None else max_len
         if M > cfg.max_len:
             raise ValueError(
@@ -169,10 +193,28 @@ class ServeEngine:
                     f"{self._mb}: one request could exhaust the pool with "
                     f"no one left to preempt"
                 )
-            self.cache: PagedKVCache = init_paged_cache(
-                cfg, num_blocks, block_size, dtype=cache_dtype
-            )
             self.alloc = BlockAllocator(num_blocks, block_size)
+            if self.has_state:
+                if prefill_chunk % block_size:
+                    raise ValueError(
+                        f"prefill_chunk={prefill_chunk} must be a multiple "
+                        f"of block_size={block_size} for a hybrid decoder: "
+                        f"its K/V write moves whole blocks")
+                self.cache: HybridCache = init_hybrid_cache(
+                    cfg, num_slots, num_blocks, block_size,
+                    num_state_snapshots,
+                    dtype=jnp.bfloat16 if cache_dtype is None else cache_dtype)
+                #: prefix -> snapshot row; dies with the allocator's blocks
+                self.snapshots = SnapshotTable(num_state_snapshots,
+                                               self.alloc)
+                #: slot -> how far into the prompt the prefix cache matched
+                #: at admission: the part other requests demonstrably
+                #: share, where chunk ends are worth a snapshot
+                self._shared_upto: dict[int, int] = {}
+            else:
+                self.cache: PagedKVCache = init_paged_cache(
+                    cfg, num_blocks, block_size, dtype=cache_dtype
+                )
             #: slot → physical block ids in logical order (host truth);
             #: the device-side table mirrors it, sentinel-padded
             self._blocks: list[list[int]] = [[] for _ in range(num_slots)]
@@ -228,6 +270,16 @@ class ServeEngine:
                 self.model)
             self._decode = decode_lib.jit_paged_decode_step(self.model)
             self._copy_block = decode_lib.jit_copy_block()
+            if self.has_state:
+                self._take_snapshot = decode_lib.jit_take_snapshot()
+                self._restore_snapshot = decode_lib.jit_restore_snapshot()
+                # compiled here, on a cache that is still all zeros: the
+                # first snapshot hit must not compile in a serving window
+                # (no request of a warm-up is sure to cause one)
+                self.cache = self._copy_block(self.cache, 0, 0)
+                if num_state_snapshots:
+                    self.cache = self._take_snapshot(self.cache, 0, 0)
+                    self.cache = self._restore_snapshot(self.cache, 0, 0)
             if spec_k > 0:
                 self._verify = decode_lib.jit_paged_verify_step(self.model)
                 #: host-side accept-rule randomness (temperature spec);
@@ -296,6 +348,22 @@ class ServeEngine:
         self._m_spec_rate = r.gauge(
             "spec_acceptance_rate",
             "accepted / proposed draft tokens over the engine lifetime")
+        # recurrent-state snapshots (docs/observability.md "Hybrid cache");
+        # unconditional, zeros on an engine without recurrent layers
+        self._m_snap_taken = r.counter(
+            "state_snapshots_taken_total",
+            "recurrent-state snapshots copied out at a block-aligned "
+            "prefill-chunk end")
+        self._m_snap_hits = r.counter(
+            "state_snapshot_hits_total",
+            "admissions that started from a state snapshot")
+        self._m_snap_evic = r.counter(
+            "state_snapshot_evictions_total",
+            "state snapshots dropped: least recently used, or with the "
+            "prefix-cache block they belonged to")
+        self._m_snap_live = r.gauge(
+            "state_snapshots_live", "snapshot rows that hold a prefix")
+        self._snap_seen = (0, 0, 0)
         #: engine-lifetime accept accounting behind the gauge
         self._spec_proposed = 0
         self._spec_accepted = 0
@@ -307,6 +375,9 @@ class ServeEngine:
         cls, cfg: TransformerConfig, *, seed: int = 0, **kw
     ) -> "ServeEngine":
         """Random-weight engine for demos/benches (examples/serve.py)."""
+        if isinstance(cfg, olmo_hybrid.OlmoHybridConfig):
+            params = olmo_hybrid.init_params(cfg, jax.random.PRNGKey(seed))
+            return cls(cfg, params, seed=seed, **kw)
         params, _ = make_init_fn(Transformer(cfg), min(8, cfg.max_len))(
             jax.random.PRNGKey(seed)
         )
@@ -369,7 +440,7 @@ class ServeEngine:
                     if req.preemptions == 0:
                         self._m_queue_wait.observe(req.t_admit - req.t_submit)
                     if self.paged:
-                        self._begin_paged(slot, req)
+                        self._begin_paged(slot, req, admit)
             stats.prefill_s = admit.duration
             # occupancy counts every slot WORKING this step — decoding,
             # mid-chunked-prefill, or just admitted (even if its first
@@ -495,6 +566,16 @@ class ServeEngine:
         if d:
             self._m_block_evic.inc(d)
             self._evictions_seen = self.alloc.evictions
+        if self.has_state:
+            snaps = self.snapshots
+            now = (snaps.taken, snaps.hits, snaps.evictions)
+            for metric, new, old in zip(
+                    (self._m_snap_taken, self._m_snap_hits,
+                     self._m_snap_evic), now, self._snap_seen):
+                if new != old:
+                    metric.inc(new - old)
+            self._snap_seen = now
+            self._m_snap_live.set(float(len(snaps)))
 
     def _mb_bucket(self, hi_blocks: int) -> int:
         """Table width (in blocks) to hand the jit'd step: the smallest
@@ -525,6 +606,11 @@ class ServeEngine:
         T = len(req.prompt) + len(req.generated)
         need = -(-min(T + 1, self.sched.max_len) // self.block_size)
         m = self.alloc.peek_match(req.prompt) if self.prefix_reuse else 0
+        if m and self.has_state:
+            # only as far as a state snapshot lets the match be used
+            m = self.snapshots.longest(
+                req.prompt, min(m * self.block_size, T - 1),
+                touch=False)[0] // self.block_size
         free, ev = self.alloc.blocks_free, self.alloc.evictable()
         reserved = self._gate_reserved
         with_reuse = free + max(ev - m, 0) - reserved >= max(need - m, 1)
@@ -545,6 +631,8 @@ class ServeEngine:
         self._written[slot] = 0
         self._pending.pop(slot, None)
         self._ptoks.pop(slot, None)
+        if self.has_state:
+            self._shared_upto.pop(slot, None)
 
     def _youngest_resident(self, exclude: int) -> int | None:
         """Preemption victim: the LOWEST-priority resident, youngest
@@ -615,19 +703,40 @@ class ServeEngine:
             # the written offsets are stale from here on
             self.alloc.note_write(blocks[b], max(start - b * bs, 0))
 
-    def _begin_paged(self, slot: int, req: Request) -> None:
+    def _begin_paged(self, slot: int, req: Request, admit) -> None:
         """Admission bookkeeping for the paged path: map what the
         prefix cache already holds (never the last known position —
         its logits must be recomputed to sample the next token) and
-        queue the rest for chunked prefill."""
+        queue the rest for chunked prefill. A model with recurrent layers
+        can use a match only as far as a state snapshot exists: the match
+        is trimmed back to the longest such position, the blocks past it
+        are given back, and the slot starts from that snapshot (from
+        nought where there is none: the first chunk at position 0 does
+        that itself). ``admit`` is the step's ``serve.step.admit`` span:
+        it sums what its admissions matched and gave up."""
         toks = tuple(req.prompt) + tuple(req.generated)
         blocks: list[int] = []
-        matched = 0
+        matched = trimmed = 0
         if self.prefix_reuse:
             blocks, matched = self.alloc.match_prefix(toks)
             matched = min(matched, len(toks) - 1)
+            if self.has_state:
+                found = matched
+                matched, row = self.snapshots.longest(toks, found)
+                trimmed = found - matched
+                self.alloc.release_tail(blocks, matched // self.block_size)
+                #: full blocks of the match: shared with another request
+                self._shared_upto[slot] = (
+                    found // self.block_size * self.block_size)
+                if row is not None:
+                    self.cache = self._restore_snapshot(self.cache, slot,
+                                                        row)
             if blocks:
                 self._m_reuse.inc(len(blocks))
+        admit.attrs["matched_tokens"] = (
+            admit.attrs.get("matched_tokens", 0) + matched + trimmed)
+        admit.attrs["trimmed_tokens"] = (
+            admit.attrs.get("trimmed_tokens", 0) + trimmed)
         self._blocks[slot] = blocks
         self._table[slot, :] = self.cache.num_blocks
         self._table[slot, :len(blocks)] = blocks
@@ -665,7 +774,17 @@ class ServeEngine:
         with tracer.span("dispatch"):
             logits, self.cache = self._prefill_chunk_fn(
                 self.params, self.cache, table, buf, start, n,
+                *((slot,) if self.has_state else ()),
             )
+            if (self.has_state and end % self.block_size == 0
+                    and end <= self._shared_upto.get(slot, 0)
+                    and self.alloc.is_cached(toks[:end])):
+                # a chunk end inside the part of the prompt that the prefix
+                # cache matched and no snapshot covered: the next request
+                # behind this prefix starts from here
+                row = self.snapshots.take(toks[:end])
+                if row is not None:
+                    self.cache = self._take_snapshot(self.cache, slot, row)
         stats.prefill_chunks += 1
         self._m_chunks.inc()
         self.flightrec.emit("serve_prefill_chunk", uid=req.uid, slot=slot,

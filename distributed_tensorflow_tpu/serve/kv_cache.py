@@ -1,6 +1,6 @@
 """KV caches — the resident state of the decode engine.
 
-Two layouts live here (docs/serving.md):
+Three layouts live here (docs/serving.md):
 
 - **Paged** (the default): a fixed pool of KV blocks (``PagedKVCache``)
   plus host-side free/used accounting with copy-on-write refcounts and
@@ -11,6 +11,10 @@ Two layouts live here (docs/serving.md):
 - **Slot-dense** (``KVCache``, the exact-parity fallback): the PR-1
   layout described below, kept bit-for-bit for parity testing and as
   the ``ServeEngine(paged=False)`` escape hatch.
+- **Hybrid** (``HybridCache``, for ``models/olmo_hybrid.py``): the paged
+  pool for the full-attention layers only and, beside it, per slot and
+  per linear layer, the recurrent state and the convolution's window,
+  plus a pool of state snapshots for prefix reuse (``SnapshotTable``).
 
 Dense layout: one pair of buffers for the whole model, layers stacked
 on the leading axis::
@@ -238,6 +242,162 @@ def shard_paged_cache(
     return sharding.shard_tree(cache, mesh, paged_cache_specs(rules))
 
 
+# ---------------------------------------------------------------------------
+# Hybrid cache: recurrent state beside the paged pool (docs/serving.md
+# "Hybrid cache")
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass
+class HybridCache:
+    """The serving state of a decoder that mixes full-attention and
+    linear-attention (gated delta rule) layers.
+
+    - ``k``/``v``: [full layers, num_blocks + 1, heads, block_size,
+      head_dim], the paged pool, for the full layers ONLY; the block table,
+      the allocator and the prefix cache are the paged engine's. The last
+      physical block belongs to no one: the write kernel sends there what
+      idle slots and padding must not write (``paged_write_kv``).
+    - ``state``: [linear layers, slots, H, dk, dv] float32, each resident
+      request's recurrent state; ``conv``: [linear layers, slots, taps - 1,
+      channels] float32, the last inputs of the short convolution. A slot's
+      rows are its request's: the first prefill chunk of a request starts
+      them from nought, a decode step leaves the rows of slots that do not
+      decode as they were.
+    - ``snap_state``/``snap_conv``: the same with snapshot rows in place of
+      slots: copies of a slot's rows taken at a block-aligned chunk end,
+      which a later request with the same prefix starts from
+      (``SnapshotTable`` keeps which row holds which prefix)."""
+
+    k: jax.Array
+    v: jax.Array
+    state: jax.Array
+    conv: jax.Array
+    snap_state: jax.Array
+    snap_conv: jax.Array
+
+    @property
+    def num_blocks(self) -> int:
+        """Blocks a table can name (the allocator's count, and the table's
+        sentinel): the pool's, less the write-off block."""
+        return self.k.shape[1] - 1
+
+    @property
+    def block_size(self) -> int:
+        return self.k.shape[3]
+
+    def nbytes(self) -> int:
+        return sum(x.nbytes for x in jax.tree.leaves(self))
+
+
+jax.tree_util.register_dataclass(
+    HybridCache,
+    data_fields=["k", "v", "state", "conv", "snap_state", "snap_conv"],
+    meta_fields=[],
+)
+
+#: Partition-rules table of the hybrid cache: heads over ``model`` in the
+#: pool and in the recurrent state, as the weights that produce them; the
+#: convolution's channels follow their heads; blocks, slots and snapshot
+#: rows replicated.
+HYBRID_CACHE_RULES = sharding.partition_rules(
+    "serve-hybrid-cache",
+    ((r"^(k|v)$", P(None, None, mesh_lib.MODEL, None, None)),
+     (r"^(snap_)?state$", P(None, None, mesh_lib.MODEL, None, None)),
+     (r"^(snap_)?conv$", P(None, None, None, mesh_lib.MODEL))),
+    coverage=("k", "v", "state", "conv", "snap_state", "snap_conv"),
+)
+
+
+def init_hybrid_cache(cfg, num_slots: int, num_blocks: int, block_size: int,
+                      num_snapshots: int,
+                      dtype: str | jnp.dtype = jnp.bfloat16) -> HybridCache:
+    """Zero-filled hybrid cache for a ``models.olmo_hybrid.OlmoHybridConfig``;
+    ``dtype`` is the pool's, the recurrent state is float32."""
+    if min(num_blocks, block_size, num_slots) < 1 or num_snapshots < 0:
+        raise ValueError("num_blocks, block_size and num_slots must be >= 1, "
+                         "num_snapshots >= 0")
+    n_full, n_lin = (cfg.count("full_attention"),
+                     cfg.count("linear_attention"))
+    pool = (n_full, num_blocks + 1, cfg.num_heads, block_size, cfg.head_dim)
+    H, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    taps = cfg.conv_kernel - 1
+    f32 = jnp.float32
+    return HybridCache(
+        k=jnp.zeros(pool, dtype), v=jnp.zeros(pool, dtype),
+        state=jnp.zeros((n_lin, num_slots, H, dk, dv), f32),
+        conv=jnp.zeros((n_lin, num_slots, taps, cfg.conv_channels), f32),
+        snap_state=jnp.zeros((n_lin, num_snapshots, H, dk, dv), f32),
+        snap_conv=jnp.zeros((n_lin, num_snapshots, taps, cfg.conv_channels),
+                            f32))
+
+
+class SnapshotTable:
+    """Which snapshot row holds the recurrent state after which token
+    prefix: host-side, jax-free, least recently used first out.
+
+    Keys are the allocator's own: the token prefix up to a block boundary,
+    under which ``BlockAllocator`` caches the block that ends there. A
+    snapshot is of use only while that block is cached (a match has to
+    reach it), so the table hangs on the allocator's ``on_uncache`` hook: a
+    snapshot dies with its block, and ``flush_prefix_cache()`` leaves
+    none."""
+
+    def __init__(self, num_rows: int, alloc: BlockAllocator):
+        self.num_rows = num_rows
+        self.block_size = alloc.block_size
+        #: prefix -> row; insertion order is the LRU order (hits re-insert)
+        self._rows: dict[tuple[int, ...], int] = {}
+        self._free = list(range(num_rows - 1, -1, -1))
+        self.taken = self.hits = self.evictions = 0
+        alloc.on_uncache = self.drop
+
+    def __len__(self) -> int:
+        return len(self._rows)
+
+    def __contains__(self, prefix) -> bool:
+        return tuple(prefix) in self._rows
+
+    def longest(self, tokens: tuple[int, ...], limit: int,
+                touch: bool = True) -> tuple[int, int | None]:
+        """The longest block-aligned prefix of ``tokens``, at most ``limit``
+        tokens, that has a snapshot: (its length, its row), or (0, None).
+        ``touch`` counts it a hit and makes it the most recently used."""
+        bs = self.block_size
+        for n in range(limit // bs * bs, 0, -bs):
+            key = tuple(tokens[:n])
+            row = self._rows.get(key)
+            if row is not None:
+                if touch:
+                    self._rows[key] = self._rows.pop(key)
+                    self.hits += 1
+                return n, row
+        return 0, None
+
+    def take(self, prefix: tuple[int, ...]) -> int | None:
+        """A row for a snapshot after ``prefix`` (the caller copies the
+        state into it): a free one, else the least recently used one's.
+        None where the table has no rows or the prefix has one already."""
+        prefix = tuple(prefix)
+        if not self.num_rows or prefix in self._rows:
+            return None
+        if not self._free:
+            oldest = next(iter(self._rows))
+            self._free.append(self._rows.pop(oldest))
+            self.evictions += 1
+        row = self._free.pop()
+        self._rows[prefix] = row
+        self.taken += 1
+        return row
+
+    def drop(self, prefix: tuple[int, ...]) -> None:
+        """The block that ends ``prefix`` left the prefix cache."""
+        row = self._rows.pop(tuple(prefix), None)
+        if row is not None:
+            self._free.append(row)
+            self.evictions += 1
+
+
 class NoFreeBlocks(RuntimeError):
     """The pool is exhausted and nothing is evictable — the engine's
     cue to preempt a resident request (backpressure, not corruption)."""
@@ -290,6 +450,19 @@ class BlockAllocator:
         #: copy-on-write block copies performed (engine bumps this when
         #: it resolves a shared-block write)
         self.cow_copies = 0
+        #: called with the token prefix of every full-block entry that
+        #: leaves the prefix cache (eviction, ``release_cached``, flush):
+        #: what is keyed by the same prefixes (state snapshots) dies with it
+        self.on_uncache = None
+
+    def _uncached(self, key: tuple[int, ...]) -> None:
+        if self.on_uncache is not None:
+            self.on_uncache(key)
+
+    def is_cached(self, prefix: tuple[int, ...]) -> bool:
+        """Whether the full block that ends token prefix ``prefix`` is in
+        the prefix cache."""
+        return prefix in self._prefix
 
     # -- accounting --------------------------------------------------------
 
@@ -364,6 +537,7 @@ class BlockAllocator:
             bid = self._prefix[key]
             if self._ref[bid] == 1:
                 del self._prefix[key]
+                self._uncached(key)
                 self.evictions += 1
                 if self.decref(bid):
                     return
@@ -515,6 +689,7 @@ class BlockAllocator:
         removed = False
         for key in [k for k, b in self._prefix.items() if b == bid]:
             del self._prefix[key]
+            self._uncached(key)
             self.evictions += 1
             self.decref(bid)
             removed = True
@@ -525,8 +700,9 @@ class BlockAllocator:
         afterwards only resident requests hold blocks. Returns the
         number of blocks freed outright."""
         freed = 0
-        for bid in self._prefix.values():
+        for key, bid in self._prefix.items():
             freed += bool(self.decref(bid))
+            self._uncached(key)
         self._prefix.clear()
         self._partial.clear()
         return freed

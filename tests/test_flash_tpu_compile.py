@@ -1,5 +1,5 @@
-"""The flash kernels compiled for a described (not attached) TPU v5e, at
-real sizes: Mosaic's layout rules and its 16 MB scoped-VMEM limit are what
+"""The flash kernels, and the hybrid decoder's kernels and step programs,
+compiled for a described (not attached) TPU v5e, at real sizes: Mosaic's layout rules and its 16 MB scoped-VMEM limit are what
 the interpreter cannot check and what ``flash_tile_plan``'s own estimate
 has to stay under. Nothing runs; a pass says the chip's compiler takes the
 kernels, not that they are right or fast (tests/test_attention_ops.py,
@@ -53,3 +53,100 @@ def test_flash_forward_and_backward_compile_for_v5e(
     for kernel in ("flash_attention_fwd", "flash_attention_bwd_dkv",
                    "flash_attention_bwd_dq"):
         assert kernel in text
+
+
+# ---------------------------------------------------------------------------
+# the hybrid decoder (models/olmo_hybrid.py) at Olmo-Hybrid-7B's widths
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture()
+def kernels_for_the_chip(monkeypatch):
+    """The kernels pick the interpreter off the TPU; here the backend is the
+    CPU and the target the described chip."""
+    import importlib
+
+    for name in ("flash_attention", "gated_delta"):
+        monkeypatch.setattr(importlib.import_module(
+            f"distributed_tensorflow_tpu.ops.{name}"), "_on_tpu",
+            lambda: True)
+
+
+def test_gated_delta_kernels_compile_for_v5e(one_chip, kernels_for_the_chip):
+    """A chunk of 256 tokens and a step of 16 slots at 30 heads of 96 x 192,
+    in place on the state of 12 layers and 16 slots."""
+    from distributed_tensorflow_tpu.ops import gated_delta as gd
+
+    T, B, H, dk, dv = 256, 16, 30, 96, 192
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    state, i32 = S((12, B, H, dk, dv)), S((), jnp.int32)
+
+    def chunk(q, k, v, g, b, state, layer, slot, length):
+        return gd.gated_delta_chunk(q, k, v, g, b, state, layer=layer,
+                                    slot=slot, length=length,
+                                    fresh=length < 0, impl="pallas")
+
+    done = jax.jit(chunk, donate_argnums=(5,)).lower(
+        S((T, H, dk)), S((T, H, dk)), S((T, H, dv)), S((T, H)), S((T, H)),
+        state, i32, i32, i32).compile()
+    assert "gated_delta_chunk_fwd" in done.as_text()
+    # the state is updated in place: aliased, nothing of its size on the side
+    mem = done.memory_analysis()
+    assert mem.alias_size_in_bytes >= 12 * B * H * dk * dv * 4
+    assert mem.temp_size_in_bytes < 64e6
+
+    def step(q, k, v, g, b, state, layer, live):
+        return gd.gated_delta_step(q, k, v, g, b, state, layer=layer,
+                                   live=live, impl="pallas")
+
+    done = jax.jit(step, donate_argnums=(5,)).lower(
+        S((B, H, dk)), S((B, H, dk)), S((B, H, dv)), S((B, H)), S((B, H)),
+        state, i32, S((B,), jnp.bool_)).compile()
+    assert "gated_delta_step" in done.as_text()
+    assert done.memory_analysis().temp_size_in_bytes < 64e6
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_hybrid_step_programs_compile_and_fit_v5e(
+        one_chip, kernels_for_the_chip, program):
+    """The two serving programs of the benchmark's cut (16 layers, 16 slots,
+    384 blocks of 128, 32 snapshot rows) at the widest table: every kernel
+    is there, the pool and the state ride the scan without a copy (a
+    program that restacks them does not fit beside 8.2 GB of weights)."""
+    from distributed_tensorflow_tpu.models import olmo_hybrid as oh
+    from distributed_tensorflow_tpu.serve import decode, kv_cache
+
+    cfg = oh.OlmoHybridConfig(
+        vocab_size=100352, d_model=3840, d_ff=11008, num_heads=30,
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+        linear_heads=30, linear_key_dim=96, linear_value_dim=192,
+        paged_attention_impl="pallas", gated_delta_impl="pallas")
+    cfg = oh.OlmoHybridConfig(**{**cfg.__dict__,
+                                 "layer_types": cfg.layer_types * 4})
+    model = oh.OlmoHybrid(cfg)
+    on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, oh.param_shapes(cfg))
+    slots, width = 16, 64
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: kv_cache.init_hybrid_cache(cfg, slots, 384, 128, 32)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    if program == "prefill":
+        done = decode.jit_paged_prefill_chunk(model).lower(
+            params, cache, i32(width), i32(256), i32(), i32(), i32())
+        kernels = ("gated_delta_chunk_fwd",)
+    else:
+        done = decode.jit_paged_decode_step(model).lower(
+            params, cache, i32(slots, width), i32(slots), i32(slots))
+        kernels = ("gated_delta_step",)
+    done = done.compile()
+    text = done.as_text()
+    for kernel in kernels + ("paged_attention_fwd", "paged_kv_write"):
+        assert kernel in text
+    mem = done.memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes     # the cache, in place
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 15.0e9
