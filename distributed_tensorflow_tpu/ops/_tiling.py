@@ -31,16 +31,85 @@ def pad_to_sublane(n: int, sublane: int = 8) -> int:
     return -(-n // sublane) * sublane
 
 
-def paged_attn_vmem_ok(S: int, block_size: int, D: int,
-                       *, lanes: int = 128) -> bool:
-    """True when the paged-attention kernel's per-instance VMEM footprint
-    (resident q/o/acc [S, D] tiles, m/l row stats [S, lanes], one
-    double-buffered [block_size, D] k/v block pair) fits the shared
-    budget. Decode shapes are tiny (S ≤ 8, D ≤ 256), so this is a
-    tripwire against pathological configs, not a tile picker."""
-    resident = 3 * S * D * 4 + 2 * S * lanes * 4
-    stream = 2 * 2 * block_size * D * 4
-    return resident + stream <= VMEM_BUDGET
+def _lane_pad(n: int, lanes: int = 128) -> int:
+    return -(-n // lanes) * lanes
+
+
+class PagedAttnPlan(NamedTuple):
+    """How ``paged_flash_attention`` tiles one call: a grid step owns one
+    slot, ``hb`` heads and ``chunk_blocks`` of the slot's blocks, each
+    block an operand of its own."""
+
+    hb: int
+    chunk_blocks: int
+    vmem_bytes: int  # this model's count of a grid step's VMEM
+    vmem_limit_bytes: int  # what Mosaic is allowed for the call
+
+
+PAGED_CHUNK_POSITIONS = 256  # key positions a grid step aims for
+PAGED_VMEM_LIMIT = 32 * 1024 * 1024  # of the v5e's 128 MiB; default is 16
+
+
+def paged_attn_vmem_bytes(S: int, hb: int, chunk_blocks: int,
+                          block_size: int, D: int, itemsize: int) -> int:
+    """VMEM of one grid step of the paged kernel: the pipeline's two copies
+    of the q and o tiles, of the lane-broadcast q positions and of the
+    chunk's K and V blocks, the chunk's K and V joined into one operand
+    each, the f32 accumulator with its two lane-broadcast row statistics,
+    and the score tile's temporaries counted as three f32 tiles a head plus
+    the operand copy of ``p`` (the flash kernels' rule,
+    ``flash_vmem_bytes``). The head dim pads to the lane width, a chunk's
+    positions to it where they are the lane dim."""
+    T = chunk_blocks * block_size
+    row = _lane_pad(D) * itemsize
+    tiles = hb * S * _lane_pad(T) * (3 * 4 + itemsize)
+    return (2 * (2 * hb * S * row + S * 128 * 4)
+            + (2 + 1) * 2 * hb * T * row
+            + hb * S * (_lane_pad(D) + 2 * 128) * 4
+            + tiles)
+
+
+@functools.lru_cache(maxsize=None)
+def paged_attn_plan(S: int, H: int, max_blocks: int, block_size: int, D: int,
+                    itemsize: int) -> PagedAttnPlan:
+    """Tiles for ``paged_flash_attention`` from the call's shape alone:
+    ``S`` query rows a slot (already padded to the sublane width), ``H``
+    heads of ``D``, a table of ``max_blocks`` blocks of ``block_size``.
+
+    A grid step takes as many blocks as make PAGED_CHUNK_POSITIONS key
+    positions (never more than the table has): blocks of 16 go sixteen at
+    a time, blocks of 128 two. It takes the most heads that divide ``H``
+    and fit the budget: every head of a block is one contiguous copy, and
+    a chunk's heads one batched matmul. Where one head does not fit, the
+    chunk halves. Raises where a single block of a single head does not
+    fit (a block or a head dim far past anything served).
+
+    On a v5e (PR 28, 16 slots, bf16): 79 us a call at GPT-2-XL's decode
+    shape (25 heads of 64, 12 contexts of 40-420 tokens) at 256 positions
+    a step, 87 at 128, 183 at 512 (where 25 heads no longer fit and five
+    groups of 5 take their place); 608 / 612 / 689 us at the hybrid
+    model's (30 heads of 128, blocks of 128, contexts of 400-4000), which
+    is 82 % of the chip's memory bandwidth."""
+    chunk = max(1, min(PAGED_CHUNK_POSITIONS // block_size, max_blocks))
+    while True:
+        for hb in range(H, 0, -1):
+            if H % hb:
+                continue
+            vmem = paged_attn_vmem_bytes(S, hb, chunk, block_size, D,
+                                         itemsize)
+            if vmem <= FULL_VMEM_BUDGET:
+                plan = PagedAttnPlan(hb, chunk, vmem, PAGED_VMEM_LIMIT)
+                logger.info(
+                    "paged_attention S=%d H=%d max_blocks=%d block_size=%d "
+                    "D=%d itemsize=%d: %s", S, H, max_blocks, block_size, D,
+                    itemsize, plan)
+                return plan
+        if chunk == 1:
+            raise ValueError(
+                f"paged attention tile (S={S}, block_size={block_size}, "
+                f"D={D}) exceeds the VMEM budget; shrink block_size or "
+                f"head_dim")
+        chunk //= 2
 
 
 class FlashTilePlan(NamedTuple):
@@ -57,10 +126,6 @@ class FlashTilePlan(NamedTuple):
     resident: bool  # both spans cover their whole sequence
     grid_steps: int  # of the forward call
     computed_over_needed: float  # score elements computed / kept by the mask
-
-
-def _lane_pad(n: int, lanes: int = 128) -> int:
-    return -(-n // lanes) * lanes
 
 
 def flash_vmem_bytes(block_q: int, block_k: int, hb: int, q_span: int,

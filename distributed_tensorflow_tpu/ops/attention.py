@@ -191,9 +191,12 @@ def paged_attention(
       XLA; any backend.
     - ``"pallas"`` — the block-table-aware Pallas kernel
       (ops/flash_attention.paged_flash_attention): block ids are
-      scalar-prefetched and each grid step DMAs one physical block from
-      the pool in place — the logical view never exists in HBM at all.
-      Compiled on TPU, interpreter elsewhere (tests only).
+      scalar-prefetched and a grid step copies a chunk of one slot's own
+      blocks, every head of each, from the pool in place — the logical
+      view never exists in HBM at all. Rows at the past-the-table
+      sentinel (idle slots, padding) return zeros, not the garbage the
+      XLA paths compute. Compiled on TPU, interpreter elsewhere (tests
+      only).
     - ``"auto"`` — ``"pallas"`` on TPU, ``"fused"`` elsewhere.
     """
     if impl == "auto":

@@ -63,7 +63,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._tiling import flash_tile_plan
+from ._tiling import flash_tile_plan, pad_to_sublane, paged_attn_plan
 from .attention import NEG_INF
 
 LANES = 128  # TPU lane width
@@ -93,12 +93,6 @@ def _dot_tn(a, b):
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
-
-
-def _dot(a, b, dims):
-    return jax.lax.dot_general(
-        a, b, (dims, ((), ())), preferred_element_type=jnp.float32
-    )
 
 
 def _loop(lo, hi, body):
@@ -538,19 +532,20 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _paged_fwd_kernel(
-    bt_ref,  # scalar-prefetched block table [B, MB] (unused in the body —
-    #          it drives the k/v index_maps; Pallas still passes it in)
-    *refs,   # [layer_ref,] q, qpos, k, v, o, acc, m, l
-    sm_scale, block_size,
+    nb_ref,  # scalar-prefetched: the blocks each slot's valid rows can see
+    *refs,   # fetch_ref, [layer_ref,] q, qpos, C x k, C x v, o, acc, m, l
+    sm_scale, block_size, chunk_blocks,
 ):
-    (*layer_ref, q_ref, qpos_ref, k_ref, v_ref, o_ref,
-     acc_ref, m_ref, l_ref) = refs
-    del bt_ref
-    if layer_ref:  # pools with a layer axis in front: one more unit dim
-        k_ref, v_ref = k_ref.at[0], v_ref.at[0]
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
-    S = q_ref.shape[2]
+    """One slot, ``hb`` heads, one chunk of ``chunk_blocks`` of the slot's
+    blocks: each block of the chunk is an operand of its own, aimed by the
+    scalar-prefetched ids (which only drive the index maps); a chunk past
+    the slot's last block computes nothing."""
+    C, bs = chunk_blocks, block_size
+    *_, q_ref, qpos_ref = refs[:-2 * C - 4]
+    k_refs, v_refs = refs[-2 * C - 4:-C - 4], refs[-C - 4:-4]
+    o_ref, acc_ref, m_ref, l_ref = refs[-4:]
+    b, j = pl.program_id(0), pl.program_id(2)
+    S, T = q_ref.shape[2], C * bs
 
     @pl.when(j == 0)
     def _init():
@@ -558,41 +553,62 @@ def _paged_fwd_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # [S, D]
-    k = k_ref[0, 0].astype(jnp.float32)  # [bs, D] — one physical block
-    v = v_ref[0, 0].astype(jnp.float32)
-    logits = _dot(q, k, ((1,), (1,))) * sm_scale  # [S, bs]
-    kpos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (S, block_size), 1
-    )
-    # [S, 1] absolute query positions (-1 = padded row); the row stat
-    # arrives lane-broadcast like m/l, so this is a lane slice, not a
-    # sublane<->lane relayout
-    mask = kpos <= qpos_ref[0][:, :1]
-    logits = jnp.where(mask, logits, NEG_INF)
+    @pl.when(j * C < nb_ref[b])
+    def _chunk():
+        # a block's tile is [..., hb, bs, D] behind one or two unit dims
+        tile = lambda ref: ref[(0,) * (ref.ndim - 3)]
+        q = q_ref[0]  # [hb, S, D]
+        k = jnp.concatenate([tile(r) for r in k_refs], axis=1)  # [hb, T, D]
+        v = jnp.concatenate([tile(r) for r in v_refs], axis=1)
+        logits = _dot_nt(q, k) * sm_scale  # [hb, S, T]
+        kpos = j * T + jax.lax.broadcasted_iota(jnp.int32, (1, S, T), 2)
+        # [S, 1] absolute query positions (-1 = attends nothing); the row
+        # stat arrives lane-broadcast like m/l, so this is a lane slice,
+        # not a sublane<->lane relayout
+        mask = kpos <= qpos_ref[0][:, :1]
+        logits = jnp.where(mask, logits, NEG_INF)
+        m_prev = m_ref[...][:, :, :1]  # [hb, S, 1]
+        m_new = jnp.maximum(m_prev, logits.max(axis=2, keepdims=True))
+        # explicit zero under the mask (see _fwd_kernel): fully-masked rows
+        # keep m == NEG_INF and must not poison l with exp(0) == 1
+        p = jnp.where(mask, jnp.exp(logits - m_new), 0.0)
+        correction = jnp.exp(m_prev - m_new)
+        l_new = (l_ref[...][:, :, :1] * correction
+                 + p.sum(axis=2, keepdims=True))
+        acc_ref[...] = acc_ref[...] * correction + _dot_nn(
+            p.astype(v.dtype), v)  # [hb, S, D]
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    m_prev = m_ref[...]
-    l_prev = l_ref[...]
-    m_cur = logits.max(axis=1)[:, None]
-    m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-    # explicit zero under the mask (see _fwd_kernel): fully-masked rows
-    # keep m == NEG_INF and must not poison l with exp(0) == 1
-    p = jnp.where(mask, jnp.exp(logits - m_new[:, :1]), 0.0)
-    correction = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_prev * correction + jnp.broadcast_to(
-        p.sum(axis=1)[:, None], l_prev.shape
-    )
-    acc_ref[...] = acc_ref[...] * correction[:, :1] + _dot(p, v, ((1,), (0,)))
-    m_ref[...] = m_new
-
-    @pl.when(j == nb - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
-        l = l_ref[...][:, :1]
-        # all-masked rows (idle slots never reach here with l == 0 — their
-        # sentinel q_pos attends everything — but padded rows do)
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(
-            o_ref.dtype
-        )
+        # rows that attend nothing (padding, an idle slot: l == 0) → zeros
+        l = l_ref[...][:, :, :1]
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _paged_fetch_ids(block_table, n_blocks, num_blocks, chunk_blocks):
+    """[B, J * C] physical block ids for the paged kernel's K/V operands:
+    operand ``i`` of grid step ``(b, j)`` is logical block ``j * C + i`` of
+    slot ``b`` where that is one of the slot's own ``n_blocks[b]``, else
+    the id the same operand held the step before (across slots too), so
+    that the pipeline sees an unchanged block and copies nothing. An
+    operand that has had no block yet takes the call's first live one:
+    what it holds meets ``p == 0`` and has to be some request's numbers."""
+    B, MB = block_table.shape
+    C = chunk_blocks
+    J = -(-MB // C)
+    table = jnp.pad(block_table, ((0, 0), (0, J * C - MB)))
+    live = jnp.arange(J * C)[None] < n_blocks[:, None]
+    step = jnp.arange(B * J)[:, None]  # the grid's (b, j), flattened
+    live = live.reshape(B * J, C)
+    last = jax.lax.cummax(jnp.where(live, step, -1), axis=0)
+    first = jnp.argmax(live.reshape(-1))  # 0 where nothing is live
+    flat = jnp.clip(table, 0, num_blocks - 1).reshape(B * J, C)
+    ids = jnp.where(last >= 0,
+                    jnp.take_along_axis(flat, jnp.maximum(last, 0), axis=0),
+                    flat.reshape(-1)[first])
+    return ids.reshape(B, J * C)
 
 
 def paged_flash_attention(
@@ -606,18 +622,34 @@ def paged_flash_attention(
     interpret: bool | None = None,
     layer: jax.Array | None = None,
 ) -> jax.Array:
-    """Block-table-aware attention for paged decode — KV blocks are read
-    IN PLACE from the pool (``k_pool``/``v_pool``
+    """Block-table-aware attention for paged decode and prefill chunks: K/V
+    blocks are read IN PLACE from the pool (``k_pool``/``v_pool``
     [num_blocks, H, block_size, D]); the contiguous logical view that
     ``paged_gather_kv`` materializes never exists.
 
-    The block table is SCALAR-PREFETCHED (pltpu.PrefetchScalarGridSpec):
-    the grid iterates (batch, head, logical-block) and the k/v index_maps
-    read ``table[b, j]`` to aim each step's DMA at the right physical
-    block — table indirection costs an index computation, not a gather.
-    Masking is the paged contract: key position ``j <= q_pos`` attends;
-    sentinel table entries (``>= num_blocks``) clamp onto garbage the
-    mask excludes. Forward-only (decode never differentiates).
+    A grid step owns one slot (row of ``q`` [B, H, S, D]), ``hb`` heads
+    (all ``H`` where VMEM allows) and one chunk of ``C`` of the slot's
+    blocks (``ops/_tiling.paged_attn_plan``): grid ``(B, H // hb,
+    ceil(max_blocks / C))``. It relies on the pool's layout keeping a
+    block's heads contiguous: a block's ``[hb, block_size, D]`` is one
+    copy, and the chunk's ``C`` K and ``C`` V blocks are operands of their
+    own, each aimed by scalar-prefetched ids (``_paged_fetch_ids``), under
+    one online-softmax update over ``[hb, S, C * block_size]`` scores.
+    Each slot's TRIP COUNT is derived here from ``q_pos`` [B, S]: the
+    blocks the slot's valid rows can see, ``ceil((max valid q_pos + 1) /
+    block_size)``, where a row is valid if its position lies inside the
+    table (the callers' past-the-table sentinel marks idle slots and a
+    prefill chunk's padding). A grid step past a slot's count computes
+    nothing and fetches nothing (its operands keep the ids they had, and
+    the pipeline does not copy an unchanged block); the table is not read
+    past the count. Masking inside the last block is the paged contract:
+    key position ``j <= q_pos`` attends. A row that is not valid attends
+    nothing and returns zeros; an idle slot's count is 0. Forward-only
+    (decode never differentiates).
+
+    Matmul operands are in the pool's dtype (bf16 K and V as stored, ``q``
+    and ``p`` cast to it) with f32 accumulation; softmax, running max and
+    sum, and the rescale are f32.
 
     ``interpret=None`` auto-selects: compiled on TPU, Pallas interpreter
     elsewhere (slow; tests pin numerics against the gather path). On TPU
@@ -628,69 +660,87 @@ def paged_flash_attention(
     ``[layers, num_blocks, H, block_size, D]``, and the kernel reads row
     ``layer`` in place: a scan over layers carries one pool and never
     slices a layer's copy out of it."""
-    from ._tiling import pad_to_sublane, paged_attn_vmem_ok
+    if interpret is None:
+        interpret = not _on_tpu()
+    _, H, S, D = q.shape
+    Sp = S if interpret else pad_to_sublane(S)
+    plan = paged_attn_plan(Sp, H, block_table.shape[1], k_pool.shape[-2], D,
+                           k_pool.dtype.itemsize)
+    return _paged_call(
+        q, k_pool, v_pool, block_table, q_pos, layer, Sp=Sp,
+        sm_scale=sm_scale if sm_scale is not None else D**-0.5,
+        interpret=interpret, plan=plan)
 
+
+@functools.partial(jax.jit, static_argnames=(
+    "Sp", "sm_scale", "interpret", "plan"))
+def _paged_call(q, k_pool, v_pool, block_table, q_pos, layer, *, Sp,
+                sm_scale, interpret, plan):
+    """A jitted function of its own: a model's layers make the same call,
+    and trace and lower it once."""
     B, H, S, D = q.shape
     NB, _, bs, _ = k_pool.shape[-4:]
     MB = block_table.shape[1]
-    if interpret is None:
-        interpret = not _on_tpu()
-    if not paged_attn_vmem_ok(S, bs, D):
-        raise ValueError(
-            f"paged attention tile (S={S}, block_size={bs}, D={D}) "
-            f"exceeds the VMEM budget; shrink block_size or head_dim"
-        )
-    Sp = S if interpret else pad_to_sublane(S)
+    hb, C = plan.hb, plan.chunk_blocks
     qp = q_pos.astype(jnp.int32)
+    qp = jnp.where((qp >= 0) & (qp < MB * bs), qp, -1)  # -1 attends nothing
+    n_blocks = (qp.max(axis=1) + bs) // bs
+    out_dtype = q.dtype
+    q = q.astype(k_pool.dtype)
     if Sp != S:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
         qp = jnp.pad(qp, ((0, 0), (0, Sp - S)), constant_values=-1)
-    scale = sm_scale if sm_scale is not None else D**-0.5
 
     # [B, Sp, LANES]: a (1, Sp) block of a [B, Sp] array would put 1 on
     # the sublane dim, neither a multiple of 8 nor the full extent
     qp = jnp.broadcast_to(qp[:, :, None], (B, Sp, LANES))
-    # the scalar-prefetched operands: the table and, where the pools have a
-    # layer axis, the layer; every index_map takes them after the grid ids
-    scalars = (block_table.astype(jnp.int32),)
+    # the scalar-prefetched operands: the trip counts, the ids to fetch and,
+    # where the pools have a layer axis, the layer; every index_map takes
+    # them after the grid ids
+    scalars = (n_blocks, _paged_fetch_ids(
+        block_table.astype(jnp.int32), n_blocks, NB, C))
     if layer is None:
-        kvspec = pl.BlockSpec(
-            (1, 1, bs, D),
-            lambda b, h, j, bt: (jnp.minimum(bt[b, j], NB - 1), h, 0, 0),
-        )
+        def kvspec(i):
+            return pl.BlockSpec(
+                (1, hb, bs, D),
+                lambda b, g, j, nb, ids: (ids[b, j * C + i], g, 0, 0))
     else:
         scalars += (jnp.asarray(layer, jnp.int32).reshape(1),)
-        kvspec = pl.BlockSpec(
-            (1, 1, 1, bs, D),
-            lambda b, h, j, bt, ly: (ly[0], jnp.minimum(bt[b, j], NB - 1),
-                                     h, 0, 0),
-        )
-    qspec = pl.BlockSpec((1, 1, Sp, D), lambda b, h, j, *_: (b, h, 0, 0))
+
+        def kvspec(i):
+            return pl.BlockSpec(
+                (1, 1, hb, bs, D),
+                lambda b, g, j, nb, ids, ly: (ly[0], ids[b, j * C + i], g,
+                                              0, 0))
+    qspec = pl.BlockSpec((1, hb, Sp, D), lambda b, g, j, *_: (b, g, 0, 0))
+    kvspecs = [kvspec(i) for i in range(C)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(B, H, MB),
+        grid=(B, H // hb, pl.cdiv(MB, C)),
         in_specs=[
             qspec,
-            pl.BlockSpec((1, Sp, LANES), lambda b, h, j, *_: (b, 0, 0)),
-            kvspec,
-            kvspec,
+            pl.BlockSpec((1, Sp, LANES), lambda b, g, j, *_: (b, 0, 0)),
+            *kvspecs,
+            *kvspecs,
         ],
         out_specs=qspec,
         scratch_shapes=[
-            pltpu.VMEM((Sp, D), jnp.float32),
-            pltpu.VMEM((Sp, LANES), jnp.float32),
-            pltpu.VMEM((Sp, LANES), jnp.float32),
+            pltpu.VMEM((hb, Sp, D), jnp.float32),
+            pltpu.VMEM((hb, Sp, LANES), jnp.float32),
+            pltpu.VMEM((hb, Sp, LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _paged_fwd_kernel, sm_scale=scale, block_size=bs
-        ),
+            _paged_fwd_kernel, sm_scale=sm_scale, block_size=bs,
+            chunk_blocks=C),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Sp, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sp, D), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=plan.vmem_limit_bytes),
         interpret=interpret,
         name="paged_attention_fwd",
-    )(*scalars, q, qp, k_pool, v_pool)
+    )(*scalars, q, qp, *[k_pool] * C, *[v_pool] * C)
     return out[:, :, :S]
 
 

@@ -980,7 +980,7 @@ class ServeEngine:
             S = self.spec_k + 1
             toks = np.zeros((self.sched.num_slots, S), np.int32)
             pos = np.full((self.sched.num_slots, S), self._oob, np.int32)
-            kv_tokens = 0
+            kv_tokens = walked = 0
             for slot in active:
                 d = drafts[slot]
                 w = int(self._written[slot])
@@ -988,12 +988,13 @@ class ServeEngine:
                 toks[slot, 1: 1 + len(d)] = d
                 pos[slot, : 1 + len(d)] = np.arange(w, w + 1 + len(d))
                 kv_tokens += w + 1 + len(d)
+                walked += -(-(w + 1 + len(d)) // bs) * bs
             mbu = self._mb_bucket(max(len(self._blocks[s]) for s in active))
             table = jnp.asarray(self._table[:, :mbu])
             toks, pos = jnp.asarray(toks), jnp.asarray(pos)
         sp.attrs.update(
             slots=len(active), kv_tokens=kv_tokens, table_blocks=mbu,
-            kv_positions_walked=self.sched.num_slots * mbu * bs)
+            kv_positions_walked=walked)
         with tracer.span("dispatch"):
             logits, self.cache = self._verify(
                 self.params, self.cache, table, toks, pos,
@@ -1081,12 +1082,13 @@ class ServeEngine:
                     lens[slot] = self._written[slot]
                 mbu = self._mb_bucket(
                     max(len(self._blocks[s]) for s in active))
-                # the kernel's grid visits every block position of every
-                # slot up to the bucket width, live or not
+                # the kernel fetches each live slot's own blocks, up to the
+                # one its new token lands in, whatever the table's width
+                bs = self.block_size
                 sp.attrs.update(
                     table_blocks=mbu,
-                    kv_positions_walked=(self.sched.num_slots * mbu
-                                         * self.block_size))
+                    kv_positions_walked=int(
+                        ((self._written[active] + bs) // bs).sum()) * bs)
                 args = (jnp.asarray(self._table[:, :mbu]),
                         jnp.asarray(self._last), jnp.asarray(lens))
             else:

@@ -246,40 +246,163 @@ def test_flash_spans_that_do_not_fit_vmem(monkeypatch, Sq, Sk, causal, mask):
         _tiling.flash_tile_plan.cache_clear()
 
 
-def test_paged_attention_impls_match_gather_oracle():
-    """Every paged attention impl answers identically (PR 20): the fused
-    block-layout einsum and the Pallas kernel (interpreter off-TPU) must
-    match the PR-13 gather+cached_attention oracle on random pools with
-    ragged positions, sentinel table entries, an idle all-sentinel row,
-    and verify-shaped (S>1) queries — the shapes the serve engine feeds
-    the dispatch in decode, chunked prefill, and speculative verify."""
+_PAGED_BS = 8  # block size of the paged cases below
+
+
+def _paged_positions(kind, C, oob):
+    """q_pos [B, S] of one call of the kind the engine makes, over slots
+    whose contexts end inside the first block, on a block boundary, one
+    token past it, on exactly ``C`` blocks and on ``C`` + 1, beside an idle
+    slot (every row at the past-the-table sentinel ``oob``)."""
+    bs = _PAGED_BS
+    if kind == "decode":  # S = 1: the position each slot writes now
+        return [[3], [bs - 1], [bs], [C * bs - 1], [C * bs], [oob]]
+    if kind == "verify":  # S = spec_k + 1 = 5, unused draft rows at oob
+        last = [bs - 1, bs, C * bs - 1, C * bs + 4]
+        rows = [list(range(p - 4, p + 1)) for p in last]
+        rows.append([0, 1, 2, oob, oob])
+        rows.append([oob] * 5)
+        return rows
+    # a prefill chunk of 8 rows: whole, padded past its length, idle
+    whole = [list(range(lo, lo + 8)) for lo in (0, bs, C * bs - 8, C * bs)]
+    padded = [list(range(13, 18)) + [oob] * 3, [0] + [oob] * 7]
+    return whole + padded + [[oob] * 8]
+
+
+def _paged_case(kind, layer, C, *, H=2, D=16, dtype=jnp.float32, seed=7):
+    """(q, clean pools, poisoned pools, table, q_pos, pool row). The table
+    is wider than any slot needs and names, past each slot's own blocks, a
+    block id far outside the pool; every block that no slot owns (and
+    every other layer's row) is NaN in the poisoned pools, random in the
+    clean ones that the XLA oracles read."""
+    bs, MB = _PAGED_BS, C + 3
+    pos = np.asarray(_paged_positions(kind, C, MB * bs), np.int32)
+    B, S = pos.shape
+    valid = (pos >= 0) & (pos < MB * bs)
+    need = -(-np.where(valid, pos + 1, 0).max(axis=1) // bs)
+    NB = int(need.sum()) + 5
+    rng = np.random.default_rng(seed)
+    ids = rng.permutation(NB)
+    table = np.full((B, MB), 2**30, np.int32)
+    at = 0
+    for b in range(B):
+        table[b, :need[b]] = ids[at:at + need[b]]
+        at += need[b]
+    owned = np.zeros(NB, bool)
+    owned[ids[:at]] = True
+    layers = 1 if layer is None else 3
+    row = 0 if layer is None else layer
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(kq, (B, H, S, D), dtype)
+    clean = [jax.random.normal(key, (layers, NB, H, bs, D), dtype)
+             for key in (kk, kv)]
+    live = np.zeros((layers, NB), bool)
+    live[row] = owned
+    poisoned = [jnp.where(live[:, :, None, None, None], pool, jnp.nan)
+                for pool in clean]
+    if layer is None:
+        clean, poisoned = ([pool[0] for pool in pools]
+                           for pools in (clean, poisoned))
+    return (q, clean, poisoned, jnp.asarray(table), jnp.asarray(pos),
+            row, valid)
+
+
+def _check_paged_case(kind, layer, C, *, atol, **case):
+    """The Pallas kernel on the poisoned pools against the gather oracle on
+    the clean ones: equal on every valid row, zeros on every other (an
+    idle slot's, a chunk's padding); the fused XLA path answers as the
+    oracle on every row (PR 20)."""
+    from distributed_tensorflow_tpu.ops import _tiling
+    from distributed_tensorflow_tpu.ops.attention import paged_attention
+    from distributed_tensorflow_tpu.ops.flash_attention import (
+        paged_flash_attention,
+    )
+
+    q, clean, poisoned, table, q_pos, row, valid = _paged_case(
+        kind, layer, C, **case)
+    f32 = lambda t: t.astype(jnp.float32)
+    oracle_pools = [f32(pool if layer is None else pool[row])
+                    for pool in clean]
+    want = paged_attention(f32(q), *oracle_pools, table, q_pos=q_pos,
+                           impl="gather")
+    fused = paged_attention(f32(q), *oracle_pools, table, q_pos=q_pos,
+                            impl="fused")
+    np.testing.assert_allclose(fused, want, atol=2e-5, rtol=2e-5)
+    if layer is None:
+        got = paged_attention(q, *poisoned, table, q_pos=q_pos,
+                              impl="pallas")
+    else:
+        got = paged_flash_attention(q, *poisoned, table, q_pos=q_pos,
+                                    layer=jnp.int32(layer))
+    assert got.dtype == q.dtype
+    rows = np.broadcast_to(valid[:, None, :, None], got.shape)
+    np.testing.assert_allclose(
+        f32(got), np.where(rows, want, 0), atol=atol, rtol=atol)
+    assert not valid[-1].any()  # the idle slot
+    plan = _tiling.paged_attn_plan(
+        q.shape[2], q.shape[1], table.shape[1], _PAGED_BS, q.shape[3],
+        q.dtype.itemsize)
+    assert plan.chunk_blocks == C
+    return plan
+
+
+@pytest.fixture()
+def paged_chunk(monkeypatch):
+    """Sets how many blocks the paged kernel takes a loop iteration (the
+    shapes served give 2 to 16; the toy blocks here would all fit one)."""
+    from distributed_tensorflow_tpu.ops import _tiling
+
+    def set_chunk(C):
+        monkeypatch.setattr(_tiling, "PAGED_CHUNK_POSITIONS", C * _PAGED_BS)
+        _tiling.paged_attn_plan.cache_clear()
+    yield set_chunk
+    _tiling.paged_attn_plan.cache_clear()
+
+
+@pytest.mark.parametrize("layer", [None, 0, 2],
+                         ids=["flat", "layer0", "last_layer"])
+@pytest.mark.parametrize("kind,C", [
+    ("decode", 1), ("decode", 2), ("decode", 4),
+    ("verify", 2), ("verify", 4), ("prefill", 1), ("prefill", 2),
+])
+def test_paged_attention_impls_match_gather_oracle(paged_chunk, kind, C,
+                                                   layer):
+    """Every paged attention impl answers as the PR-13
+    gather+cached_attention oracle at the shapes the serve engine feeds the
+    dispatch (decode, speculative verify, a prefill chunk with padded
+    rows), on both pool layouts, over contexts of one chunk, exactly ``C``
+    blocks and ``C`` + 1, with the trip count and not the table's width
+    bounding the walk. float32 throughout: 2e-5."""
+    paged_chunk(C)
+    _check_paged_case(kind, layer, C, atol=2e-5)
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_paged_attention_bf16_operands_close_to_f32_oracle(paged_chunk, kind):
+    """bf16 pools and q: K, V, q and p enter the matmuls as bf16. Against
+    the float32 oracle on the same bf16 values what is left is p's rounding
+    (2^-9 relative, on values of order 1) and the bf16 output's: 2e-2."""
+    paged_chunk(2)
+    _check_paged_case(kind, 1, 2, atol=2e-2, dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_paged_attention_head_groups(monkeypatch, paged_chunk, kind):
+    """25 heads under a budget that all 25 do not fit: the plan's next
+    choice is 5 heads a grid step, five steps a slot."""
+    from distributed_tensorflow_tpu.ops import _tiling
+
+    paged_chunk(2)
+    S = len(_paged_positions(kind, 2, 0)[0])
+    fits = _tiling.paged_attn_vmem_bytes(S, 5, 2, _PAGED_BS, 16, 4)
+    monkeypatch.setattr(_tiling, "FULL_VMEM_BUDGET", fits)
+    plan = _check_paged_case(kind, None, 2, atol=2e-5, H=25)
+    assert plan.hb == 5
+
+
+def test_paged_attention_rejects_unknown_impl():
     from distributed_tensorflow_tpu.ops.attention import paged_attention
 
-    key = jax.random.PRNGKey(7)
-    B, H, D, bs, NB, MB = 3, 2, 16, 8, 10, 4
-    kq, kk, kv = jax.random.split(key, 3)
-    k_pool = jax.random.normal(kk, (NB, H, bs, D))
-    v_pool = jax.random.normal(kv, (NB, H, bs, D))
-    table = np.full((B, MB), NB, np.int32)
-    table[0, :3] = [4, 9, 1]      # 3 live blocks, non-contiguous
-    table[1, :1] = [0]            # 1 live block
-    # row 2 stays all-sentinel: an idle slot (its output is garbage the
-    # engine discards, but every impl must compute the SAME garbage)
-    table = jnp.asarray(table)
-    oob = MB * bs
-    for S, q_pos in (
-        (1, jnp.asarray([[17], [0], [oob]], jnp.int32)),
-        (5, jnp.asarray([[17, 18, 19, 20, 21], [0, 1, 2, 3, 4],
-                         [oob] * 5], jnp.int32)),
-    ):
-        q = jax.random.normal(kq, (B, H, S, D))
-        want = paged_attention(
-            q, k_pool, v_pool, table, q_pos=q_pos, impl="gather")
-        for impl in ("fused", "pallas"):
-            got = paged_attention(
-                q, k_pool, v_pool, table, q_pos=q_pos, impl=impl)
-            np.testing.assert_allclose(
-                got, want, atol=2e-5, rtol=2e-5,
-                err_msg=f"impl={impl} S={S}")
+    q, clean, _, table, q_pos, _, _ = _paged_case("decode", None, 1)
     with pytest.raises(ValueError, match="impl"):
-        paged_attention(q, k_pool, v_pool, table, q_pos=q_pos, impl="nope")
+        paged_attention(q, *clean, table, q_pos=q_pos, impl="nope")

@@ -1,9 +1,10 @@
-"""The flash kernels, and the hybrid decoder's kernels and step programs,
-compiled for a described (not attached) TPU v5e, at real sizes: Mosaic's layout rules and its 16 MB scoped-VMEM limit are what
-the interpreter cannot check and what ``flash_tile_plan``'s own estimate
-has to stay under. Nothing runs; a pass says the chip's compiler takes the
-kernels, not that they are right or fast (tests/test_attention_ops.py,
-chip_smoke.py).
+"""The flash kernels, the paged kernel at GPT-2-XL's serving shapes, and
+the hybrid decoder's kernels and step programs, compiled for a described
+(not attached) TPU v5e, at real sizes: Mosaic's layout rules and its scoped
+VMEM limit (16 MB unless a kernel asks for more) are what the interpreter
+cannot check and what the tile plans' own estimates have to stay under.
+Nothing runs; a pass says the chip's compiler takes the kernels, not that
+they are right or fast (tests/test_attention_ops.py, chip_smoke.py).
 
 The topology is described inside a fixture and only in this file: one
 process loads the TPU library, and keeps it."""
@@ -105,6 +106,37 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, kernels_for_the_chip):
         state, i32, S((B,), jnp.bool_)).compile()
     assert "gated_delta_step" in done.as_text()
     assert done.memory_analysis().temp_size_in_bytes < 64e6
+
+
+@pytest.mark.parametrize("width", [1, 4, 16, 48])
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_gpt2xl_paged_kernel_call_compiles_for_v5e(
+        one_chip, kernels_for_the_chip, program, width):
+    """The paged kernel as `gpt2xl_serve_complete_r80`'s two programs call
+    it: 16 slots (one for a prefill chunk of 32), 25 heads of 64, a bf16
+    pool of 640 blocks of 16 with no layer axis, tables from one block to
+    the widest of 48. Heads of 64 are what Mosaic is strict about (it
+    takes no slice of such a pool in HBM, which is why each block is an
+    operand of its own)."""
+    from distributed_tensorflow_tpu.ops.attention import paged_attention
+
+    B, S = (1, 32) if program == "prefill" else (16, 1)
+    S_ = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    pool = S_((640, 25, 16, 64), jnp.bfloat16)
+
+    def call(q, k, v, table, q_pos):
+        return paged_attention(q, k, v, table, q_pos=q_pos, impl="pallas")
+
+    done = jax.jit(call).lower(
+        S_((B, 25, S, 64), jnp.bfloat16), pool, pool,
+        S_((B, width), jnp.int32), S_((B, S), jnp.int32)).compile()
+    assert "paged_attention_fwd" in done.as_text()
+    # a pool handed to the call as sixteen operands is still one buffer
+    # (XLA lays a bare argument out for the kernel once, as it did for
+    # the kernel before: at most one copy of each pool)
+    pool_bytes = 640 * 25 * 16 * 64 * 2
+    assert done.memory_analysis().temp_size_in_bytes < 2 * pool_bytes + 4e6
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode"])

@@ -1129,7 +1129,7 @@ def test_step_spans_count_what_each_call_was_asked(traced_run):
     """`q_tokens/attended/context` of every `serve.step.prefill` and
     `slots/kv_tokens` of every `serve.step.decode` equal, call for call,
     what the call's arguments say; the table width is the one handed to
-    the kernel, and the walk is slots x width x block size."""
+    the kernel, and the walk is each live slot's own blocks."""
     run = traced_run
     assert sum(r.preemptions for r in run.requests.values()) >= 1
     pre, dec = _named(run, "serve.step.prefill"), _named(
@@ -1144,17 +1144,46 @@ def test_step_spans_count_what_each_call_was_asked(traced_run):
     assert [("decode", s.attrs["slots"], s.attrs["kv_tokens"],
              s.attrs["kv_tokens"], s.attrs["table_blocks"]) for s in dec] \
         == want_dec
-    eng = run.eng
+    bs = run.eng.block_size
     for s in dec:
-        assert s.attrs["kv_positions_walked"] == (
-            eng.sched.num_slots * s.attrs["table_blocks"] * eng.block_size)
-        assert 0 < s.attrs["kv_tokens"] <= s.attrs["kv_positions_walked"]
+        # kv_tokens rounded up to whole blocks, slot by slot
+        walked = s.attrs["kv_positions_walked"]
+        assert walked % bs == 0
+        assert 0 < s.attrs["kv_tokens"] <= walked \
+            < s.attrs["kv_tokens"] + s.attrs["slots"] * bs
     # a prompt of several chunks: consecutive chunks of one uid
     longest = max(run.requests.values(), key=lambda r: len(r.prompt))
     chunks = [s for s in pre if s.key == longest.uid]
     # all of the prompt but what the prefix cache supplied, at least
     assert len(chunks) >= 2 and sum(
         c.attrs["q_tokens"] for c in chunks) >= len(longest.prompt) - 16
+
+
+def test_decode_walk_follows_each_slots_own_length(decoder):
+    """One long context beside fifteen short or idle slots: the decode
+    span's `kv_positions_walked` is what the paged kernel fetches, each
+    live slot's context rounded up to blocks, and not slots x the widest
+    table x block size."""
+    from distributed_tensorflow_tpu import obs
+
+    cfg, _, params = decoder
+    tracer = obs.Tracer(annotate=False)
+    eng = _paged_engine(cfg, params, num_slots=16, max_len=48,
+                        num_blocks=32, tracer=tracer)
+    eng.submit(list(range(1, 38)), max_new_tokens=3)  # 37 tokens: 5 blocks
+    for i in range(5):
+        eng.submit([40 + i, 50 + i], max_new_tokens=8)  # one block each
+    while eng.sched.has_work:
+        eng.step()
+    dec = [s for s in tracer.events
+           if s.name == "serve.step.decode" and s.attrs.get("slots") == 6]
+    assert dec
+    bs = eng.block_size
+    for s in dec:
+        kv, walked = s.attrs["kv_tokens"], s.attrs["kv_positions_walked"]
+        # the long slot's 38-40 positions in 5 blocks, the short ones' one
+        assert kv <= walked == (5 + 5) * bs < kv + 6 * bs
+        assert walked < 16 * s.attrs["table_blocks"] * bs / 4
 
 
 def test_step_span_counts_add_up_to_the_engines_own(traced_run):

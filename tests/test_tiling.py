@@ -125,3 +125,41 @@ def test_flash_plan_explicit_blocks_override_only_the_blocks():
     assert plan.resident and 4 % plan.hb == 0
     # Sq != Sk: the last query sits on the last key
     assert 1.0 < plan.computed_over_needed < 1.3
+
+
+# --- paged attention tiles (paged_attn_plan) ------------------------------
+
+#: (H, block_size, D, widest table) of the two serving cells
+PAGED_CELLS = {"gpt2-xl": (25, 16, 64, 48),
+               "olmo-hybrid-7b": (30, 128, 128, 64)}
+
+
+@pytest.mark.parametrize("S", [8, 32, 256], ids=lambda s: f"S{s}")
+@pytest.mark.parametrize("width", [1, 2, 4, 8, 16, 32, "widest"])
+@pytest.mark.parametrize("cell", sorted(PAGED_CELLS))
+def test_paged_plan_fits_vmem_at_the_cells_shapes(cell, width, S):
+    """Decode (a row padded to 8), verify and the two cells' prefill
+    chunks, at every table width the engine's buckets make: the plan fits
+    the budget by its own count, ``hb`` divides the heads, and a grid step
+    takes at least 128 key positions wherever the table has them."""
+    H, bs, D, widest = PAGED_CELLS[cell]
+    MB = widest if width == "widest" else width
+    plan = _tiling.paged_attn_plan(S, H, MB, bs, D, 2)
+    assert H % plan.hb == 0 and 1 <= plan.chunk_blocks <= MB
+    assert plan.vmem_bytes == _tiling.paged_attn_vmem_bytes(
+        S, plan.hb, plan.chunk_blocks, bs, D, 2) <= _tiling.FULL_VMEM_BUDGET
+    assert plan.vmem_bytes < plan.vmem_limit_bytes
+    assert plan.chunk_blocks * bs >= min(128, MB * bs)
+    assert plan.chunk_blocks * bs <= max(_tiling.PAGED_CHUNK_POSITIONS, bs)
+    if S == 8:  # a decode step takes every head of a block in one copy
+        assert plan.hb == H
+
+
+def test_paged_plan_halves_the_chunk_before_it_gives_up():
+    """Where one head of the full chunk does not fit, the chunk halves;
+    where one block of one head does not fit, the shape is refused (the
+    old ``paged_attn_vmem_ok`` tripwire)."""
+    plan = _tiling.paged_attn_plan(1536, 8, 64, 64, 128, 4)
+    assert plan.hb == 1 and plan.chunk_blocks == 2  # four make 256
+    with pytest.raises(ValueError, match="VMEM budget"):
+        _tiling.paged_attn_plan(8, 8, 64, 8192, 512, 4)
