@@ -15,8 +15,8 @@ names are SHAPED, so the scrape surface stays mechanically queryable:
 - **no sub-second unit tokens** (``ms`` / ``us`` / ``ns`` /
   ``millis`` … anywhere between underscores, so ``lat_ms_total``
   can't hide one before the counter suffix): the exposition base unit
-  is seconds; milliseconds live in *presentation* (tools/bench_serve's
-  p50/p99 report), never in a registered name;
+  is seconds; milliseconds live in *presentation*
+  (``goodput.latency_percentiles_ms``), never in a registered name;
 - **registration kind matches the documented kind**: registering
   `goodput_fraction` as a counter when the docs table says gauge is
   vocabulary drift the membership check can't see;

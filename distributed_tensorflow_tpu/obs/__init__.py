@@ -41,7 +41,6 @@ from .flightrec import (  # noqa: F401
     validate_dump,
 )
 from . import goodput  # noqa: F401
-from . import scaling  # noqa: F401
 from . import fleetview  # noqa: F401
 from . import reqtrace  # noqa: F401
 from .reqtrace import PHASES, ReqTrace  # noqa: F401

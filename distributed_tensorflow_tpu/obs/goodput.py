@@ -2,14 +2,14 @@
 
 The MLPerf TPU-pod scaling work and TF-Replicator both treat step-time
 breakdown and utilization as first-class framework outputs; here they
-were ad-hoc prints inside bench.py until this module factored them out.
+were ad-hoc prints until this module factored them out.
 Two jobs:
 
 - **One MFU definition.** ``train_mfu`` is THE consumer site of the
   framework FLOPs contract (utils/flops.py): model ``flops_per_example``
   counts are FORWARD-only, and the fwd+bwd ×3 multiplier is applied
-  exactly here — so ``bench.py``'s JSON line, ``MetricsLogger``'s log
-  line, and the exported ``mfu`` gauge can never disagree.
+  exactly here — so ``MetricsLogger``'s log line and the exported
+  ``mfu`` gauge can never disagree.
   ``flops_per_step_from_compiled`` derives the per-step FLOP count from
   a compiled step's cost analysis for models without an analytic count.
 
@@ -152,7 +152,7 @@ def train_mfu(
     ``n_chips``/``peak_per_chip`` default from the live jax backend
     (pass both explicitly to stay device-free). When ``registry`` is
     given the value is also published as the ``mfu`` gauge — callers
-    that print it (bench.py's JSON line) and scrapers read one number.
+    that log it (``MetricsLogger``) and scrapers read one number.
     """
     from ..utils import flops as flops_lib  # lazy: pulls jax
 

@@ -397,7 +397,7 @@ class Registry:
         """What changed since ``baseline`` (a dict from ``snapshot()``),
         as a snapshot-shaped dict.
 
-        The per-interval isolation primitive for sweep/bench harnesses:
+        The per-interval isolation primitive:
         take ``snapshot()`` before an interval, ``delta(snap)`` after,
         and read only that interval's counters/histogram observations —
         WITHOUT a mid-run ``reset()``, which would break the registry's

@@ -354,7 +354,7 @@ def pick_single_pass_bm(M: int, cin: int, cout: int, *, in_bytes: int,
 
 # (M, cin, cout) shapes where the Pallas-backward Mosaic compile (or its
 # first execution) has been OBSERVED to stall >10 min on the real v5e —
-# round-3 session A: bench_fused_kernels grad at s3_conv1 rc=124 with the
+# round-3 session A: the fused-kernel microbench's grad at s3_conv1, rc=124 with the
 # pick_dw_tiles tiling. Populated strictly from on-chip evidence; remove
 # an entry when a later session shows it compiles+runs sanely (the
 # validator's VALIDATE_PALLAS_BWD sweep sets DTF_FUSED_BWD_FORCE=1 and

@@ -59,7 +59,7 @@ def configure_compile_cache() -> str:
     """Turn on jax's persistent compilation cache; returns its directory.
 
     Every entry point passes through here (``initialize``, the serve
-    entry, ``bench.py``, ``chip_smoke.py``) before its first compile.
+    entry, ``benchmark/run.py``, ``chip_smoke.py``) before its first compile.
     ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside — jax
     reads that variable itself, so no directory is set in code; unset,
     the cache goes to ``DEFAULT_COMPILE_CACHE_DIR``. Quick compiles are

@@ -70,7 +70,7 @@ from .scheduler import (
 
 @dataclasses.dataclass
 class StepStats:
-    """What one engine step did (tools/bench_serve.py aggregates these)."""
+    """What one engine step did."""
 
     admitted: int = 0
     decoded_slots: int = 0

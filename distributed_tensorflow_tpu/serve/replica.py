@@ -81,7 +81,7 @@ def main(argv=None) -> int:
         min_interval_s=0.5)
 
     # the tiny CPU-runnable decoder every serve rig shares
-    # (tools/bench_serve.py); weights are seed-deterministic, so every
+    # (examples/serve.py); weights are seed-deterministic, so every
     # replica of one fleet serves the same model
     cfg = tfm.TransformerConfig(
         vocab_size=256, max_len=128, num_layers=2, d_model=64, num_heads=4,

@@ -8,7 +8,7 @@ FLOPs, decides utilization — PAPERS.md):
   reordering, no priorities — fairness is positional.
 - **Fixed decode-batch slots.** The decode batch is ``num_slots`` wide,
   always. The scheduler's job is to keep occupancy at 1.0 whenever the
-  queue is non-empty (asserted by tools/bench_serve.py).
+  queue is non-empty (asserted by tests/test_serve.py).
 - **Evict on EOS / max-new / max-len.** A request leaves its slot the
   step it finishes: its own ``eos_id``, its ``max_new_tokens`` budget,
   or the slot's ``max_len`` cache budget (prompt + written tokens). The

@@ -195,8 +195,8 @@ class MetricsLogger(Callback):
         """``model_flops_per_step``: FORWARD FLOPs per step (the framework
         contract — every model's flops_per_example is fwd-only). The ×3
         training multiplier is applied by the shared MFU helper
-        (obs/goodput.train_mfu), the one consumer site for all of
-        MetricsLogger, bench.py, and the ``mfu`` gauge. Where the running
+        (obs/goodput.train_mfu), the one consumer site for both
+        MetricsLogger and the ``mfu`` gauge. Where the running
         device kind has no entry in utils/flops.PEAK_FLOPS_BY_KIND (a CPU
         run) no ``mfu`` key is reported."""
         self.every_n = every_n

@@ -69,7 +69,7 @@ __all__ = [
 
 logger = logging.getLogger(__name__)
 
-#: metric name (docs/observability.md "Scaling sweeps")
+#: metric name (docs/observability.md "Distributed eval")
 EVAL_STEPS = "eval_steps_total"
 
 
@@ -220,8 +220,7 @@ def derive_metrics(totals: dict[str, Any], auc_prefix: str = "") -> dict:
     accuracy/top5/loss ratios, and folds AUC histograms into
     ``<auc_prefix>auc`` (omitted when undefined — a one-class stream
     makes AUC NaN, which is not valid JSON downstream). Shared by the
-    runner's eval paths and the sweep harness so every consumer applies
-    one arithmetic."""
+    runner's eval paths so every consumer applies one arithmetic."""
     result = {k: float(v) for k, v in totals.items() if np.ndim(v) == 0}
     for summed, ratio in (("correct", "accuracy"),
                           ("top5_correct", "top5_accuracy"),

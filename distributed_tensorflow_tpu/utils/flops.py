@@ -11,8 +11,8 @@ model's ``flops_per_example`` and every workload's
 ``WorkloadParts.flops_per_step`` are FORWARD-only. The fwd+bwd training
 multiplier (``train_flops_multiplier()``, ×3) is applied in exactly ONE
 consumer site: ``obs/goodput.train_mfu`` — the shared MFU helper that
-``MetricsLogger`` (train-loop MFU), ``bench.py``, and the family
-benches all route through, and which publishes the ``mfu`` gauge.
+``MetricsLogger`` (train-loop MFU) routes through, and which
+publishes the ``mfu`` gauge.
 ``tests/test_flops_contract.py`` enforces both halves.
 """
 

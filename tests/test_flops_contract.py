@@ -58,15 +58,15 @@ def test_declared_flops_are_forward_only(name):
     assert 0.7 < ratio < 1.4, (
         f"{name}: declared flops_per_step is {ratio:.2f}x XLA's forward "
         f"count — the declaration must be forward-only (the ×3 train "
-        f"multiplier is applied by MetricsLogger/bench, not workloads)"
+        f"multiplier is applied by MetricsLogger, not workloads)"
     )
 
 
 def test_train_multiplier_single_site():
     """The ×3 multiplier must have exactly ONE call site —
-    obs/goodput.train_mfu, the shared MFU helper that MetricsLogger and
-    bench.py both route through — grep-level guard against
-    reintroducing it in models, workloads, or report scripts."""
+    obs/goodput.train_mfu, the shared MFU helper that MetricsLogger
+    routes through — grep-level guard against reintroducing it in
+    models, workloads, or report scripts."""
     import pathlib
 
     root = pathlib.Path(__file__).resolve().parents[1]
@@ -76,7 +76,6 @@ def test_train_multiplier_single_site():
         for py in (root / sub).rglob("*.py"):
             if call in py.read_text():
                 hits.append(py.relative_to(root).as_posix())
-    hits += ["bench.py"] if call in (root / "bench.py").read_text() else []
     assert sorted(hits) == [
         "distributed_tensorflow_tpu/obs/goodput.py",
     ], hits

@@ -851,7 +851,6 @@ def test_shipped_tree_is_clean():
     found = lint_paths([
         os.path.join(REPO, "distributed_tensorflow_tpu"),
         os.path.join(REPO, "tools"),
-        os.path.join(REPO, "bench.py"),
     ])
     assert found == [], "\n".join(f.format() for f in found)
 

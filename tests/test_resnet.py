@@ -215,7 +215,7 @@ def test_fused_block_impl_through_dp_mesh(devices):
     )
 
     # gradients through shard_map + psum'd BN stats + the Pallas
-    # custom_vjp — the exact path bench.py defaults to on TPU
+    # custom_vjp — the fused blocks' path on TPU
     def loss(model, xin):
         def go(p):
             out, _ = model.apply(

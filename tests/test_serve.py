@@ -331,6 +331,8 @@ def test_scheduler_invariants_chaos_stream():
         clk.advance(rng.uniform(0.0, 0.5))
         s.expire()
         admitted_order.extend(r.uid for _, r in s.admit())
+        if s.queue:
+            assert s.occupancy == 1.0  # a backlog leaves no slot free
         live = [r.uid for r in s.slots if r is not None]
         assert len(live) == len(set(live))  # no double-booking
         for slot in s.active_slots():
@@ -570,24 +572,28 @@ def test_block_allocator_note_write_invalidates_overwritten_tail():
     assert a.blocks_free == 4
 
 
-def test_paged_greedy_parity_with_dense(decoder):
+@pytest.mark.parametrize("paged_impl", ["gather", "fused"])
+def test_paged_greedy_parity_with_dense(decoder, paged_impl):
     """Acceptance gate: 64-step greedy decode through the paged path
-    (chunked prefill + block-table gather) is token-identical to the
-    dense slot cache, which is itself logit-checked against the
-    uncached forward above — both paths exercised on the same params."""
+    (chunked prefill + either XLA form of attention over the block
+    table) is token-identical to the dense slot cache, which is itself
+    logit-checked against the uncached forward above — both paths
+    exercised on the same params, and the drained engine is all-free."""
     cfg, _, params = decoder
     prompt = [5, 17, 3, 99, 42, 7, 11]
     dense = serve.ServeEngine(cfg, params, num_slots=1, paged=False)
     want = list(dense.stream(prompt, max_new_tokens=64))
-    paged = _paged_engine(cfg, params, num_slots=1)
+    paged = _paged_engine(cfg, params, num_slots=1, paged_impl=paged_impl)
     got = list(paged.stream(prompt, max_new_tokens=64))
     assert len(want) == 64 and got == want
+    paged.drain()
+    assert paged.alloc.blocks_free == paged.cache.num_blocks
 
     # a long prompt (multiple chunks) must agree too
     long_prompt = [(7 * i + 3) % cfg.vocab_size for i in range(40)]
     dense = serve.ServeEngine(cfg, params, num_slots=1, paged=False)
     want = list(dense.stream(long_prompt, max_new_tokens=24))
-    paged = _paged_engine(cfg, params, num_slots=1)
+    paged = _paged_engine(cfg, params, num_slots=1, paged_impl=paged_impl)
     got = list(paged.stream(long_prompt, max_new_tokens=24))
     assert got == want
 

@@ -18,20 +18,12 @@
 #      liveness of every partition_rules table, mesh-axis-closed-vocab
 #      over every PartitionSpec/collective axis literal, and
 #      sharding-seam-bypass confining placement construction to
-#      parallel/sharding.py — must all be clean over the package,
-#      tools, and bench.py; an injected unmatched param or out-of-
-#      vocab axis fails here), then the determinism rule alone over
+#      parallel/sharding.py — must all be clean over the package and
+#      tools; an injected unmatched param or out-of-vocab axis fails
+#      here), then the determinism rule alone over
 #      tests/ — the chaos/replay oracles must not consume ambient
 #      entropy either (relaxed set: pure test scaffolding is exempt
 #      from everything but determinism)
-#   4. tools/sweep.py --dryrun — scaling-observatory smoke (ISSUE 11):
-#      a 3-cell mesh×workload sweep (mlp × {1dev, dp8, pod2_dp2} on 8
-#      fake CPU devices — pod2_dp2 exercises the two-level PodTopology
-#      descriptor, ISSUE 19) that must emit a schema-valid
-#      dtf-scaling-1 report,
-#      every cell provenance-stamped (--expect-platform cpu is the
-#      masquerade tripwire: the report must SAY cpu when it ran on
-#      cpu), with the 8-dev dp scaling-efficiency gate enforced
 #   5. tools/chaos_smoke.py    — resilience smoke: scheduler
 #      timeout/cancel/backpressure invariants + one SIGTERM →
 #      coordinated-save → resume subprocess round (ISSUE 3) + one
@@ -76,25 +68,6 @@
 #      anchors and asserts the CROSS-WORKER causal stories, and
 #      fleet_top --once exercises the merged text view on the same
 #      artifacts
-#   7. tools/bench_serve.py  — paged-KV serve smoke (ISSUE 13, spec
-#      decoding ISSUE 20): the mixed-length chaos preset on the tiny
-#      model with speculative decoding on (--spec-k 4), chaos epilogue
-#      included, gating (a) 64-step greedy parity of BOTH paged
-#      attention impls against the dense fallback plus the spec ==
-#      non-spec greedy stream pins, short and multi-chunk-long prompts
-#      (--parity-check), (b) leak-free shutdown (the block allocator
-#      back to all-free after drain, spec rollback included), (c)
-#      full-batch occupancy under backlog + the one-chunk starvation
-#      bound for resident decoders, and (d) the same-run speculation
-#      win: chaos throughput must beat the non-spec gather baseline
-#      measured in the same process (--min-speedup — the bar is LOW
-#      because the CI preset is tiny and noisy; it is a CPU
-#      wall-clock ratio, not a device number)
-#   7d. tools/bench_trend.py — serve perf-regression sentinel
-#      (ISSUE 20): same freshest-pair trend as 4b, over the serve
-#      chaos bench — when a previous run left
-#      artifacts/serve_chaos_prev.json, the fresh run's tokens/sec
-#      must not collapse past the budget
 #   7b. tools/postmortem.py --merge — serve-fleet failover gate
 #      (ISSUE 16): chaos_smoke's serve-fleet round SIGKILLs one of two
 #      serve/replica.py subprocesses mid-stream and stages the
@@ -110,11 +83,6 @@
 #      became visible) — and the p2p round's timeline the catch-up story:
 #      worker dead → survivor catchup_offer → joiner catchup_restore →
 #      fleet_rejoin, with no catchup_fallback
-#   4b. tools/bench_trend.py — perf-regression sentinel (ISSUE 18): when
-#      a previous run left artifacts/scaling_dryrun_prev.json, compare
-#      the fresh sweep's dp8-cell steps/sec against it (provenance-
-#      checked: same platform/device_kind, both git_sha-pinned) and fail
-#      on a drop past the budget; first run on a clean tree skips
 #   6d. tools/postmortem.py --merge — hierarchical fault-domain gates
 #      (ISSUE 19): chaos_smoke's two-pod outage round SIGKILLs all of
 #      pod B mid-run while pod A keeps stepping — the merged timeline
@@ -142,26 +110,9 @@ bash tools/smoke_collect.sh "$@"
 env JAX_PLATFORMS=cpu python tools/obs_check.py >/dev/null
 env JAX_PLATFORMS=cpu python tools/dtf_lint.py --self-check
 env JAX_PLATFORMS=cpu python tools/dtf_lint.py --strict \
-  distributed_tensorflow_tpu tools bench.py
+  distributed_tensorflow_tpu tools
 env JAX_PLATFORMS=cpu python tools/dtf_lint.py --strict \
   --rules wall-clock-in-seam tests
-# keep the previous sweep report around as the bench_trend baseline:
-# the freshest pair of runs IS the trend (ISSUE 18)
-if [ -f artifacts/scaling_dryrun.json ]; then
-  cp artifacts/scaling_dryrun.json artifacts/scaling_dryrun_prev.json
-fi
-env JAX_PLATFORMS=cpu \
-  XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=8" \
-  python tools/sweep.py --dryrun --expect-platform cpu \
-  --out artifacts/scaling_dryrun.json >/dev/null
-# perf-regression sentinel (ISSUE 18): dryrun throughput on shared CI
-# hosts is noisy, so the budget is generous — this catches collapses
-# (a serialization bug halving step rate), not percent-level drift
-if [ -f artifacts/scaling_dryrun_prev.json ]; then
-  env JAX_PLATFORMS=cpu python tools/bench_trend.py \
-    artifacts/scaling_dryrun_prev.json artifacts/scaling_dryrun.json \
-    --metric cells.0.steps_per_sec --max-regress-pct 60
-fi
 env JAX_PLATFORMS=cpu python tools/chaos_smoke.py
 env JAX_PLATFORMS=cpu python tools/postmortem.py \
   "${DTF_CHAOS_POSTMORTEM:-artifacts/chaos_postmortem.jsonl}" --quiet \
@@ -239,23 +190,6 @@ env JAX_PLATFORMS=cpu python tools/postmortem.py --merge \
   --expect 'fault_fired[fault=slow_control_plane],fleet_done'
 env JAX_PLATFORMS=cpu python tools/fleet_top.py --once \
   --fleet-dir "${DTF_FLEET_DUMPS:-artifacts/fleet_dumps}" >/dev/null
-# keep the previous serve bench around as the bench_trend baseline,
-# same freshest-pair scheme as the sweep sentinel above (ISSUE 20)
-if [ -f artifacts/serve_chaos.json ]; then
-  cp artifacts/serve_chaos.json artifacts/serve_chaos_prev.json
-fi
-env JAX_PLATFORMS=cpu python tools/bench_serve.py --preset chaos \
-  --requests 10 --slots 4 --max-new 8 --parity-check \
-  --spec-k 4 --compare-baseline --min-speedup 1.1 \
-  --json artifacts/serve_chaos.json >/dev/null
-# serve perf-regression sentinel (ISSUE 20): chaos tok/s on shared CI
-# hosts is noisy, so the budget is generous — this catches collapses
-# (a rollback bug serializing the verify step), not percent-level drift
-if [ -f artifacts/serve_chaos_prev.json ]; then
-  env JAX_PLATFORMS=cpu python tools/bench_trend.py \
-    artifacts/serve_chaos_prev.json artifacts/serve_chaos.json \
-    --metric tokens_per_sec --max-regress-pct 60
-fi
 # serve fleet (ISSUE 16): re-merge the serve-fleet failover round's
 # per-process dumps (router/supervisor + surviving replicas, clocks
 # aligned on the serve_route dispatch/ACK handshake) and gate the

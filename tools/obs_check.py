@@ -93,7 +93,6 @@ def check(verbose: bool = True) -> list[str]:
 
     failures += _check_flightrec()
     failures += _check_goodput(reg)
-    failures += _check_scaling()
     failures += _check_fleetview()
     failures += _check_reqtrace()
 
@@ -212,77 +211,6 @@ def _check_goodput(reg) -> list[str]:
     if abs(ms["p50_ms"] - round(float(h.percentile(0.5)) * 1e3, 3)) > 1e-9:
         failures.append(f"latency_percentiles_ms disagrees with "
                         f"Histogram.percentile: {ms}")
-    return failures
-
-
-def _check_scaling() -> list[str]:
-    """Scaling-report gate (obs/scaling.py): a hand-built minimal
-    ``dtf-scaling-1`` report must validate, and the must-fail cases —
-    wrong schema tag, provenance-free cell, the CPU-masquerade
-    (cell platform disagreeing with the header), non-positive
-    throughput, mesh/device mismatch, an inconsistent gate — must each
-    be caught. Pure dict work: no device, no jax."""
-    import copy
-
-    from distributed_tensorflow_tpu.obs import scaling
-
-    failures: list[str] = []
-    prov = {
-        "backend": "cpu", "platform": "cpu", "device_kind": "cpu",
-        "device_count": 8, "hostname": "ci", "git_sha": "deadbeef",
-    }
-    cell = {
-        "cell": "dp8", "workload": "mlp", "axis": "dp", "n_devices": 8,
-        "mesh": {"pipe": 1, "data": 8, "fsdp": 1, "seq": 1, "expert": 1,
-                 "model": 1},
-        "global_batch": 1024, "steps": 8, "steps_per_sec": 40.0,
-        "examples_per_sec": 40960.0,
-        "provenance": dict(prov),
-    }
-    base = {
-        "cell": "1dev", "workload": "mlp", "axis": "dp", "n_devices": 1,
-        "mesh": {"pipe": 1, "data": 1, "fsdp": 1, "seq": 1, "expert": 1,
-                 "model": 1},
-        "global_batch": 128, "steps": 8, "steps_per_sec": 120.0,
-        "examples_per_sec": 15360.0,
-        "provenance": dict(prov),
-    }
-    good = {
-        "schema": scaling.SCHEMA,
-        "provenance": dict(prov),
-        "cells": [base, cell],
-        "efficiency": scaling.scaling_efficiency([base, cell]),
-        "gates": [{"gate": "mlp/dp8", "axis": "dp", "threshold": 0.8,
-                   "value": 2.6667, "passed": True}],
-    }
-    got = scaling.validate_scaling_report(good)
-    if got:
-        failures.append(f"valid scaling report rejected: {got}")
-    eff = good["efficiency"]
-    if len(eff) != 1 or eff[0]["basis"] != "shared_host" \
-            or abs(eff[0]["value"] - 40960.0 / 15360.0) > 1e-3:
-        failures.append(f"scaling_efficiency arithmetic wrong: {eff}")
-
-    def corrupt(mutate, needle):
-        bad = copy.deepcopy(good)
-        mutate(bad)
-        bad_failures = scaling.validate_scaling_report(bad)
-        if not any(needle in b for b in bad_failures):
-            failures.append(
-                f"validator missed a {needle!r} violation: {bad_failures}")
-
-    corrupt(lambda r: r.update(schema="dtf-scaling-0"), "schema")
-    corrupt(lambda r: r["cells"][1].pop("provenance"),
-            "missing 'provenance'")
-    # THE masquerade case: a cell claiming TPU under a CPU header
-    corrupt(lambda r: r["cells"][1]["provenance"].update(platform="tpu"),
-            "masqueraded")
-    corrupt(lambda r: r["cells"][1].update(steps_per_sec=0.0),
-            "finite positive")
-    corrupt(lambda r: r["cells"][1]["mesh"].update(data=4),
-            "does not multiply")
-    corrupt(lambda r: r["gates"][0].update(passed=False), "inconsistent")
-    corrupt(lambda r: r.update(cells=[]), "no cells")
     return failures
 
 

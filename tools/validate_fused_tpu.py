@@ -149,7 +149,7 @@ def bench_shape_sweep(r) -> bool:
             guarded(f"bench-shape conv1x1 pallas-bwd compile M={bM} "
                     f"{bci}->{bco}", compile_pallas)
 
-    ln_shapes = [  # bench_bert/gpt ln_matmul edges at bench batch
+    ln_shapes = [  # BERT-base / GPT-2 ln_matmul edges (M = batch x seq)
         (16384, 768, 2304), (16384, 768, 3072), (16384, 3072, 768),
         (32768, 1024, 4096),  # gpt long-context edge
     ]
