@@ -412,10 +412,13 @@ def compare_paged_kernel(say, eng) -> None:
     for name, (table, pos) in shapes.items():
         q = jnp.asarray(rng.randn(pos.shape[0], H, pos.shape[1], D),
                         jnp.dtype(eng.cfg.dtype))
+        # on the chip the pool stores a head at the 128 lanes
+        # (kv_cache.stored_head_dim): zeros beside q's own columns
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, k.shape[-1] - D),))
         outs = {
             impl: jax.jit(
                 lambda q, k, v, t, p, impl=impl: paged_attention(
-                    q, k, v, t, q_pos=p, impl=impl)
+                    q, k, v, t, q_pos=p, sm_scale=D ** -0.5, impl=impl)
             )(q, k, v, jnp.asarray(table), jnp.asarray(pos))
             for impl in ("pallas", "fused")
         }

@@ -342,40 +342,16 @@ def _full_out(x, a, p, cfg):
     return _mlp(x + _rms(_mm(a, p["wo"]), p["norm1"], cfg.rms_eps), p, cfg)
 
 
-def _write_kv(pool, new, layer, table, pos):
-    """``new`` [B, H, S, D] at absolute positions ``pos`` [B, S] into row
-    ``layer`` of ``pool`` [layers, blocks, H, block, D] through the block
-    table: the scatter of the XLA attention paths (off the TPU); beside the
-    Pallas kernels the write is ``ops.flash_attention.paged_write_kv``, in
-    place. A position past the table is dropped; a sentinel table entry
-    names the pool's last block, which belongs to no request."""
-    return pool.at[layer].set(
-        attn_ops.paged_append_kv(pool[layer], new, table, pos))
-
-
 def _full_paged(x, p, cfg, k_pool, v_pool, layer, table, pos):
     """x [B, S, d] at absolute positions ``pos`` [B, S] through a full
     layer: K and V written into row ``layer`` of the pool through the
     block table (sentinel positions and entries write nothing), attention
-    over the pool in place."""
+    over the pool in place (``ops.attention.paged_layer_attention``, the
+    transformer's too)."""
     q, k, v = _full_qkv(x, p, cfg)
-    impl = cfg.paged_attention_impl
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "fused"
-    bf = lambda t: t.astype(k_pool.dtype)
-    if impl == "pallas":
-        from ..ops.flash_attention import (paged_flash_attention,
-                                           paged_write_kv)
-
-        k_pool = paged_write_kv(k_pool, k, table, pos, layer=layer)
-        v_pool = paged_write_kv(v_pool, v, table, pos, layer=layer)
-        a = paged_flash_attention(bf(q), k_pool, v_pool, table, q_pos=pos,
-                                  layer=layer)
-    else:
-        k_pool = _write_kv(k_pool, k, layer, table, pos)
-        v_pool = _write_kv(v_pool, v, layer, table, pos)
-        a = attn_ops.paged_attention(bf(q), k_pool[layer], v_pool[layer],
-                                     table, q_pos=pos, impl=impl)
+    a, k_pool, v_pool = attn_ops.paged_layer_attention(
+        q.astype(k_pool.dtype), k, v, k_pool, v_pool, table, pos,
+        layer=layer, impl=cfg.paged_attention_impl)
     return _full_out(x, a.astype(jnp.float32), p, cfg), k_pool, v_pool
 
 

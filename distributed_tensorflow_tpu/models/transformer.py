@@ -392,22 +392,21 @@ class SelfAttention(nn.Module):
         new_cache = None
         seq_shards = self.mesh.shape[mesh_lib.SEQ] if self.mesh is not None else 1
         if cache is not None and "bt" in cache:
-            from ..ops.attention import paged_append_kv, paged_attention
+            from ..ops.attention import paged_layer_attention
 
-            # paged path: per-layer pool [NB,H,bs,D] + block table [B,MB].
-            # New K/V scatter through the table at the tokens' absolute
-            # positions (sentinel ids drop padded/idle writes); attention
-            # reads the pool through the table via the impl-selected
-            # dispatch — fused/Pallas by default, or the gather-then-
-            # attend masked dense form as the exact-parity escape hatch.
-            bt = cache["bt"]
-            ck = paged_append_kv(cache["k"], k, bt, decode_pos)
-            cv = paged_append_kv(cache["v"], v, bt, decode_pos)
-            new_cache = {"k": ck, "v": cv, "bt": bt}
-            out = paged_attention(
-                q, ck, cv, bt, q_pos=decode_pos,
-                impl=cfg.paged_attention_impl,
+            # paged path: the pools of ALL layers [L,NB+1,H,bs,D], this
+            # layer's index and the block table [B,MB]. New K/V go into
+            # row `layer` through the table at the tokens' absolute
+            # positions (sentinel ids and positions write nothing a
+            # request owns) and attention reads that row in place: on the
+            # TPU the two Pallas kernels, elsewhere a scatter and the
+            # impl-selected XLA form. The pools come back whole: the
+            # caller carries them from layer to layer.
+            out, ck, cv = paged_layer_attention(
+                q, k, v, cache["k"], cache["v"], cache["bt"], decode_pos,
+                layer=cache["layer"], impl=cfg.paged_attention_impl,
             )
+            new_cache = {"k": ck, "v": cv}
         elif cache is not None:
             from ..ops.attention import append_kv, cached_attention
 
@@ -615,9 +614,10 @@ class Transformer(nn.Module):
         # validity is the contiguous-fill predicate (ops.cached_attention).
         # With ``block_table`` [B, max_blocks] the cache is instead a
         # paged block POOL (serve.kv_cache.PagedKVCache: k/v
-        # [L, num_blocks, H, block_size, D]): K/V scatter through the
-        # table and attention gathers the logical view back
-        # (ops.paged_append_kv / paged_gather_kv).
+        # [L, num_blocks + 1, H, block_size, D]): K/V are written through
+        # the table into the pool, which is carried whole through the
+        # layers, and attention reads it in place
+        # (ops.attention.paged_layer_attention).
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
         B, S = input_ids.shape
@@ -670,16 +670,24 @@ class Transformer(nn.Module):
             nn.remat(Block, static_argnums=(3,))
             if cfg.remat and kv_cache is None else Block
         )
+        paged = block_table is not None
+        # the block pool rides the layers whole and is written in place: a
+        # layer takes both stacked pools and its index, and hands them on
+        pools = {"k": kv_cache.k, "v": kv_cache.v} if paged else None
         new_k, new_v = [], []
         for i in range(cfg.num_layers):
             use_moe = (
                 cfg.num_experts > 0 and i % cfg.moe_every == cfg.moe_every - 1
             )
             block = block_cls(cfg, self.mesh, use_moe, name=f"layer_{i}")
-            if kv_cache is not None:
+            if paged:
+                x, pools = block(
+                    x, mask, train,
+                    cache={**pools, "bt": block_table, "layer": i},
+                    decode_pos=decode_pos,
+                )
+            elif kv_cache is not None:
                 layer_cache = {"k": kv_cache.k[i], "v": kv_cache.v[i]}
-                if block_table is not None:
-                    layer_cache["bt"] = block_table
                 x, lc = block(
                     x, mask, train,
                     cache=layer_cache,
@@ -719,9 +727,12 @@ class Transformer(nn.Module):
         if kv_cache is not None:
             # same dataclass type in, same out — no serve/ import here,
             # so models/ stays independent of the serving subsystem
-            new_cache = dataclasses.replace(
-                kv_cache, k=jnp.stack(new_k), v=jnp.stack(new_v)
-            )
+            if paged:
+                new_cache = dataclasses.replace(kv_cache, **pools)
+            else:
+                new_cache = dataclasses.replace(
+                    kv_cache, k=jnp.stack(new_k), v=jnp.stack(new_v)
+                )
             return logits + bias, new_cache
         return logits + bias
 

@@ -75,21 +75,27 @@ def paged_append_kv(
     new: jax.Array,
     block_table: jax.Array,
     pos: jax.Array,
+    *,
+    layer: jax.Array | int | None = None,
 ) -> jax.Array:
     """Scatter ``new`` [B,H,S,D] into the shared block pool
     [num_blocks, H, block_size, D] through a per-sequence block table
     [B, max_blocks] (logical block index → physical block id). Token
     ``(b, s)`` at absolute position ``p = pos[b, s]`` lands in physical
     block ``block_table[b, p // block_size]`` at offset
-    ``p % block_size``.
+    ``p % block_size``. With ``layer`` the pool is that of several layers,
+    [layers, num_blocks, H, block_size, D], and the scatter goes to row
+    ``layer`` of it, the other rows untouched.
 
     Out-of-range routing is the padding contract: a position past the
     table (``p // block_size >= max_blocks`` — the chunk-padding
     sentinel) or a table entry ``>= num_blocks`` (the idle-slot /
     unallocated sentinel) produces an out-of-bounds scatter index, and
     the scatter drops it — padded rows and idle slots write NOTHING,
-    instead of corrupting a live block."""
-    NB, H, bs, D = pool.shape
+    instead of corrupting a live block. (A pool with a write-off block,
+    serve.kv_cache, has one physical block more than its tables count:
+    their sentinel entry names that block, which belongs to no request.)"""
+    NB, H, bs, D = pool.shape[-4:]
     B, _, S, _ = new.shape
     MB = block_table.shape[1]
     blk = pos // bs                                   # [B,S] logical block
@@ -100,9 +106,10 @@ def paged_append_kv(
         NB,  # past-the-table positions route out of bounds -> dropped
     )
     flat_new = new.transpose(0, 2, 1, 3).reshape(B * S, H, D)
-    return pool.at[bids.reshape(-1), :, off.reshape(-1), :].set(
-        flat_new.astype(pool.dtype), mode="drop"
-    )
+    where = (bids.reshape(-1), slice(None), off.reshape(-1), slice(None))
+    if layer is not None:
+        where = (layer,) + where
+    return pool.at[where].set(flat_new.astype(pool.dtype), mode="drop")
 
 
 def paged_gather_kv(pool: jax.Array, block_table: jax.Array) -> jax.Array:
@@ -237,6 +244,58 @@ def paged_attention(
     ).reshape(logits.shape)
     out = jnp.einsum("bhsmk,bmhkd->bhsd", probs.astype(vg.dtype), vg)
     return out.astype(q.dtype)
+
+
+def paged_layer_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    k_pool: jax.Array,
+    v_pool: jax.Array,
+    block_table: jax.Array,
+    pos: jax.Array,
+    *,
+    layer: jax.Array | int,
+    sm_scale: float | None = None,
+    impl: str = "auto",
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One attention layer of a decoder served from the paged cache: the
+    incoming tokens' ``k``/``v`` [B,H,S,D] at absolute positions ``pos``
+    [B,S] are written into row ``layer`` of the stacked pools
+    [layers, num_blocks + 1, H, block_size, D] through ``block_table``
+    (sentinel positions and entries write nothing a request owns), then
+    ``q`` attends over that row. Returns (out [B,H,S,D], k_pool, v_pool):
+    the caller carries the two pools whole through its layers, and never
+    slices a layer out of them or stacks them again. The pools' heads may
+    be stored wider than ``D``.
+
+    ``impl`` as in ``paged_attention``. ``"pallas"`` (``"auto"`` on the
+    TPU) writes in place with ``paged_write_kv`` and reads in place with
+    ``paged_flash_attention(layer=)``; the XLA forms scatter into the
+    stacked pool and attend over ``pool[layer]``."""
+    if impl == "auto":
+        impl = "pallas" if jax.default_backend() == "tpu" else "fused"
+    D, stored = q.shape[-1], k_pool.shape[-1]
+    sm_scale = _scale(q, sm_scale)
+    if stored != D:
+        # heads stored wider than the model's (serve.kv_cache.
+        # stored_head_dim): zeros in the padding move no score and no
+        # output, and the padding of the output is dropped
+        q, k, v = (jnp.pad(t, ((0, 0),) * 3 + ((0, stored - D),))
+                   for t in (q, k, v))
+    if impl == "pallas":
+        from .flash_attention import paged_flash_attention, paged_write_kv
+
+        k_pool = paged_write_kv(k_pool, k, block_table, pos, layer=layer)
+        v_pool = paged_write_kv(v_pool, v, block_table, pos, layer=layer)
+        out = paged_flash_attention(q, k_pool, v_pool, block_table,
+                                    q_pos=pos, sm_scale=sm_scale, layer=layer)
+    else:
+        k_pool = paged_append_kv(k_pool, k, block_table, pos, layer=layer)
+        v_pool = paged_append_kv(v_pool, v, block_table, pos, layer=layer)
+        out = paged_attention(q, k_pool[layer], v_pool[layer], block_table,
+                              q_pos=pos, sm_scale=sm_scale, impl=impl)
+    return out[..., :D], k_pool, v_pool
 
 
 def blockwise_attention(

@@ -767,9 +767,9 @@ def paged_write_kv(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Write ``new`` [B, H, S, D] into row ``layer`` of ``pool``
-    [layers, num_blocks, H, block_size, D], IN PLACE (the pool is aliased
-    to the output), through ``block_table`` [B, max_blocks]: the token at
-    absolute position ``pos[b, s]`` lands in physical block
+    [layers, num_blocks + 1, H, block_size, D], IN PLACE (the pool is
+    aliased to the output), through ``block_table`` [B, max_blocks]: the
+    token at absolute position ``pos[b, s]`` lands in physical block
     ``block_table[b, p // block_size]`` at offset ``p % block_size``.
 
     The padding contract is ``ops.attention.paged_append_kv``'s: a position
@@ -780,37 +780,69 @@ def paged_write_kv(
     needs a block that no other step of the call writes: sent to a real
     block, its stale copy (fetched while an earlier step was still
     computing) would land on top of that step's new row.
-    Either one token a sequence (decode), or ``S`` a multiple of the block
-    size with each sequence's positions consecutive from a block boundary
-    and the padding last (a prefill chunk).
+
+    Each sequence's positions are CONSECUTIVE from ``pos[b, 0]``, which may
+    lie anywhere inside a block, with the real positions first and the
+    padding (past the table) last: one token a slot (decode), a prefill
+    chunk from wherever the prefix cache's match ended, the K + 1 rows of a
+    speculative verify. ``S`` such positions touch at most ``ceil(S /
+    block_size) + 1`` blocks, which is the grid's steps a sequence (one
+    where ``S`` is 1); ``new`` is moved down by ``pos[b, 0] % block_size``
+    rows outside the kernel, so that a step's rows of it are its block's.
+    Positions that do not run consecutively are not what this writes:
+    refused where they are concrete, and where they are traced the caller's
+    to keep (the rows land as if they ran on from ``pos[b, 0]``).
 
     A kernel rather than a scatter or ``dynamic_update_slice``: with those
     XLA keeps a pool that a scan carries in the layout the write likes and
     copies all of it back to the kernels' layout every layer."""
+    if interpret is None:
+        interpret = not _on_tpu()
+    if not isinstance(pos, jax.core.Tracer):
+        # concrete positions only (an eager call): never under a trace
+        p = np.asarray(pos).astype(np.int64)  # dtflint: disable=host-sync-in-step
+        real = p < block_table.shape[1] * pool.shape[-2]
+        run = p[:, :1] + np.arange(p.shape[1])
+        if (real[:, 1:] > real[:, :-1]).any() or (real & (p != run)).any():
+            raise ValueError(
+                "paged_write_kv writes consecutive positions, the real ones "
+                f"first and the padding last; got {p.tolist()}")
+    return _paged_write_call(pool, new, block_table, pos, layer,
+                             interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_write_call(pool, new, block_table, pos, layer, *, interpret):
+    """A jitted function of its own, the layer an argument: a model's
+    layers make the same call, and trace and lower it once."""
     _, NB, H, bs, D = pool.shape
     NB -= 1  # the last physical block takes the writes that go nowhere
     B, _, S, _ = new.shape
     MB = block_table.shape[1]
-    if S != 1 and S % bs:
-        raise ValueError(f"a write of {S} tokens a sequence is neither one "
-                         f"token nor whole blocks of {bs}")
-    if interpret is None:
-        interpret = not _on_tpu()
     pos = pos.astype(jnp.int32)
-    n = max(S // bs, 1)
-    lb = pos[:, :1] // bs + jnp.arange(n)[None]            # [B, n] logical
+    start = pos[:, :1]                                     # [B, 1]
+    off = start % bs
+    # real positions come first: their count is where the padding begins
+    real = (pos < MB * bs).sum(axis=1, keepdims=True, dtype=jnp.int32)
+    new = new.astype(pool.dtype)
+    n, rows = 1, 1
+    if S > 1:
+        # row r of the sequence goes to row off + r of n whole blocks
+        n, rows = pl.cdiv(S, bs) + 1, bs
+        padded = jnp.pad(new, ((0, 0), (0, 0), (bs, n * bs - S), (0, 0)))
+        new = jax.vmap(lambda x, o: jax.lax.dynamic_slice_in_dim(
+            x, bs - o, n * bs, axis=1))(padded, off[:, 0])
+    step = jnp.arange(n, dtype=jnp.int32)[None]            # [1, n]
+    lb = start // bs + step                                # [B, n] logical
+    lo = jnp.clip(off - step * bs, 0, bs)
+    hi = jnp.clip(off + real - step * bs, 0, bs)
     bid = jnp.where(
         lb < MB,
-        jnp.take_along_axis(block_table, jnp.clip(lb, 0, MB - 1), axis=1), NB)
-    live = bid < NB
-    if S == 1:
-        lo = jnp.where(live, pos % bs, 0)
-        hi = jnp.where(live, pos % bs + 1, 0)
-    else:
-        # real positions come first: count them block by block
-        real = (pos < MB * bs).reshape(B, n, bs).sum(-1)
-        lo, hi = jnp.zeros_like(real), jnp.where(live, real, 0)
-    rows = min(S, bs)
+        jnp.take_along_axis(block_table.astype(jnp.int32),
+                            jnp.clip(lb, 0, MB - 1), axis=1), NB)
+    # a step with nothing to write goes to the write-off block (where a
+    # sentinel entry's rows go too: it is no one's)
+    bid = jnp.where((bid < NB) & (hi > lo), bid, NB)
     spec = pl.BlockSpec(
         (1, 1, H, bs, D),
         lambda b, j, ly, bid, lo, hi: (ly[0], bid[b, j], 0, 0, 0))
@@ -829,9 +861,7 @@ def paged_write_kv(
         input_output_aliases={5: 0},
         interpret=interpret,
         name="paged_kv_write",
-    )(jnp.asarray(layer, jnp.int32).reshape(1),
-      jnp.minimum(bid, NB).astype(jnp.int32), lo.astype(jnp.int32),
-      hi.astype(jnp.int32), new.astype(pool.dtype), pool)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), bid, lo, hi, new, pool)
 
 
 def flash_attention(

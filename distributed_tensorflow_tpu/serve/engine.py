@@ -199,7 +199,8 @@ class ServeEngine:
                     raise ValueError(
                         f"prefill_chunk={prefill_chunk} must be a multiple "
                         f"of block_size={block_size} for a hybrid decoder: "
-                        f"its K/V write moves whole blocks")
+                        f"state snapshots are taken at block-aligned chunk "
+                        f"ends")
                 self.cache: HybridCache = init_hybrid_cache(
                     cfg, num_slots, num_blocks, block_size,
                     num_state_snapshots,

@@ -147,7 +147,8 @@ def shard_cache(
 
 @dataclasses.dataclass
 class PagedKVCache:
-    """k/v: [num_layers, num_blocks, num_heads, block_size, head_dim].
+    """k/v: [num_layers, num_blocks + 1, num_heads, block_size, head_dim]
+    (on the TPU ``head_dim`` rounded up to the lanes: ``stored_head_dim``).
 
     The device side of the paged cache is ONLY this pool of physical
     blocks — no slot dimension. Which blocks belong to which request is
@@ -157,14 +158,22 @@ class PagedKVCache:
     ``BlockAllocator``. A resident request therefore costs
     ``ceil(tokens / block_size)`` blocks instead of a dense ``max_len``
     row, and requests sharing a common prefix map the SAME physical
-    blocks until their first divergent write."""
+    blocks until their first divergent write.
+
+    The last physical block belongs to no one: the write kernel sends
+    there what idle slots and padding must not write
+    (``ops.flash_attention.paged_write_kv``), and the tables' sentinel
+    entry (``num_blocks``) names it. ``HybridCache`` keeps its pool the
+    same way."""
 
     k: jax.Array
     v: jax.Array
 
     @property
     def num_blocks(self) -> int:
-        return self.k.shape[1]
+        """Blocks a table can name (the allocator's count, and the table's
+        sentinel): the pool's, less the write-off block."""
+        return self.k.shape[1] - 1
 
     @property
     def block_size(self) -> int:
@@ -175,8 +184,8 @@ class PagedKVCache:
 
     def block_nbytes(self) -> int:
         """Bytes of ONE physical block across both buffers and all
-        layers — the unit of the bench's KV-per-request accounting."""
-        return self.nbytes() // self.num_blocks
+        layers — the unit of KV-per-request accounting."""
+        return self.nbytes() // self.k.shape[1]
 
 
 jax.tree_util.register_dataclass(
@@ -201,6 +210,26 @@ PAGED_KV_CACHE_RULES = sharding.partition_rules(
 )
 
 
+def stored_head_dim(head_dim: int) -> int:
+    """The width a head's K and V rows are stored at in the pool: on the
+    TPU, ``head_dim`` rounded up to the 128 lanes (GPT-2's heads of 64 are
+    stored as 128, zeros in the padding). The Pallas kernels read and write
+    a block ``[heads, block_size, head_dim]`` of a row-major pool, and that
+    is where such a head takes its 128 lanes anyway. Left at 64, XLA lays
+    the array out with the BLOCK axis last (a last dimension under the lane
+    width buys it no padding there), and a program that calls the kernels
+    then copies both pools into their layout and back, every run: the copy
+    the in-place write exists to avoid. (The layout can also be asked for,
+    ``jax.experimental.layout``; jax 0.9.0's persistent compile cache
+    hands such a program back expecting the default one.) Elsewhere: as it
+    is. ``ops.attention.paged_layer_attention`` pads what it writes and
+    drops the padding of what it reads."""
+    lanes = 128
+    if jax.default_backend() != "tpu":
+        return head_dim
+    return -(-head_dim // lanes) * lanes
+
+
 def init_paged_cache(
     cfg: TransformerConfig,
     num_blocks: int,
@@ -210,14 +239,15 @@ def init_paged_cache(
     """Zero-filled block pool for ``cfg``. Unlike the dense cache there
     is no per-slot ``max_len`` row: capacity is simply
     ``num_blocks * block_size`` tokens shared by every resident
-    request."""
+    request. One physical block more is allocated: the write-off block
+    (``PagedKVCache``); heads are as wide as ``stored_head_dim`` says."""
     if num_blocks < 1:
         raise ValueError("num_blocks must be >= 1")
     if block_size < 1:
         raise ValueError("block_size must be >= 1")
     dt = jnp.dtype(cfg.dtype if dtype is None else dtype)
-    shape = (cfg.num_layers, num_blocks, cfg.num_heads, block_size,
-             cfg.head_dim)
+    shape = (cfg.num_layers, num_blocks + 1, cfg.num_heads, block_size,
+             stored_head_dim(cfg.head_dim))
     return PagedKVCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt))
 
 
@@ -254,10 +284,9 @@ class HybridCache:
     linear-attention (gated delta rule) layers.
 
     - ``k``/``v``: [full layers, num_blocks + 1, heads, block_size,
-      head_dim], the paged pool, for the full layers ONLY; the block table,
-      the allocator and the prefix cache are the paged engine's. The last
-      physical block belongs to no one: the write kernel sends there what
-      idle slots and padding must not write (``paged_write_kv``).
+      head_dim], the paged pool as ``PagedKVCache`` keeps it (write-off
+      block last), for the full layers ONLY; the block table, the
+      allocator and the prefix cache are the paged engine's.
     - ``state``: [linear layers, slots, H, dk, dv] float32, each resident
       request's recurrent state; ``conv``: [linear layers, slots, taps - 1,
       channels] float32, the last inputs of the short convolution. A slot's

@@ -1,5 +1,6 @@
-"""The flash kernels, the paged kernel at GPT-2-XL's serving shapes, and
-the hybrid decoder's kernels and step programs, compiled for a described
+"""The flash kernels, the paged kernels and the transformer's two serving
+programs at GPT-2-XL's serving shapes, and the hybrid decoder's kernels and
+step programs, compiled for a described
 (not attached) TPU v5e, at real sizes: Mosaic's layout rules and its scoped
 VMEM limit (16 MB unless a kernel asks for more) are what the interpreter
 cannot check and what the tile plans' own estimates have to stay under.
@@ -8,6 +9,8 @@ they are right or fast (tests/test_attention_ops.py, chip_smoke.py).
 
 The topology is described inside a fixture and only in this file: one
 process loads the TPU library, and keeps it."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,31 +115,90 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, kernels_for_the_chip):
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 def test_gpt2xl_paged_kernel_call_compiles_for_v5e(
         one_chip, kernels_for_the_chip, program, width):
-    """The paged kernel as `gpt2xl_serve_complete_r80`'s two programs call
-    it: 16 slots (one for a prefill chunk of 32), 25 heads of 64, a bf16
-    pool of 640 blocks of 16 with no layer axis, tables from one block to
-    the widest of 48. Heads of 64 are what Mosaic is strict about (it
-    takes no slice of such a pool in HBM, which is why each block is an
-    operand of its own)."""
-    from distributed_tensorflow_tpu.ops.attention import paged_attention
+    """A layer of `gpt2xl_serve_complete_r80`'s two programs as the model
+    makes it (`ops.attention.paged_layer_attention`): 16 slots (one for a
+    prefill chunk of 32), 25 heads of 64, the bf16 pool of 24 layers and
+    640 + 1 blocks of 16, tables from one block to the widest of 48; K and
+    V written in place, attention over the same row. Heads of 64 are what
+    Mosaic is strict about (it takes no slice of such a pool in HBM, which
+    is why each block is an operand of its own)."""
+    from distributed_tensorflow_tpu.ops.attention import paged_layer_attention
 
     B, S = (1, 32) if program == "prefill" else (16, 1)
     S_ = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                    sharding=one_chip)
-    pool = S_((640, 25, 16, 64), jnp.bfloat16)
+    # the pool as the engine keeps it on the chip: heads stored at the 128
+    # lanes (`kv_cache.stored_head_dim`; left at 64, XLA puts the block
+    # axis last and every call copies both pools there and back)
+    pool = S_((24, 641, 25, 16, 128), jnp.bfloat16)
+    new = S_((B, 25, S, 64), jnp.bfloat16)
 
-    def call(q, k, v, table, q_pos):
-        return paged_attention(q, k, v, table, q_pos=q_pos, impl="pallas")
+    def call(q, k, v, k_pool, v_pool, table, pos, layer):
+        return paged_layer_attention(q, k, v, k_pool, v_pool, table, pos,
+                                     layer=layer, impl="pallas")
 
-    done = jax.jit(call).lower(
-        S_((B, 25, S, 64), jnp.bfloat16), pool, pool,
-        S_((B, width), jnp.int32), S_((B, S), jnp.int32)).compile()
-    assert "paged_attention_fwd" in done.as_text()
-    # a pool handed to the call as sixteen operands is still one buffer
-    # (XLA lays a bare argument out for the kernel once, as it did for
-    # the kernel before: at most one copy of each pool)
-    pool_bytes = 640 * 25 * 16 * 64 * 2
-    assert done.memory_analysis().temp_size_in_bytes < 2 * pool_bytes + 4e6
+    done = jax.jit(call, donate_argnums=(3, 4)).lower(
+        new, new, new, pool, pool, S_((B, width), jnp.int32),
+        S_((B, S), jnp.int32), S_((), jnp.int32)).compile()
+    text = done.as_text()
+    assert "paged_attention_fwd" in text and "paged_kv_write" in text
+    # both pools written where they lie and read from there: nothing of a
+    # pool's size, nor of one layer's, on the side
+    pool_bytes = 24 * 641 * 25 * 16 * 128 * 2
+    mem = done.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < 4e6
+
+
+@pytest.mark.parametrize("width", [4, 48])
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_gpt2xl_step_programs_compile_and_keep_the_pool_in_place(
+        one_chip, kernels_for_the_chip, program, width):
+    """The transformer's two serving programs at GPT-2-XL's widths (d 1600,
+    25 heads of 64, d_ff 6400; 16 slots, the pool of 24 layers and 640 + 1
+    blocks of 16 with heads stored at 128, chunk 32; four layers of the
+    model keep the compile short) on a narrow and the widest table: both kernels are there, the
+    donated pool is the program's result where it lies, and the
+    temporaries (the weights cast to bf16, most of them) are far under one
+    pool: a program that slices a layer out of the pool or stacks it again
+    cannot hide there."""
+    from distributed_tensorflow_tpu.models import transformer as tr
+    from distributed_tensorflow_tpu.serve import decode, kv_cache
+
+    cfg = tr.TransformerConfig(
+        vocab_size=50304, d_model=1600, num_heads=25, num_layers=4,
+        d_ff=6400, max_len=1024, dropout=0.0, causal=True, pre_ln=True,
+        dtype="bfloat16", paged_attention_impl="pallas")
+    model = tr.Transformer(cfg)
+    on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32)))["params"])
+    slots = 16
+    # the engine's cache as `init_paged_cache` makes it on the chip (here
+    # the backend is the CPU): heads stored at the 128 lanes
+    pool = jax.ShapeDtypeStruct((24, 641, 25, 16, 128), jnp.bfloat16,
+                                sharding=one_chip)
+    cache = kv_cache.PagedKVCache(k=pool, v=pool)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    if program == "prefill":
+        done = decode.jit_paged_prefill_chunk(model).lower(
+            params, cache, i32(width), i32(32), i32(), i32())
+    else:
+        done = decode.jit_paged_decode_step(model).lower(
+            params, cache, i32(slots, width), i32(slots), i32(slots))
+    done = done.compile()
+    text = done.as_text()
+    assert "paged_attention_fwd" in text and "paged_kv_write" in text
+    pool_bytes = cache.k.size * cache.k.dtype.itemsize
+    mem = done.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes    # the pool, in place
+    assert mem.temp_size_in_bytes < 0.75 * pool_bytes
+    # nothing makes a second pool, and no layer's [641, 25, 16, 64] exists
+    assert not re.search(r"= bf16\[24,641,25,16,128\]\S* (copy|fusion)\(",
+                         text)
+    assert "bf16[641,25,16,128]" not in text
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode"])

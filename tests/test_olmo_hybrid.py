@@ -22,6 +22,7 @@ from benchmark.reference import olmo_hybrid as ref  # noqa: E402
 from distributed_tensorflow_tpu import serve  # noqa: E402
 from distributed_tensorflow_tpu.models import olmo_hybrid as oh  # noqa: E402
 from distributed_tensorflow_tpu.ops import gated_delta as gd  # noqa: E402
+from distributed_tensorflow_tpu.ops.attention import paged_append_kv  # noqa: E402
 from distributed_tensorflow_tpu.ops.flash_attention import (  # noqa: E402
     paged_flash_attention,
     paged_write_kv,
@@ -180,7 +181,7 @@ def test_paged_kernels_with_a_layer_axis_match_the_plain_forms():
     new = jax.random.normal(jax.random.PRNGKey(1), (1, H, S, D))
     wrote = paged_write_kv(pool, new, table, pos, layer=1)
     same = lambda a, b: np.array_equal(a[:, :NB], b[:, :NB])
-    assert same(wrote, oh._write_kv(pool, new, 1, table, pos))
+    assert same(wrote, paged_append_kv(pool, new, table, pos, layer=1))
     assert np.array_equal(wrote[0], pool[0]) and not np.array_equal(
         wrote[1], pool[1])
     # one token a slot, the middle slot idle
@@ -188,7 +189,7 @@ def test_paged_kernels_with_a_layer_axis_match_the_plain_forms():
     pos = jnp.array([[9], [3 * bs], [20]])
     new = jax.random.normal(jax.random.PRNGKey(2), (3, H, 1, D))
     wrote = paged_write_kv(pool, new, table, pos, layer=0)
-    assert same(wrote, oh._write_kv(pool, new, 0, table, pos))
+    assert same(wrote, paged_append_kv(pool, new, table, pos, layer=0))
     assert np.array_equal(wrote[0, 1], pool[0, 1])
     # a live slot on the LAST block a table can name, idle slots around it:
     # their write-back of nothing must not land on its row
@@ -196,7 +197,7 @@ def test_paged_kernels_with_a_layer_axis_match_the_plain_forms():
     pos = jnp.array([[3 * bs], [5], [3 * bs]])
     wrote = paged_write_kv(pool, new, table, pos, layer=1)
     np.testing.assert_array_equal(wrote[1, NB - 1, :, 5], new[1, :, 0])
-    assert same(wrote, oh._write_kv(pool, new, 1, table, pos))
+    assert same(wrote, paged_append_kv(pool, new, table, pos, layer=1))
     table = jnp.array([[4, 2, NB], [1, NB, NB], [0, 3, 5]])
     pos = jnp.array([[9], [3 * bs], [20]])
     wrote = paged_write_kv(pool, new, table, pos, layer=0)

@@ -665,6 +665,39 @@ def test_paged_prefix_reuse_and_cow(decoder):
                for i in range(eng.cache.num_blocks))
 
 
+@pytest.mark.parametrize("paged_impl", ["fused", "pallas"])
+def test_paged_unaligned_first_chunk_behind_a_partial_tail_match(
+        decoder, paged_impl):
+    """A request admitted behind a partial-tail prefix match starts its
+    prefill inside a block (the first chunk writes two blocks from row 3 of
+    the first, a copy of the sharer's), and is token-identical to the dense
+    oracle, through the scatter and through the in-place write kernel (the
+    interpreter here)."""
+    from distributed_tensorflow_tpu.obs.flightrec import FlightRecorder
+
+    cfg, _, params = decoder
+    first = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9]      # 1 block + 5 tail
+    second = first[:11] + [(11 * i + 2) % cfg.vocab_size for i in range(19)]
+    want = []
+    for prompt in (first, second):
+        dense = serve.ServeEngine(cfg, params, num_slots=1, paged=False)
+        want.append(list(dense.stream(prompt, max_new_tokens=10)))
+    rec = FlightRecorder(capacity=256)
+    eng = _paged_engine(cfg, params, num_slots=2, paged_impl=paged_impl,
+                        flightrec=rec)
+    a = eng.submit(first, max_new_tokens=10)
+    eng.step(), eng.step(), eng.step()  # prefilled, registered, decoding
+    b = eng.submit(second, max_new_tokens=10)
+    done = eng.run()
+    chunks = [e for e in rec.events() if e["kind"] == "serve_prefill_chunk"
+              and e["uid"] == b]
+    assert [c["start"] for c in chunks] == [11, 19, 27]
+    assert eng.alloc.cow_copies >= 1
+    assert [done[a].generated, done[b].generated] == want
+    eng.drain()
+    assert eng.alloc.blocks_free == eng.cache.num_blocks
+
+
 def test_paged_chunked_prefill_interleaves_decode(decoder):
     """A long prompt prefills in fixed-size chunks interleaved with
     decode: the resident request gains one token EVERY step of the long
@@ -879,12 +912,15 @@ def test_spec_engine_validation(decoder):
         _paged_engine(cfg, params, num_slots=1, spec_k=2, spec_ngram=0)
 
 
-def test_spec_greedy_exact_parity(decoder):
+@pytest.mark.parametrize("paged_impl", ["fused", "pallas"])
+def test_spec_greedy_exact_parity(decoder, paged_impl):
     """Acceptance gate: greedy streams with speculative decoding on are
     BIT-IDENTICAL to the non-spec paged engine (itself parity-gated
     against dense above), short and multi-chunk-long prompts — rejected
     drafts roll back without a trace, accepted ones are the same tokens
-    the target would have emitted one step at a time."""
+    the target would have emitted one step at a time. Through the scatter
+    and through the write kernel (the interpreter here), which takes the
+    K + 1 rows of a verify from wherever the slot's frontier stands."""
     cfg, _, params = decoder
     prompts = [
         [5, 17, 3, 99, 42, 7, 11],
@@ -893,7 +929,8 @@ def test_spec_greedy_exact_parity(decoder):
     for prompt in prompts:
         plain = _paged_engine(cfg, params, num_slots=1)
         want = list(plain.stream(prompt, max_new_tokens=48))
-        spec = _paged_engine(cfg, params, num_slots=1, spec_k=4)
+        spec = _paged_engine(cfg, params, num_slots=1, spec_k=4,
+                             paged_impl=paged_impl)
         got = list(spec.stream(prompt, max_new_tokens=48))
         assert got == want
         spec.drain()
