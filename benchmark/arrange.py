@@ -3,6 +3,7 @@
 
     python3 benchmark/arrange.py --config olmo-hybrid-7b --traffic docs_r80 \
         [--seeds LO:HI]
+    python3 benchmark/arrange.py --config gpt2-xl --traffic complete_v2_r80
 
 A mix that fixes its arrangement replays ONE order of lengths and gaps
 (`traffic.py`), and which order is a choice. This file holds the rule of
@@ -26,8 +27,12 @@ that share varies with a millisecond of host time, which is the spread of
 The model is coarse on purpose: a step is the host's share, one chunk for
 every slot still in prefill, and one decode step whose time grows with the
 widest table (a power-of-two bucket of blocks) among the decoding slots; a
-shared document is prefilled whole by its first two requests and restored
-from a snapshot from the third on. It knows nothing of preemption.
+shared prefix is prefilled whole by its first requests and skipped by the
+later ones. How many prefill it is the mix's to say
+(`arrangement_rule.shared_prefilled_by`): 2 where it is left out, the
+hybrid decoder's state snapshot, which the second request with a document
+writes; 1 for a prefix cache of K/V blocks alone (GPT-2), which serves the
+blocks the first request wrote. It knows nothing of preemption.
 """
 
 from __future__ import annotations
@@ -68,6 +73,7 @@ def walk(mix: dict, serving: dict, arrangement_seed: int,
     prompt = np.array([len(r.prompt) for r in reqs])
     out = np.array([r.out_len for r in reqs])
     shared = (mix.get("shared_prefix") or {}).get("tokens", 0)
+    prefilled_by = mix["arrangement_rule"].get("shared_prefilled_by", 2)
     seen: dict = {}                       # document -> requests begun
     pos, gen = np.zeros(n, int), np.zeros(n, int)
     admit, first, done = (np.full(n, np.nan) for _ in range(3))
@@ -85,7 +91,7 @@ def walk(mix: dict, serving: dict, arrangement_seed: int,
                 if reqs[i].prefix is not None:
                     before = seen.get(reqs[i].prefix, 0)
                     seen[reqs[i].prefix] = before + 1
-                    if before >= 2:       # its snapshot exists by now
+                    if before >= prefilled_by:  # restored, not prefilled
                         pos[i] = shared
                 slot[s], admit[i] = i, t
         live = [i for i in slot if i is not None]

@@ -25,14 +25,24 @@ def load_json(*parts: str) -> dict:
         return json.load(f)
 
 
+def retired() -> dict:
+    """Cells that left the manifest: name -> `{"for", "pr", "why"}`
+    (`benchmark/retired.json`). Documents and tests outside the benchmark's
+    own directories may still name one; its name then stands for the cell
+    that took its place."""
+    return load_json(HERE, "retired.json")
+
+
 def load_cell(name: str, root: str = ROOT) -> dict:
     """The cell's entry, its configuration and its traffic mix, each found
     by the name `BENCHMARK.json` gives."""
     manifest = load_json(root, "BENCHMARK.json")
     cells = {w["name"]: w for w in manifest["workloads"]}
     if name not in cells:
-        raise SystemExit(f"no workload {name!r} in BENCHMARK.json "
-                         f"(have {sorted(cells)})")
+        gone = retired().get(name)
+        raise SystemExit(f"no workload {name!r} in BENCHMARK.json " + (
+            f"(have {sorted(cells)})" if gone is None else
+            f"since PR {gone['pr']}: run {gone['for']!r} ({gone['why']})"))
     cell = cells[name]
     config = next(c for c in manifest["configs"] if c["name"] == cell["config"])
     return {
@@ -136,7 +146,9 @@ def annotate(name: str):
 def per_layer_metrics(manifest: dict, cell_name: str, ctx: dict) -> dict:
     """Every per-layer metric that lists this cell (or lists none), read by
     the reader its file under `benchmark/metrics/` names. A reader that
-    finds nothing to read returns None and the metric is left out."""
+    finds nothing to read returns None and the metric is left out. A
+    retired cell's name reads as the cell that took its place."""
+    cell_name = retired().get(cell_name, {}).get("for", cell_name)
     out = {}
     for m in manifest["per_layer"]:
         if "workloads" in m and cell_name not in m["workloads"]:

@@ -149,8 +149,10 @@ def prefill_phase_p50_ms(ctx):
 
 def decode_kv_useful_pct(ctx):
     """K/V positions the traced decode steps needed (`kv_tokens`: each live
-    slot's context) of the positions the paged kernel's grid walked
-    (`kv_positions_walked`: slots x table width x block size)."""
+    slot's context) of the positions the paged kernel fetches
+    (`kv_positions_walked`: since PR 28 each live slot's own blocks up to
+    its new token's, times the block size; the kernel walks no block of
+    an idle slot and none past a slot's last)."""
     m = Mapped(ctx)
     steps = [s for s in m.in_window("serve.step.decode")
              if s.attrs.get("kv_positions_walked")]

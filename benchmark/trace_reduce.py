@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import heapq
 import re
 
 OPS_LINE = "XLA Ops"
@@ -31,6 +32,8 @@ COLLECTIVE = re.compile(
     r"|collective-broadcast|send|recv)")
 #: host events shorter than this name no idle gap worth reporting
 MIN_HOST_NS = 20_000
+#: and none longer than this: a thread's whole life explains no gap
+MAX_HOST_NS = 5_000_000_000
 
 
 @dataclasses.dataclass
@@ -283,7 +286,10 @@ def top_modules(trace: Trace, n: int = 10) -> list:
 def idle_gaps(trace: Trace, n: int = 10) -> list:
     """[[what the host was doing, idle seconds], ...] on the first chip:
     every gap between operations goes to the shortest host event that covers
-    its middle, and the gaps add up by that name."""
+    its middle (of two as short, the one that began later), and the gaps add
+    up by that name. One sweep over gaps and host events, both in order of
+    time: a traced window of a busy serving cell holds some hundred
+    thousand of each."""
     if not trace.devices:
         return []
     dev = next(iter(trace.devices.values()))
@@ -291,19 +297,19 @@ def idle_gaps(trace: Trace, n: int = 10) -> list:
     if not busy:
         return []
     gaps = subtract([(busy[0][0], busy[-1][1])], busy)
-    host = sorted(trace.host, key=lambda ev: ev[1])
-    starts = [ev[1] for ev in host]
+    host = sorted((ev for ev in trace.host if ev[2] - ev[1] <= MAX_HOST_NS),
+                  key=lambda ev: ev[1])
     out: dict = {}
+    covering: list = []  # heap of (length, -start, end, name): began by `mid`
+    nxt = 0
     for s, e in gaps:
         mid = (s + e) / 2
-        best = None
-        # host events starting before the middle, most recent first
-        for i in range(bisect.bisect_right(starts, mid) - 1, -1, -1):
-            name, hs, he = host[i]
-            if he >= mid and (best is None or he - hs < best[1]):
-                best = (name, he - hs)
-            if mid - hs > 5e9:  # nothing of use lasts longer than a window
-                break
-        name = base_name(best[0]) if best else "unattributed"
+        while nxt < len(host) and host[nxt][1] <= mid:
+            name, hs, he = host[nxt]
+            heapq.heappush(covering, (he - hs, -hs, he, name))
+            nxt += 1
+        while covering and covering[0][2] < mid:  # ended before this gap
+            heapq.heappop(covering)
+        name = base_name(covering[0][3]) if covering else "unattributed"
         out[name] = out.get(name, 0.0) + (e - s) / 1e9
     return [[k, v] for k, v in sorted(out.items(), key=lambda kv: -kv[1])[:n]]

@@ -22,58 +22,97 @@ def load(*parts):
         return json.load(f)
 
 
-MIX = load(BENCH, "traffic", "docs_r80.json")
-SERVING = load(BENCH, "configs", "olmo-hybrid-7b.json")["serving"]
 SECONDS = load(ROOT, "BENCHMARK.json")["run_seconds"]
+#: the mixes that fix their arrangement by the rule: mix -> (configuration,
+#: the least share of the window, in per cent, with every slot taken)
+MIXES = {"docs_r80": ("olmo-hybrid-7b", 5.0),
+         "complete_v2_r80": ("gpt2-xl", 1.0)}
 
 
-@pytest.fixture(scope="module")
-def candidates():
-    lo, hi = MIX["arrangement_rule"]["seeds"]
-    return [arrange.walk(MIX, SERVING, seed, SECONDS)
-            for seed in range(lo, hi)]
+@pytest.fixture(scope="module", params=sorted(MIXES))
+def picked(request):
+    """(the mix, its configuration's serving block, its floor for the share
+    of the window with every slot taken, every candidate of its rule
+    walked)."""
+    mix = load(BENCH, "traffic", f"{request.param}.json")
+    serving = load(BENCH, "configs",
+                   f"{MIXES[request.param][0]}.json")["serving"]
+    lo, hi = mix["arrangement_rule"]["seeds"]
+    return mix, serving, MIXES[request.param][1], [
+        arrange.walk(mix, serving, seed, SECONDS) for seed in range(lo, hi)]
 
 
-def test_the_mix_holds_the_arrangement_its_rule_picks(candidates):
-    assert MIX["arrangement_rule"]["tool"] == "benchmark/arrange.py"
-    assert os.path.exists(os.path.join(ROOT, MIX["arrangement_rule"]["tool"]))
+def test_every_mix_that_fixes_its_arrangement_is_held_here():
+    """A mix with an `arrangement_seed` states the rule that picked it, and
+    this file holds it to the rule."""
+    fixed = set()
+    for name in os.listdir(os.path.join(BENCH, "traffic")):
+        mix = load(BENCH, "traffic", name)
+        if mix.get("arrangement_seed") is not None:
+            assert mix["arrangement_rule"]["tool"] == "benchmark/arrange.py"
+            fixed.add(name.removesuffix(".json"))
+    assert fixed == set(MIXES)
+
+
+def test_the_mix_holds_the_arrangement_its_rule_picks(picked):
+    mix, _, _, candidates = picked
+    assert os.path.exists(os.path.join(ROOT, mix["arrangement_rule"]["tool"]))
     assert len(candidates) == 300
     assert arrange.pick(candidates)["arrangement_seed"] == \
-        MIX["arrangement_seed"]
+        mix["arrangement_seed"]
 
 
 def test_the_picked_arrangement_is_typical_of_the_mix_and_closes_calm(
-        candidates):
+        picked):
     """Not an outlier of what presses on the slots (every such number
     inside the candidates' interquartile range, slots full for part of the
     window), and calmer at the close than three quarters of them."""
-    stands = arrange.standing(candidates, MIX["arrangement_seed"])
+    mix, serving, slots_full_floor, candidates = picked
+    stands = arrange.standing(candidates, mix["arrangement_seed"])
     for key in arrange.PRESSURE:
         s = stands[key]
         assert s["q1"] <= s["chosen"] <= s["q3"], (key, s)
-    assert stands["in_flight_peak"]["chosen"] > SERVING["num_slots"]
-    assert stands["slots_full_pct"]["chosen"] > 5
+    assert stands["in_flight_peak"]["chosen"] > serving["num_slots"]
+    assert stands["slots_full_pct"]["chosen"] > slots_full_floor
     assert stands["in_flight_at_close"]["chosen"] < \
         stands["in_flight_at_close"]["q1"]
+    before = mix["arrangement_rule"].get("measured_before")
+    if not before:
+        return
     # the arrangements measured before it were not: one closed on a burst,
     # the other never filled the slots
     burst, idle = (next(r for r in candidates + [
-        arrange.walk(MIX, SERVING, seed, SECONDS)]
-        if r["arrangement_seed"] == seed)
-        for seed in MIX["arrangement_rule"]["measured_before"])
+        arrange.walk(mix, serving, seed, SECONDS)]
+        if r["arrangement_seed"] == seed) for seed in before)
     assert burst["in_flight_at_close"] > stands["in_flight_at_close"]["q3"]
-    assert idle["in_flight_peak"] < SERVING["num_slots"]
+    assert idle["in_flight_peak"] < serving["num_slots"]
     assert idle not in arrange.typical(candidates)
 
 
-def hand_mix(rate, **kw):
+def test_walk_skips_a_cached_prefix_from_the_request_the_mix_states():
+    """GPT-2's prefix cache serves a header's blocks from the second
+    request behind it on, the hybrid's snapshot a document from the third
+    on: the new mix says so, and the walk follows."""
+    mix = load(BENCH, "traffic", "complete_v2_r80.json")
+    assert mix["arrangement_rule"]["shared_prefilled_by"] == 1
+    assert "shared_prefilled_by" not in load(
+        BENCH, "traffic", "docs_r80.json")["arrangement_rule"]
+    serving = {"num_slots": 1, "block_size": 128, "prefill_chunk": 256}
+    shared = {"count": 1, "tokens": 256, "share": 1.0, "min_body": 64}
+    got = arrange.walk(hand_mix(1.0, {"shared_prefilled_by": 1},
+                                shared_prefix=shared), serving, 7, 10.0)
+    assert got["slots_full_pct"] == pytest.approx(
+        100 * (1 * 0.35 + 9 * 0.25) / 10)
+
+
+def hand_mix(rate, rule=None, **kw):
     return {"kind": "open_loop", "rate_per_s": rate, "arrivals": "uniform",
             "prompt_tokens": {"law": "uniform", "min": 512, "max": 512},
             "output_tokens": {"law": "uniform", "min": 4, "max": 4},
             "vocab": 100, "drain_seconds": 60,
             "arrangement_rule": {"step_model_ms": {
                 "host": 0.0, "prefill_chunk": 100.0, "decode_base": 50.0,
-                "decode_per_table_block": 0.0}}, **kw}
+                "decode_per_table_block": 0.0}, **(rule or {})}, **kw}
 
 
 @pytest.mark.parametrize("slots, wait_ms, peak", [(4, 0.0, 1), (1, 0.0, 1)])

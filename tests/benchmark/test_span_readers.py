@@ -20,7 +20,7 @@ BENCH = os.path.join(ROOT, "benchmark")
 from benchmark import harness, trace_reduce  # noqa: E402
 from benchmark.readers import spans as rd  # noqa: E402
 
-TRAIN, SERVE = "gpt2m_train_1k", "gpt2xl_serve_complete_r80"
+TRAIN, SERVE = "gpt2m_train_1k", "gpt2xl_serve_complete_v2_r80"
 #: the ring's clock at the instant the hand-built trace began, in seconds
 T0 = 5000.0
 
@@ -240,23 +240,34 @@ def test_without_spans_in_the_context_the_programs_default_ring_is_read():
     assert read("serve_step_host_ms.complete", ctx) is None
 
 
-def test_manifest_lists_the_seven_span_metrics_last_and_finds_their_readers():
+#: PR 24's seven span metrics and the cells each lists
+SEVEN = {"train_input_wait_ms": [TRAIN], "train_loop_host_ms": [TRAIN],
+         "serve_step_host_ms.complete": [SERVE],
+         "serve_prefill_phase_p50_ms.complete": [SERVE],
+         "decode_kv_useful_pct.complete": [SERVE],
+         "setup_trace_lower_s": [TRAIN, SERVE],
+         "setup_backend_compile_s": [TRAIN, SERVE]}
+
+
+def the_seven(manifest: dict) -> tuple:
+    """(where the seven stand in `per_layer`, their entries), found by
+    name: later PRs append after them, so their place from the end moves."""
+    names = [m["name"] for m in manifest["per_layer"]]
+    at = names.index("train_input_wait_ms")
+    return at, manifest["per_layer"][at:at + 7]
+
+
+def test_manifest_lists_the_seven_span_metrics_and_finds_their_readers():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    cells = {"train_input_wait_ms": [TRAIN], "train_loop_host_ms": [TRAIN],
-             "serve_step_host_ms.complete": [SERVE],
-             "serve_prefill_phase_p50_ms.complete": [SERVE],
-             "decode_kv_useful_pct.complete": [SERVE],
-             "setup_trace_lower_s": [TRAIN, SERVE],
-             "setup_backend_compile_s": [TRAIN, SERVE]}
-    last = manifest["per_layer"][-7:]
-    assert [m["name"] for m in last] == list(cells)
+    _, seven = the_seven(manifest)
+    assert [m["name"] for m in seven] == list(SEVEN)  # together, in order
     layers = {m["layer"] for m in manifest["per_layer"]}
     assert {"train loop", "start-up"} <= layers
-    for m in last:
+    for m in seven:
         spec = spec_of(m["name"])
         assert {k: spec[k] for k in m} == m
-        assert m["workloads"] == cells[m["name"]]
+        assert m["workloads"] == SEVEN[m["name"]]
         module, _, func = spec["reader"].partition(":")
         reader = getattr(importlib.import_module(
             f"benchmark.readers.{module}"), func)
@@ -264,7 +275,7 @@ def test_manifest_lists_the_seven_span_metrics_last_and_finds_their_readers():
     # through the harness's own dispatch, each cell's line gets its own
     for cell, n in ((TRAIN, 4), (SERVE, 5)):
         got = harness.per_layer_metrics(
-            {"per_layer": last}, cell, hand_ctx())
+            {"per_layer": seven}, cell, hand_ctx())
         assert len(got) == n and all(
             got[k]["value"] == pytest.approx(EXPECTED[k]) for k in got)
 
