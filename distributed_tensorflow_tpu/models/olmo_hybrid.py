@@ -102,12 +102,22 @@ class OlmoHybridConfig:
     def count(self, kind: str) -> int:
         return self.layer_types.count(kind)
 
+    def decoder(self) -> "OlmoHybrid":
+        """The model this config describes (``serve.ServeEngine`` asks)."""
+        return OlmoHybrid(self)
+
+    @property
+    def linear_key_heads(self) -> int:
+        """q and k heads of a linear layer: one for every value head here
+        (``models/gigachat3_5.py`` has fewer, each serving a group)."""
+        return self.linear_heads
+
     @property
     def conv_channels(self) -> int:
         """q, k and v of a linear layer side by side: what its convolution
         runs over and its window remembers."""
-        return self.linear_heads * (2 * self.linear_key_dim
-                                    + self.linear_value_dim)
+        return (2 * self.linear_key_heads * self.linear_key_dim
+                + self.linear_heads * self.linear_value_dim)
 
 
 #: leaf -> shape as a function of the config, for each kind of layer
@@ -249,12 +259,17 @@ def _gates(x, p, cfg):
 
 def _split_qkv(y, cfg):
     """Convolved and activated channels [..., C] -> q, k (unit length, q
-    scaled) and v by head."""
+    scaled) and v by value head: where there are fewer q/k heads, q/k head
+    ``j`` serves value heads ``j * g .. j * g + g - 1`` and is repeated
+    for each of them before the kernels."""
     H, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    Hk = cfg.linear_key_heads
     lead = y.shape[:-1]
-    q, k, v = jnp.split(y, [H * dk, 2 * H * dk], axis=-1)
-    q = _l2norm(q.reshape(*lead, H, dk)) * dk ** -0.5
-    k = _l2norm(k.reshape(*lead, H, dk))
+    q, k, v = jnp.split(y, [Hk * dk, 2 * Hk * dk], axis=-1)
+    q = _l2norm(q.reshape(*lead, Hk, dk)) * dk ** -0.5
+    k = _l2norm(k.reshape(*lead, Hk, dk))
+    if Hk != H:
+        q, k = (jnp.repeat(t, H // Hk, axis=-2) for t in (q, k))
     return q, k, v.reshape(*lead, H, dv)
 
 
@@ -274,8 +289,10 @@ def _qkv_inputs(x, p):
 
 
 def _linear_chunk(x, p, cfg, state, conv, layer, slot, length, fresh):
-    """``T`` tokens of one request (x [T, d]) through a linear layer, from
-    the slot's carried state and convolution window to the new ones."""
+    """``T`` tokens of one request (x [T, d]) through a linear layer's
+    mixer, from the slot's carried state and convolution window to the new
+    ones. Returns (the rule's output o [T, H, dv], state, conv): what
+    follows it is the decoder's."""
     T, c = x.shape[0], cfg.conv_kernel
     u = _qkv_inputs(x, p)                                     # [T, C]
     window = jnp.where(fresh, 0.0, conv[layer, slot])         # [c-1, C]
@@ -292,11 +309,11 @@ def _linear_chunk(x, p, cfg, state, conv, layer, slot, length, fresh):
     o, state = gated_delta.gated_delta_chunk(
         q, k, v, g, beta, state, layer=layer, slot=slot,
         length=length, fresh=fresh, impl=cfg.gated_delta_impl)
-    return _linear_out(x, o, p, cfg), state, conv
+    return o, state, conv
 
 
 def _linear_step(x, p, cfg, state, conv, layer, live):
-    """One token for every slot (x [B, d])."""
+    """One token for every slot (x [B, d]); returns as `_linear_chunk`."""
     u = _qkv_inputs(x, p)                                     # [B, C]
     window = conv[layer]                                      # [B, c-1, C]
     taps = _conv_taps(p)
@@ -309,7 +326,7 @@ def _linear_step(x, p, cfg, state, conv, layer, live):
     o, state = gated_delta.gated_delta_step(
         q, k, v, g, beta, state, layer=layer, live=live,
         impl=cfg.gated_delta_impl)
-    return _linear_out(x, o, p, cfg), state, conv
+    return o, state, conv
 
 
 def _linear_full_sequence(x, p, cfg):
@@ -389,6 +406,18 @@ class OlmoHybrid:
     def __init__(self, cfg: OlmoHybridConfig):
         self.cfg = cfg
 
+    def init_params(self, key) -> dict:
+        """Random parameters (``init_params``)."""
+        return init_params(self.cfg, key)
+
+    def init_cache(self, num_slots, num_blocks, block_size, num_snapshots,
+                   dtype=jnp.bfloat16):
+        """The engine's cache for this model: ``serve.kv_cache.HybridCache``."""
+        from ..serve import kv_cache
+
+        return kv_cache.init_hybrid_cache(self.cfg, num_slots, num_blocks,
+                                          block_size, num_snapshots, dtype)
+
     def _scan(self, params, carry, layer_fn):
         """``layer_fn(kind, index of the layer among its kind, its
         parameters, carry) -> carry`` over every layer, a period a scan
@@ -445,8 +474,9 @@ class OlmoHybrid:
         def layer(kind, index, p, carry):
             x, k, v, state, conv = carry
             if kind == LINEAR:
-                x, state, conv = _linear_chunk(
+                o, state, conv = _linear_chunk(
                     x, p, cfg, state, conv, index, slot, length, fresh)
+                x = _linear_out(x, o, p, cfg)
             else:
                 y, k, v = _full_paged(x[None], p, cfg, k, v, index,
                                       table_row[None], pos)
@@ -473,8 +503,9 @@ class OlmoHybrid:
         def layer(kind, index, p, carry):
             x, k, v, state, conv = carry
             if kind == LINEAR:
-                x, state, conv = _linear_step(x, p, cfg, state, conv, index,
+                o, state, conv = _linear_step(x, p, cfg, state, conv, index,
                                               live)
+                x = _linear_out(x, o, p, cfg)
             else:
                 y, k, v = _full_paged(x[:, None], p, cfg, k, v, index,
                                       block_tables, lengths[:, None])

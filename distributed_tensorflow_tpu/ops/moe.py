@@ -25,11 +25,14 @@ token dispatch over an `expert` mesh axis). TPU-first design:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ..parallel import mesh as mesh_lib
@@ -283,3 +286,220 @@ def collect_aux_loss(variables: Any) -> jax.Array:
 def flops_per_token(cfg: MoEConfig) -> float:
     """Fwd FLOPs per token: top_k experts' FFN matmuls (router negligible)."""
     return cfg.top_k * 2.0 * 2.0 * cfg.d_model * cfg.d_ff
+
+
+# ---------------------------------------------------------------------------
+# Expert share: dropless, sorted dispatch to the experts this device holds
+# (the layer of an expert-parallel deployment, without its exchange)
+# ---------------------------------------------------------------------------
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+#: rows of a group tile: a decode step of some tens of slots gives a held
+#: expert a few assignments, a prefill chunk some tens
+DECODE_TILE_ROWS, PREFILL_TILE_ROWS = 16, 128
+GMM_VMEM_LIMIT = 64 * 1024 * 1024  # of the v5e's 128 MiB; the default is 16
+
+
+def _divisor_tile(n: int, most: int) -> int:
+    """The largest divisor of ``n`` that is at most ``most`` and a multiple
+    of the 128 lanes, or ``n`` itself where there is none."""
+    for t in range(min(n, most) // 128 * 128, 0, -128):
+        if n % t == 0:
+            return t
+    return n
+
+
+def _gmm_kernel(te_ref, nr_ref, ly_ref, x_ref, *refs, n_w, limit):
+    """One row tile of one held expert against one column tile of its
+    weights, accumulated over the contraction's tiles. With two weight
+    operands (gate, up) the result is the clamped SwiGLU of the two
+    products; with one, the product. A tile past the real ones computes and
+    writes nothing (its blocks are the last real tile's, so nothing is
+    fetched or written back either)."""
+    del te_ref, ly_ref
+    w_refs, o_ref, acc_refs = refs[:n_w], refs[n_w], refs[n_w + 1:]
+    t, k = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(t < nr_ref[0])
+    def _tile():
+        @pl.when(k == 0)
+        def _init():
+            for acc in acc_refs:
+                acc[...] = jnp.zeros_like(acc)
+
+        x = x_ref[...]
+        for w, acc in zip(w_refs, acc_refs):
+            acc[...] += jnp.dot(x, w[0, 0],
+                                preferred_element_type=jnp.float32)
+
+        @pl.when(k == pl.num_programs(2) - 1)
+        def _out():
+            o_ref[...] = _finish([a[...] for a in acc_refs], limit).astype(
+                o_ref.dtype)
+
+
+def _finish(products, limit):
+    """Two products (gate, up): the SwiGLU with its inputs clamped at
+    ``limit`` (the gate from above, the up product both ways); one: as it
+    is."""
+    if len(products) == 1:
+        return products[0]
+    g, u = products
+    return jax.nn.silu(jnp.minimum(g, limit)) * jnp.clip(u, -limit, limit)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "tile_rows", "limit", "out_dtype", "interpret"))
+def _gmm_pallas(x, weights, tile_expert, n_real, layer, *, tile_rows, limit,
+                out_dtype, interpret):
+    M, K = x.shape
+    N = weights[0].shape[-1]
+    tm, n_w = tile_rows, len(weights)
+    tk = _divisor_tile(K, 3584)
+    tn = _divisor_tile(N, 512 if n_w == 2 else 1024)
+    nk, nn_ = K // tk, N // tn
+
+    def at(live, i, last):
+        return jnp.where(live, i, last)
+
+    def x_map(t, n, k, te, nr, ly):
+        live = t < nr[0]
+        return at(live, t, jnp.maximum(nr[0] - 1, 0)), at(live, k, nk - 1)
+
+    def w_map(t, n, k, te, nr, ly):
+        live = t < nr[0]
+        return ly[0], te[t], at(live, k, nk - 1), at(live, n, nn_ - 1)
+
+    def o_map(t, n, k, te, nr, ly):
+        live = t < nr[0]
+        return at(live, t, jnp.maximum(nr[0] - 1, 0)), at(live, n, nn_ - 1)
+
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, n_w=n_w, limit=limit),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(M // tm, nn_, nk),
+            in_specs=[pl.BlockSpec((tm, tk), x_map)]
+            + [pl.BlockSpec((1, 1, tk, tn), w_map)] * n_w,
+            out_specs=pl.BlockSpec((tm, tn), o_map),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)] * n_w,
+        ),
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=GMM_VMEM_LIMIT),
+        interpret=interpret,
+        name="moe_grouped_mm",
+    )(tile_expert, n_real.reshape(1), jnp.asarray(layer, jnp.int32).reshape(1),
+      x, *weights)
+
+
+def moe_grouped_mm(x, weights, tile_expert, n_real, *, layer, tile_rows,
+                   limit=None, out_dtype=jnp.float32, impl="auto"):
+    """Grouped matmul over held experts' row groups. ``x`` [M, K] holds
+    ``M / tile_rows`` tiles of rows, tile ``t`` all of expert
+    ``tile_expert[t]``'s (a group padded to whole tiles), the first
+    ``n_real`` tiles real; ``weights`` is one stack [layers, experts, K, N]
+    (the product) or two (gate and up: the clamped SwiGLU of the two
+    products, at ``limit``), of which row ``layer`` is read in place.
+    Returns [M, N] in ``out_dtype``; rows of tiles past ``n_real`` are not
+    written (the caller reads none of them). ``impl``: the Pallas kernel
+    ``moe_grouped_mm`` (``"pallas"``; the interpreter off the TPU), the
+    same in ``jax.numpy`` (``"plain"``), or by backend (``"auto"``)."""
+    if impl == "auto":
+        impl = "pallas" if _on_tpu() else "plain"
+    weights = tuple(weights)
+    n_real = jnp.asarray(n_real, jnp.int32)
+    if impl == "pallas":
+        return _gmm_pallas(x, weights, tile_expert.astype(jnp.int32), n_real,
+                           layer, tile_rows=tile_rows, limit=limit,
+                           out_dtype=jnp.dtype(out_dtype),
+                           interpret=not _on_tpu())
+    if impl != "plain":
+        raise ValueError(f"grouped matmul impl must be 'auto', 'plain' or "
+                         f"'pallas', got {impl!r}")
+    tiles = x.reshape(-1, tile_rows, x.shape[-1])
+    out = _finish([jnp.einsum("tmk,tkn->tmn", tiles, w[layer][tile_expert],
+                              preferred_element_type=jnp.float32)
+                   for w in weights], limit)
+    return out.reshape(x.shape[0], -1).astype(out_dtype)
+
+
+def sigmoid_route(x, router_w, bias, *, top_k, scale):
+    """DeepSeek-V3 routing over every expert of the layer: scores
+    ``s = sigmoid(x W_r)`` in float32, the ``top_k`` experts by ``s + b``
+    (``b`` the per-expert correction, used for the choice only), weights
+    ``s / sum(s of the chosen) * scale``. Returns (experts [T, k], weights
+    [T, k] float32)."""
+    s = jax.nn.sigmoid(jnp.matmul(x.astype(jnp.float32), router_w,
+                                  precision=jax.lax.Precision.HIGHEST))
+    _, top = jax.lax.top_k(s + bias, top_k)
+    chosen = jnp.take_along_axis(s, top, axis=-1)
+    return top, chosen / chosen.sum(-1, keepdims=True) * scale
+
+
+def expert_share(x, router_w, bias, w_gate, w_up, w_down, *, layer, first,
+                 top_k, scale, limit, valid=None, impl="auto"):
+    """The routed part of a sigmoid-routed MoE layer that one device of an
+    expert-parallel deployment computes: routing over ALL the layer's
+    experts (``router_w`` [d, E_all]), then the assignments to the experts
+    this device holds, ``[first, first + E)`` (``w_gate``/``w_up`` [layers,
+    E, d, f], ``w_down`` [layers, E, f, d], row ``layer``), sorted by
+    expert with no capacity and nothing dropped; assignments to experts
+    held elsewhere contribute nothing here.
+
+    The held experts' rows form contiguous groups, each padded to whole
+    tiles of ``DECODE_TILE_ROWS`` or ``PREFILL_TILE_ROWS`` rows, and one
+    grouped matmul kernel (``moe_grouped_mm``) runs the gate and up
+    products with the clamped SwiGLU, another the down product. The tiles
+    are counted for the worst case (every assignment held), so the shapes
+    are static; tiles past the real ones do nothing.
+
+    x [T, d] float32; ``valid`` [T] (all by default): tokens that are
+    not (padding, idle slots) are assigned to no expert. Returns (y [T, d]
+    float32, assignments [E] int32: the count of local assignments each
+    held expert received)."""
+    T, d = x.shape
+    E = w_gate.shape[1]
+    top, weight = sigmoid_route(x, router_w, bias, top_k=top_k, scale=scale)
+    local = top - first
+    held = (local >= 0) & (local < E)
+    if valid is not None:
+        held = held & valid[:, None]
+    flat = jnp.where(held, local, E).reshape(-1)                # [T k]
+    counts = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
+    tm = DECODE_TILE_ROWS if T <= 64 else PREFILL_TILE_ROWS
+    n_tiles = -(-min(T * top_k, T * E) // tm) + E
+    M = n_tiles * tm
+    tiles = -(-counts // tm)
+    ends = jnp.cumsum(tiles)                                    # in tiles
+    n_real = ends[-1]
+    # each assignment's row: its group's padded start plus its rank in the
+    # group; an assignment held elsewhere goes to row M (dropped)
+    order = jnp.argsort(flat, stable=True)
+    by_expert = flat[order]
+    starts = jnp.cumsum(counts) - counts
+    rank = jnp.arange(T * top_k) - jnp.append(starts, 0)[by_expert]
+    pstart = jnp.append((ends - tiles) * tm, M)
+    row_sorted = jnp.where(by_expert < E, pstart[by_expert] + rank, M)
+    row = jnp.zeros_like(flat).at[order].set(row_sorted)        # [T k]
+    xs = jnp.zeros((M, d), w_gate.dtype).at[row].set(
+        jnp.repeat(x.astype(w_gate.dtype), top_k, axis=0), mode="drop")
+    # tile -> expert; tiles past the real ones keep the last real one's
+    tile_expert = jnp.searchsorted(
+        ends, jnp.minimum(jnp.arange(n_tiles), jnp.maximum(n_real - 1, 0)),
+        side="right").astype(jnp.int32)
+    tile_expert = jnp.minimum(tile_expert, E - 1)
+    h = moe_grouped_mm(xs, (w_gate, w_up), tile_expert, n_real, layer=layer,
+                       tile_rows=tm, limit=limit, out_dtype=w_down.dtype,
+                       impl=impl)
+    ys = moe_grouped_mm(h, (w_down,), tile_expert, n_real, layer=layer,
+                        tile_rows=tm, out_dtype=jnp.float32, impl=impl)
+    held = held.reshape(-1)
+    got = jnp.where(held[:, None], ys[jnp.minimum(row, M - 1)], 0.0)
+    y = (got.reshape(T, top_k, d) * weight[..., None]).sum(1)
+    return y, counts

@@ -20,8 +20,10 @@ by name: they ask ``model.prefill_chunk(params, cache, table_row, tokens,
 start, length, slot)`` and ``model.decode_step(params, cache,
 block_tables, tokens, lengths)``, and the engine asks ``model.has_state``
 (recurrent state beside the K/V pool: snapshots for prefix reuse, no
-speculation). ``models.transformer.Transformer`` and
-``models.olmo_hybrid.OlmoHybrid`` answer it.
+speculation). ``models.transformer.Transformer``,
+``models.olmo_hybrid.OlmoHybrid`` and ``models.gigachat3_5.GigaChat35``
+answer it; a model with state also builds its own cache
+(``model.init_cache``).
 
 Numerics: the cache path runs the same f32 masked softmax(QKᵀ)V as the
 dense reference (ops.attention.cached_attention docstring), so cached
@@ -203,14 +205,13 @@ def copy_block(
     cache: PagedKVCache, src: jax.Array, dst: jax.Array
 ) -> PagedKVCache:
     """Copy-on-write resolution: duplicate physical block ``src`` into
-    ``dst`` across every layer and both buffers, on device. The engine
-    calls this (jit, donated) before the first divergent write into a
-    block whose refcount is > 1."""
-    return dataclasses.replace(
-        cache,
-        k=cache.k.at[:, dst].set(cache.k[:, src]),
-        v=cache.v.at[:, dst].set(cache.v[:, src]),
-    )
+    ``dst`` across every layer and every pool of the cache (``POOLS``: K
+    and V, or the latent rows), on device. The engine calls this (jit,
+    donated) before the first divergent write into a block whose refcount
+    is > 1."""
+    return dataclasses.replace(cache, **{
+        name: getattr(cache, name).at[:, dst].set(getattr(cache, name)[:, src])
+        for name in cache.POOLS})
 
 
 def take_snapshot(cache: HybridCache, slot: jax.Array,

@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import olmo_hybrid
 from ..models.transformer import Transformer, TransformerConfig, make_init_fn
 from ..obs import flightrec as flightrec_lib
 from ..obs import trace as trace_lib
@@ -52,13 +51,11 @@ from . import decode as decode_lib
 from . import sampling
 from .kv_cache import (
     BlockAllocator,
-    HybridCache,
     KVCache,
     NoFreeBlocks,
     PagedKVCache,
     SnapshotTable,
     init_cache,
-    init_hybrid_cache,
     init_paged_cache,
 )
 from .scheduler import (
@@ -95,9 +92,11 @@ class StepStats:
 
 class ServeEngine:
     """KV-cached continuous-batching inference over a causal Transformer,
-    or over a hybrid decoder (``models.olmo_hybrid.OlmoHybridConfig``:
-    recurrent state beside the paged cache, docs/serving.md "Hybrid
-    cache"); the config's class picks the model.
+    or over a decoder with recurrent state beside its paged pool
+    (``models.olmo_hybrid.OlmoHybridConfig``, docs/serving.md "Hybrid
+    cache"; ``models.gigachat3_5.GigaChat35Config``, "Latent pool and
+    expert share"); a config that names its decoder (``cfg.decoder()``)
+    picks it, any other is a ``Transformer``'s.
 
     >>> eng = ServeEngine.with_random_params(cfg, num_slots=4)
     >>> uid = eng.submit([5, 17, 3], max_new_tokens=16)
@@ -107,7 +106,7 @@ class ServeEngine:
 
     def __init__(
         self,
-        cfg: TransformerConfig | olmo_hybrid.OlmoHybridConfig,
+        cfg,
         params,
         *,
         num_slots: int = 4,
@@ -149,12 +148,12 @@ class ServeEngine:
             )
         if spec_ngram < 1:
             raise ValueError(f"spec_ngram must be >= 1, got {spec_ngram}")
-        # the config's class picks the model; from here on the engine asks
-        # the model, not its name (serve/decode.py "the serving protocol")
-        self.model = (olmo_hybrid.OlmoHybrid(cfg)
-                      if isinstance(cfg, olmo_hybrid.OlmoHybridConfig)
+        # the config picks the model; from here on the engine asks the
+        # model, not its name (serve/decode.py "the serving protocol")
+        self.model = (cfg.decoder() if hasattr(cfg, "decoder")
                       else Transformer(cfg))
-        #: recurrent layers beside the paged cache (HybridCache)
+        #: recurrent layers beside the paged pool: the model builds the
+        #: cache that holds both (``model.init_cache``)
         self.has_state = self.model.has_state
         if self.has_state and not paged:
             raise ValueError("a model with recurrent layers is served "
@@ -201,9 +200,8 @@ class ServeEngine:
                         f"of block_size={block_size} for a hybrid decoder: "
                         f"state snapshots are taken at block-aligned chunk "
                         f"ends")
-                self.cache: HybridCache = init_hybrid_cache(
-                    cfg, num_slots, num_blocks, block_size,
-                    num_state_snapshots,
+                self.cache = self.model.init_cache(
+                    num_slots, num_blocks, block_size, num_state_snapshots,
                     dtype=jnp.bfloat16 if cache_dtype is None else cache_dtype)
                 #: prefix -> snapshot row; dies with the allocator's blocks
                 self.snapshots = SnapshotTable(num_state_snapshots,
@@ -364,6 +362,16 @@ class ServeEngine:
             "prefix-cache block they belonged to")
         self._m_snap_live = r.gauge(
             "state_snapshots_live", "snapshot rows that hold a prefix")
+        # the expert layer's share (docs/observability.md "Serve latency
+        # metrics"); unconditional, zeros on an engine without one
+        self._m_moe_assigned = r.counter(
+            "moe_local_assignments_total",
+            "token-to-expert assignments to the experts this engine holds")
+        self._m_moe_calls = r.counter(
+            "moe_expert_calls_total",
+            "held experts that received at least one token, summed over "
+            "expert-layer calls")
+        self._moe_seen = None
         self._snap_seen = (0, 0, 0)
         #: engine-lifetime accept accounting behind the gauge
         self._spec_proposed = 0
@@ -376,8 +384,8 @@ class ServeEngine:
         cls, cfg: TransformerConfig, *, seed: int = 0, **kw
     ) -> "ServeEngine":
         """Random-weight engine for demos/benches (examples/serve.py)."""
-        if isinstance(cfg, olmo_hybrid.OlmoHybridConfig):
-            params = olmo_hybrid.init_params(cfg, jax.random.PRNGKey(seed))
+        if hasattr(cfg, "decoder"):
+            params = cfg.decoder().init_params(jax.random.PRNGKey(seed))
             return cls(cfg, params, seed=seed, **kw)
         params, _ = make_init_fn(Transformer(cfg), min(8, cfg.max_len))(
             jax.random.PRNGKey(seed)
@@ -578,6 +586,25 @@ class ServeEngine:
             self._snap_seen = now
             self._m_snap_live.set(float(len(snaps)))
 
+    def _observe_expert_load(self, sp) -> None:
+        """A cache that carries an expert layer's running counts
+        (``LatentCache.moe_counts``, read once the step's tokens are on
+        the host): what changed since the last fetch goes on the step's
+        span (``moe_assignments``, ``moe_expert_calls``) and into the
+        registry's counters. Steps between two fetches (prefill chunks
+        that end no prompt) are counted at the next."""
+        counts = getattr(self.cache, "moe_counts", None)
+        if counts is None:
+            return
+        now = np.asarray(counts).astype(np.int64)
+        seen = np.zeros_like(now) if self._moe_seen is None else self._moe_seen
+        delta = (now - seen) % (1 << 32)
+        self._moe_seen = now
+        assigned, calls = int(delta[0].sum()), int(delta[1].sum())
+        sp.attrs.update(moe_assignments=assigned, moe_expert_calls=calls)
+        self._m_moe_assigned.inc(assigned)
+        self._m_moe_calls.inc(calls)
+
     def _mb_bucket(self, hi_blocks: int) -> int:
         """Table width (in blocks) to hand the jit'd step: the smallest
         power of two covering the widest live slot, capped at the full
@@ -777,12 +804,15 @@ class ServeEngine:
                 self.params, self.cache, table, buf, start, n,
                 *((slot,) if self.has_state else ()),
             )
-            if (self.has_state and end % self.block_size == 0
-                    and end <= self._shared_upto.get(slot, 0)
+            shared = self._shared_upto.get(slot, 0) if self.has_state else 0
+            if (end % self.block_size == 0 and end <= shared
+                    and end + self.prefill_chunk > shared
                     and self.alloc.is_cached(toks[:end])):
-                # a chunk end inside the part of the prompt that the prefix
-                # cache matched and no snapshot covered: the next request
-                # behind this prefix starts from here
+                # the last chunk end inside the part of the prompt that the
+                # prefix cache matched and no snapshot covered: the next
+                # request behind this prefix starts from here (one row a
+                # shared prefix; the chunk ends before it would hold states
+                # that no request of the prefix restores)
                 row = self.snapshots.take(toks[:end])
                 if row is not None:
                     self.cache = self._take_snapshot(self.cache, slot, row)
@@ -814,6 +844,7 @@ class ServeEngine:
                     temperature=self.temperature, top_k=self.top_k,
                 )
             )
+            self._observe_expert_load(sp)
         self._last[slot] = tok
         if self.reqtrace is not None and req.rid is not None:
             # prefill complete, first token of this residency sampled —
@@ -1103,6 +1134,7 @@ class ServeEngine:
                     temperature=self.temperature, top_k=self.top_k,
                 )
             )
+            self._observe_expert_load(sp)
         with tracer.span("deliver"):
             for slot in active:
                 # the decode wrote k/v at the old index

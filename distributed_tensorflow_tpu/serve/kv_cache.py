@@ -1,6 +1,6 @@
 """KV caches — the resident state of the decode engine.
 
-Three layouts live here (docs/serving.md):
+Four layouts live here (docs/serving.md):
 
 - **Paged** (the default): a fixed pool of KV blocks (``PagedKVCache``)
   plus host-side free/used accounting with copy-on-write refcounts and
@@ -15,6 +15,11 @@ Three layouts live here (docs/serving.md):
   pool for the full-attention layers only and, beside it, per slot and
   per linear layer, the recurrent state and the convolution's window,
   plus a pool of state snapshots for prefix reuse (``SnapshotTable``).
+
+- **Latent** (``LatentCache``, for ``models/gigachat3_5.py``): the same
+  beside-the-pool state and snapshots, with a pool of latent rows (one a
+  token, shared by every head) in place of the K/V pool, and the running
+  counts of the expert layer's local assignments.
 
 Dense layout: one pair of buffers for the whole model, layers stacked
 on the leading axis::
@@ -48,6 +53,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..models.transformer import TransformerConfig
+from ..ops.latent_attention import stored_width
 from ..parallel import mesh as mesh_lib
 from ..parallel import sharding
 
@@ -168,6 +174,9 @@ class PagedKVCache:
 
     k: jax.Array
     v: jax.Array
+
+    #: the block pools (``decode.copy_block`` copies a block in each)
+    POOLS = ("k", "v")
 
     @property
     def num_blocks(self) -> int:
@@ -305,6 +314,8 @@ class HybridCache:
     snap_state: jax.Array
     snap_conv: jax.Array
 
+    POOLS = ("k", "v")
+
     @property
     def num_blocks(self) -> int:
         """Blocks a table can name (the allocator's count, and the table's
@@ -359,6 +370,73 @@ def init_hybrid_cache(cfg, num_slots: int, num_blocks: int, block_size: int,
         snap_state=jnp.zeros((n_lin, num_snapshots, H, dk, dv), f32),
         snap_conv=jnp.zeros((n_lin, num_snapshots, taps, cfg.conv_channels),
                             f32))
+
+
+@dataclasses.dataclass
+class LatentCache:
+    """The serving state of a decoder with latent-attention layers beside
+    linear-attention layers and an expert layer
+    (``models.gigachat3_5.GigaChat35``).
+
+    - ``kv``: [latent layers, num_blocks + 1, 1, block_size, W], one row a
+      cached token, ``[c_kv || k_r]`` shared by every head
+      (``ops.latent_attention``; W the row's lanes, on the TPU rounded up
+      to 128), write-off block last, under the paged engine's block
+      tables, allocator and prefix cache.
+    - ``state``/``conv``/``snap_state``/``snap_conv``: as ``HybridCache``
+      keeps them, for the linear layers.
+    - ``moe_counts``: [2, held experts] int32, running totals over every
+      expert layer and step: the local assignments each held expert
+      received, and the calls in which it received any. The engine reads
+      the change at each fetch it makes (wrapping at 2**32)."""
+
+    kv: jax.Array
+    state: jax.Array
+    conv: jax.Array
+    snap_state: jax.Array
+    snap_conv: jax.Array
+    moe_counts: jax.Array
+
+    POOLS = ("kv",)
+
+    @property
+    def num_blocks(self) -> int:
+        return self.kv.shape[1] - 1
+
+    @property
+    def block_size(self) -> int:
+        return self.kv.shape[3]
+
+
+jax.tree_util.register_dataclass(
+    LatentCache,
+    data_fields=["kv", "state", "conv", "snap_state", "snap_conv",
+                 "moe_counts"],
+    meta_fields=[],
+)
+
+
+def init_latent_cache(cfg, num_slots: int, num_blocks: int, block_size: int,
+                      num_snapshots: int,
+                      dtype: str | jnp.dtype = jnp.bfloat16) -> LatentCache:
+    """Zero-filled latent cache for a ``models.gigachat3_5.GigaChat35Config``;
+    ``dtype`` is the latent pool's, the recurrent state is float32."""
+    if min(num_blocks, block_size, num_slots) < 1 or num_snapshots < 0:
+        raise ValueError("num_blocks, block_size and num_slots must be >= 1, "
+                         "num_snapshots >= 0")
+    n_lin = cfg.count("linear_attention")
+    pool = (cfg.count("full_attention"), num_blocks + 1, 1, block_size,
+            stored_width(cfg.latent_width))
+    H, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    window = (cfg.conv_kernel - 1, cfg.conv_channels)
+    f32 = jnp.float32
+    return LatentCache(
+        kv=jnp.zeros(pool, dtype),
+        state=jnp.zeros((n_lin, num_slots, H, dk, dv), f32),
+        conv=jnp.zeros((n_lin, num_slots, *window), f32),
+        snap_state=jnp.zeros((n_lin, num_snapshots, H, dk, dv), f32),
+        snap_conv=jnp.zeros((n_lin, num_snapshots, *window), f32),
+        moe_counts=jnp.zeros((2, cfg.experts_held), jnp.int32))
 
 
 class SnapshotTable:

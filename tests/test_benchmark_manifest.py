@@ -22,12 +22,24 @@ from benchmark import harness, trace_reduce  # noqa: E402
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     MANIFEST = json.load(f)
 
+#: the latent and expert cell's metrics, appended in this order
+LONGDOCS = [f"{name}.longdocs" for name in (
+    "moe_grouped_mm_roofline", "latent_attention_roofline",
+    "moe_tokens_per_expert", "moe_grouped_mm_device_pct",
+    "gated_delta_step_roofline", "gated_delta_chunk_roofline",
+    "serve_step_mfu", "decode_step_device_ms", "prefill_chunk_device_ms",
+    "device_idle_pct", "prefix_hit_pct", "pool_live_peak_pct",
+    "state_snapshot_trim_pct", "serve_step_host_ms", "setup_trace_lower_s",
+    "setup_backend_compile_s", "gated_delta_device_pct", "ttft_p50_ms",
+    "tpot_p50_ms", "serve_queue_wait_p95_ms")]
+
 
 def test_the_seven_span_metrics_and_what_was_appended_after_them():
     """PR 24's seven span metrics stand together and in order, find their
     readers and read the hand-built ring as they did; after them come only
-    entries that later PRs appended, in the order they came: PR 27's
-    eighteen `.docs` metrics, then PR 31's `pool_copy_device_pct.complete`."""
+    entries that later PRs appended, in the order they came: the eighteen
+    `.docs` metrics of the hybrid cell, then `pool_copy_device_pct.complete`,
+    then the twenty `.longdocs` metrics of the latent and expert cell."""
     import test_span_readers as t
 
     cells = {"train_input_wait_ms": [t.TRAIN],
@@ -43,7 +55,8 @@ def test_the_seven_span_metrics_and_what_was_appended_after_them():
     assert [m["name"] for m in seven] == list(cells)   # together, in order
     after = names[at + 7:]
     assert all(n.endswith(".docs") for n in after[:18])
-    assert after[18:] == ["pool_copy_device_pct.complete"]
+    assert after[18] == "pool_copy_device_pct.complete"
+    assert after[19:] == LONGDOCS
     for m in seven:
         spec = t.spec_of(m["name"])
         assert {k: spec[k] for k in m} == m
@@ -72,7 +85,9 @@ def test_pool_copy_share_counts_each_copy_operation_once():
         spec = json.load(f)
     entry = [m for m in MANIFEST["per_layer"] if m["name"] == spec["name"]]
     assert entry == [{k: spec[k] for k in entry[0]}]
-    assert entry[0] == MANIFEST["per_layer"][-1]     # appended, not inserted
+    # appended, not inserted: only the later cell's entries come after it
+    at = MANIFEST["per_layer"].index(entry[0])
+    assert [m["name"] for m in MANIFEST["per_layer"][at + 1:]] == LONGDOCS
     assert set(spec) - set(entry[0]) == {"reader", "args"}
     names = spec["args"]["kernels"]
     assert len(names) == len(set(names)) and not any(
@@ -89,7 +104,7 @@ def test_pool_copy_share_counts_each_copy_operation_once():
         "cfg": {}, "traffic": {}, "chips": 1, "device_kind": "TPU v5 lite",
         "run": {}}
     only = {"per_layer": entry}
-    cell = "gpt2xl_serve_complete_r80"
+    cell = "gpt2xl_serve_complete_v2_r80"
     got = harness.per_layer_metrics(only, cell, ctx)
     assert got[spec["name"]] == {
         "value": pytest.approx(100 * 28 / 35), "unit": "%"}

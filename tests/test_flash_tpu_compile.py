@@ -1,6 +1,7 @@
 """The flash kernels, the paged kernels and the transformer's two serving
-programs at GPT-2-XL's serving shapes, and the hybrid decoder's kernels and
-step programs, compiled for a described
+programs at GPT-2-XL's serving shapes, the hybrid decoder's kernels and
+step programs, and GigaChat 3.5's (latent attention, grouped expert
+matmuls) at its published widths, compiled for a described
 (not attached) TPU v5e, at real sizes: Mosaic's layout rules and its scoped
 VMEM limit (16 MB unless a kernel asks for more) are what the interpreter
 cannot check and what the tile plans' own estimates have to stay under.
@@ -70,7 +71,7 @@ def kernels_for_the_chip(monkeypatch):
     CPU and the target the described chip."""
     import importlib
 
-    for name in ("flash_attention", "gated_delta"):
+    for name in ("flash_attention", "gated_delta", "latent_attention", "moe"):
         monkeypatch.setattr(importlib.import_module(
             f"distributed_tensorflow_tpu.ops.{name}"), "_on_tpu",
             lambda: True)
@@ -244,3 +245,107 @@ def test_hybrid_step_programs_compile_and_fit_v5e(
     assert mem.alias_size_in_bytes >= cache_bytes     # the cache, in place
     assert mem.temp_size_in_bytes < 0.5e9
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 15.0e9
+
+
+# ---------------------------------------------------------------------------
+# GigaChat 3.5 (models/gigachat3_5.py) at its published widths
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_latent_attention_kernel_compiles_for_v5e(
+        one_chip, kernels_for_the_chip, program):
+    """A prefill chunk of 512 tokens (64 heads a token: 32768 query rows) and
+    a decode step of 32 slots, against the latent pool of 9216 + 1 blocks
+    of 128 rows of 576 lanes stored at 640, the widest table (260 blocks)."""
+    from distributed_tensorflow_tpu.ops import latent_attention as la
+
+    B, S = (1, 512) if program == "prefill" else (32, 1)
+    S_ = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    pool = S_((1, 9217, 1, 128, 640), jnp.bfloat16)
+
+    def call(q, pool, table, q0, nq, layer):
+        return la.latent_attention(q, pool, table, q0, nq, layer=layer,
+                                   heads=64, value_width=512, sm_scale=0.1,
+                                   impl="pallas")
+
+    done = jax.jit(call).lower(
+        S_((B, S * 64, 640), jnp.bfloat16), pool, S_((B, 260), jnp.int32),
+        S_((B,), jnp.int32), S_((B,), jnp.int32), S_((), jnp.int32)).compile()
+    assert "paged_latent_attention" in done.as_text()
+    assert done.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+@pytest.mark.parametrize("tokens", [32, 512])
+def test_grouped_expert_matmul_compiles_for_v5e(
+        one_chip, kernels_for_the_chip, tokens):
+    """The held experts' part of a decode step (32 tokens) and of a prefill
+    chunk (512): routing over 256 experts, 16 held at d 7168 and width
+    2048, read in place from the stacks of 4 expert layers."""
+    from distributed_tensorflow_tpu.ops import moe
+
+    S_ = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    w = lambda *s: S_((4, 16, *s), jnp.bfloat16)
+
+    def call(x, router, bias, wg, wu, wd, layer):
+        return moe.expert_share(x, router, bias, wg, wu, wd, layer=layer,
+                                first=0, top_k=8, scale=2.5, limit=10.0,
+                                impl="pallas")
+
+    done = jax.jit(call).lower(
+        S_((tokens, 7168)), S_((7168, 256)), S_((256,)), w(7168, 2048),
+        w(7168, 2048), w(2048, 7168), S_((), jnp.int32)).compile()
+    assert "moe_grouped_mm" in done.as_text()
+    # the experts are read where they lie: nothing of a layer's 1.4 GB
+    assert done.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_gigachat_step_programs_compile_and_fit_v5e(
+        one_chip, kernels_for_the_chip, program):
+    """The two serving programs of the benchmark's cut (5 layers, 16 of 256
+    experts held, 32 slots, 9216 blocks of 128 latent rows, 48 snapshot
+    rows) at the widest table: every kernel is there, the cache rides the
+    layers in place, and weights, cache and temporaries fit the chip."""
+    import json
+    import os
+
+    from benchmark.families.gigachat3_5 import adapter
+    from distributed_tensorflow_tpu.models import gigachat3_5 as gc
+    from distributed_tensorflow_tpu.serve import decode
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "gigachat3.5-432b-a28b.json")) as f:
+        file = json.load(f)
+    model = gc.GigaChat35(adapter.model_config(file))
+    deploy = file["serving"]
+    on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, gc.param_shapes(model.cfg))
+    slots, width = deploy["num_slots"], 260
+    cache = jax.tree.map(on_chip, jax.eval_shape(lambda: model.init_cache(
+        slots, deploy["num_blocks"], 128, deploy["num_state_snapshots"])))
+    assert cache.kv.shape[-1] == 640              # 576 lanes stored at 640
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    if program == "prefill":
+        done = decode.jit_paged_prefill_chunk(model).lower(
+            params, cache, i32(width), i32(512), i32(), i32(), i32())
+        kernels = ("gated_delta_chunk_fwd",)
+    else:
+        done = decode.jit_paged_decode_step(model).lower(
+            params, cache, i32(slots, width), i32(slots), i32(slots))
+        kernels = ("gated_delta_step",)
+    done = done.compile()
+    text = done.as_text()
+    for kernel in kernels + ("paged_latent_attention", "moe_grouped_mm",
+                             "paged_kv_write"):
+        assert kernel in text
+    mem = done.memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes     # the cache, in place
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 14.0e9

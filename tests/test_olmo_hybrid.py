@@ -295,22 +295,22 @@ def test_prefix_reuse_with_snapshots_gives_the_logits_of_no_reuse(weights):
     eng = engine(weights)
     hits = lambda: eng.registry.get("prefix_reuse_hits_total").value
     # first request: nothing cached; second: the blocks match, no snapshot
-    # yet, the match is given up and the shared part snapshotted; third:
-    # starts from the snapshot at the document's end
+    # yet, the match is given up and the shared part snapshotted at its
+    # last chunk end (the document's end); third: starts from there
     for i, t in enumerate(tails):
         before = hits()
         err, gen = served_logit_error(eng, weights, doc + t, 8)
         assert gen == want[i] and err < TOL
         assert hits() - before == (4 if i == 2 else 0)
-    assert eng.snapshots.taken == 2 and eng.snapshots.hits == 1
+    assert eng.snapshots.taken == 1 and eng.snapshots.hits == 1
     admits = [s for s in eng.tracer.events if s.name == "serve.step.admit"
               and "matched_tokens" in s.attrs][-3:]
     assert [(s.attrs["matched_tokens"], s.attrs["trimmed_tokens"])
             for s in admits] == [(0, 0), (32, 32), (32, 0)]
     reg = eng.registry
-    assert reg.get("state_snapshots_taken_total").value == 2
+    assert reg.get("state_snapshots_taken_total").value == 1
     assert reg.get("state_snapshot_hits_total").value == 1
-    assert reg.get("state_snapshots_live").value == 2
+    assert reg.get("state_snapshots_live").value == 1
 
 
 def test_an_evicted_snapshot_trims_the_match(weights):
@@ -319,8 +319,9 @@ def test_an_evicted_snapshot_trims_the_match(weights):
     for seed in (1, 2):
         uid = eng.submit(doc + prompt(5, seed=seed), max_new_tokens=2)
         eng.run()
-    # one row: the snapshot at 16 was evicted by the one at 32, ... at 48
-    assert len(eng.snapshots) == 1 and eng.snapshots.evictions == 2
+    # one row, taken at the shared part's last chunk end (48) and at no
+    # chunk end before it
+    assert len(eng.snapshots) == 1 and eng.snapshots.evictions == 0
     assert tuple(doc[:48]) in eng.snapshots
     # a prompt that shares 40 tokens matches 5 blocks and has no snapshot
     # at or under 40: all of the match is given up, and the run is right
@@ -331,7 +332,8 @@ def test_an_evicted_snapshot_trims_the_match(weights):
              and "matched_tokens" in s.attrs][-1]
     assert (admit.attrs["matched_tokens"], admit.attrs["trimmed_tokens"]) == (
         40, 40)
-    # the snapshot at 48 serves a prompt that shares all 48
+    # that request snapshotted its own shared part's last chunk end (32),
+    # which took the one row; it serves a prompt that shares all 48
     before = eng.snapshots.hits
     uid = eng.submit(doc + prompt(7, seed=3), max_new_tokens=2)
     eng.run()
@@ -344,7 +346,7 @@ def test_flush_prefix_cache_leaves_no_snapshot(weights):
     for seed in (1, 2):
         eng.submit(doc + prompt(6, seed=seed), max_new_tokens=2)
         eng.run()
-    assert len(eng.snapshots) == 2
+    assert len(eng.snapshots) == 1
     eng.alloc.flush_prefix_cache()       # the allocator's own call
     assert len(eng.snapshots) == 0 and eng.alloc.blocks_in_use == 0
     uid = eng.submit(doc + prompt(6, seed=3), max_new_tokens=2)
@@ -358,18 +360,20 @@ def test_a_snapshot_dies_with_its_block(weights):
     for seed in (1, 2):
         eng.submit(doc + prompt(6, seed=seed), max_new_tokens=2)
         eng.run()
-    assert len(eng.snapshots) == 2
-    # a long request takes every block: the cached prefix is evicted
+    assert len(eng.snapshots) == 1 and tuple(doc[:32]) in eng.snapshots
+    # a request of 31 blocks: the three least recently used cached blocks
+    # go, the fourth stays, and so does its snapshot
     eng.submit(prompt(240, seed=4), max_new_tokens=4)
     eng.run()
-    # 31 of 32 blocks: the three least recently used cached blocks go, and
-    # the snapshot after the second of them with it; the fourth stays, and
-    # so does its snapshot
     assert eng.alloc.evictions == 3
     assert not eng.alloc.is_cached(tuple(doc[:16]))
-    assert tuple(doc[:16]) not in eng.snapshots
     assert eng.alloc.is_cached(tuple(doc[:32]))
     assert tuple(doc[:32]) in eng.snapshots and len(eng.snapshots) == 1
+    # one of all 32 blocks: the fourth goes too, and its snapshot with it
+    eng.submit(prompt(248, seed=5), max_new_tokens=4)
+    eng.run()
+    assert not eng.alloc.is_cached(tuple(doc[:32]))
+    assert len(eng.snapshots) == 0
 
 
 def test_preemption_and_reprefill_serve_the_same_tokens(weights):
