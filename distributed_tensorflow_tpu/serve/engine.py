@@ -27,6 +27,15 @@ is the serving analog of one train step:
    slots — and their KV blocks — are re-admissible on the NEXT step's
    admit phase.
 
+**One step in flight** (the paged engine without speculation;
+docs/serving.md "The engine's step"). Sampled tokens stay on the device:
+a decode step takes the previous one's tokens, and a prompt's first token,
+as a device vector (``_next``), and ``step()`` fetches the tokens it owes
+only after dispatching its own decode, so the host's admit, staging and
+delivery run while the chip computes. Tokens therefore arrive one step
+after the call that sampled them. The dense fallback and the speculative
+verify read their results on the host and stay synchronous.
+
 Everything device-side is shape-static; everything dynamic (queue
 state, per-slot write indices, request lifetimes) lives host-side in
 plain Python/numpy — the same host-drives/device-computes split as the
@@ -65,6 +74,12 @@ from .scheduler import (
 )
 
 
+def _set_token(tokens: jax.Array, slot, token: jax.Array) -> jax.Array:
+    """``tokens`` with ``token`` at ``slot``: a prompt's first token joins
+    the decode input on the device (jitted once; ``slot`` is traced)."""
+    return tokens.at[slot].set(token)
+
+
 @dataclasses.dataclass
 class StepStats:
     """What one engine step did."""
@@ -75,16 +90,18 @@ class StepStats:
     #: prefill chunks run this step (paged engine; dense prefill is
     #: atomic and reports 0)
     prefill_chunks: int = 0
-    #: (uid, token) pairs in delivery order — a uid can appear twice in
-    #: one step (its prefill token AND its first decode token)
+    #: (uid, token) pairs in delivery order. On the paged engine a token
+    #: arrives in the step after the call that sampled it (one step in
+    #: flight); on the dense engine a uid can appear twice in one step
+    #: (its prefill token AND its first decode token)
     tokens: list[tuple[int, int]] = dataclasses.field(default_factory=list)
     finished: list[int] = dataclasses.field(default_factory=list)
     #: host wall-clock split of this step: prefill phase (all admits,
     #: compile-warm), decode phase (one fused step), and the whole call —
     #: the durations of the step's ``serve.step.admit`` + ``.prefill``,
-    #: ``.decode`` and ``serve.step`` spans (obs/trace.py). Timings block
-    #: on sampled-token transfer, so they are real compute latencies, not
-    #: dispatch times.
+    #: ``.decode`` and ``serve.step`` spans (obs/trace.py). The decode
+    #: phase ends on a token fetch: with one step in flight, the fetch of
+    #: the tokens the previous calls sampled.
     wall_s: float = 0.0
     prefill_s: float = 0.0
     decode_s: float = 0.0
@@ -265,6 +282,14 @@ class ServeEngine:
         self._written = np.zeros(num_slots, np.int32)
         self._last = np.zeros(num_slots, np.int32)
         if paged:
+            #: every slot's newest token, on the device: what the last
+            #: decode step sampled, with each prompt's first token put in
+            #: at its slot. The next decode step's input; fetched a step late
+            self._next = jnp.zeros(num_slots, jnp.int32)
+            #: slot -> the request owed the token ``_next`` holds for it
+            #: (sampled, not yet fetched); ``_release_slot`` drops an entry
+            self._owed: dict[int, Request] = {}
+            self._set_token = jax.jit(_set_token)
             self._prefill_chunk_fn = decode_lib.jit_paged_prefill_chunk(
                 self.model)
             self._decode = decode_lib.jit_paged_decode_step(self.model)
@@ -586,14 +611,19 @@ class ServeEngine:
             self._snap_seen = now
             self._m_snap_live.set(float(len(snaps)))
 
-    def _observe_expert_load(self, sp) -> None:
-        """A cache that carries an expert layer's running counts
-        (``LatentCache.moe_counts``, read once the step's tokens are on
-        the host): what changed since the last fetch goes on the step's
-        span (``moe_assignments``, ``moe_expert_calls``) and into the
-        registry's counters. Steps between two fetches (prefill chunks
-        that end no prompt) are counted at the next."""
+    def _expert_counts(self, copy: bool) -> jax.Array | None:
+        """An expert layer's running counts (``LatentCache.moe_counts``),
+        or None for a cache without them; ``copy`` when the next call
+        donates the cache."""
         counts = getattr(self.cache, "moe_counts", None)
+        return jnp.copy(counts) if copy and counts is not None else counts
+
+    def _observe_expert_load(self, sp, counts: jax.Array | None) -> None:
+        """At a decode span's fetch: what the expert layer's counts gained
+        since the last fetch goes on that span (``moe_assignments``,
+        ``moe_expert_calls``) and into the registry's counters. A dispatched
+        step reads the counts as they were just before its own call, so
+        every program is counted once, at the next decode span."""
         if counts is None:
             return
         now = np.asarray(counts).astype(np.int64)
@@ -651,7 +681,9 @@ class ServeEngine:
     def _release_slot(self, slot: int) -> None:
         """Give every block in ``slot``'s table back to the allocator
         (shared blocks just drop one ref) and reset the slot to the
-        idle sentinel state."""
+        idle sentinel state. A token the device still holds for the slot
+        is never delivered: a call already dispatched wrote it into the
+        slot's own blocks, and the next owner's calls run after it."""
         for bid in self._blocks[slot]:
             self.alloc.decref(bid)
         self._blocks[slot] = []
@@ -659,6 +691,7 @@ class ServeEngine:
         self._written[slot] = 0
         self._pending.pop(slot, None)
         self._ptoks.pop(slot, None)
+        self._owed.pop(slot, None)
         if self.has_state:
             self._shared_upto.pop(slot, None)
 
@@ -694,6 +727,24 @@ class ServeEngine:
                     raise
                 self.sched.preempt(victim)
                 self._release_slot(victim)
+
+    def _blocks_needed(self, slot: int, start: int, end: int) -> int:
+        """Blocks ``_ensure_blocks(slot, start, end)`` may allocate: one
+        for each block of the span past the slot's table or shared."""
+        bs, blocks = self.block_size, self._blocks[slot]
+        return sum(b >= len(blocks) or self.alloc.refcount(blocks[b]) > 1
+                   for b in range(start // bs, (end - 1) // bs + 1))
+
+    def _fetch_if_preempting(self, need: int, stats: StepStats) -> None:
+        """Fetch and deliver the owed tokens before an allocation of
+        ``need`` blocks that the pool cannot supply without preempting: a
+        preempted request re-prefills from the tokens it holds, so none may
+        still be on the device (docs/serving.md "The engine's step")."""
+        if not self._owed or need <= self.alloc.blocks_free \
+                or need <= self.alloc.blocks_free + self.alloc.evictable():
+            return
+        owed, self._owed = self._owed, {}
+        self._fetch(owed, self._next, stats)
 
     def _ensure_blocks(self, slot: int, start: int, end: int) -> None:
         """Make positions ``[start, end)`` of ``slot`` writable: append
@@ -774,8 +825,10 @@ class ServeEngine:
 
     def _paged_prefill_step(self, slot: int, stats: StepStats) -> None:
         """Run ONE prefill chunk for ``slot``; on the final chunk,
-        sample the first token, publish the prompt's blocks for prefix
-        reuse, and hand the slot to the decode phase."""
+        sample the first token onto the device's token vector (owed to
+        the request until the decode phase fetches it), publish the
+        prompt's blocks for prefix reuse, and hand the slot to the decode
+        phase."""
         req = self.sched.slots[slot]
         with self.tracer.span("prefill", key=req.uid) as sp:
             self._prefill_chunk(slot, req, stats, sp)
@@ -790,6 +843,8 @@ class ServeEngine:
         end = min(start + self.prefill_chunk, T)
         n = end - start
         with tracer.span("stage"):
+            self._fetch_if_preempting(
+                self._blocks_needed(slot, start, end), stats)
             self._ensure_blocks(slot, start, end)
             buf = np.zeros(self.prefill_chunk, np.int32)
             buf[:n] = toks[start:end]
@@ -837,22 +892,21 @@ class ServeEngine:
             self.alloc.register_prefix(
                 req.prompt, self._blocks[slot][:n_prompt_blocks]
             )
-        with tracer.span("fetch"):
-            tok = int(
-                sampling.sample(
-                    logits, self._next_rng(),
-                    temperature=self.temperature, top_k=self.top_k,
-                )
+        with tracer.span("sample"):
+            # the first token stays on the device, the slot's decode input;
+            # the decode phase's fetch delivers it
+            tok = sampling.sample(
+                logits, self._next_rng(),
+                temperature=self.temperature, top_k=self.top_k,
             )
-            self._observe_expert_load(sp)
-        self._last[slot] = tok
+            self._next = self._set_token(self._next, slot, tok)
+        self._owed[slot] = req
         if self.reqtrace is not None and req.rid is not None:
             # prefill complete, first token of this residency sampled —
             # the request enters decode; this is also the replica-side
             # half of the sample→delivery clock anchor (the router's
             # matching decode_gap span opens strictly later)
             self.reqtrace.transition(req.rid, "decode_gap", uid=req.uid)
-        self._deliver(slot, tok, stats)
 
     def _observe_finish(self, req: Request, stats: StepStats | None) -> None:
         """The ONE terminal observation per finished request, whatever
@@ -945,13 +999,13 @@ class ServeEngine:
                 logits, self.cache = self._prefill(
                     self.params, self.cache, slot, toks, P
                 )
-            with tracer.span("fetch"):
-                tok = int(
-                    sampling.sample(
-                        logits, self._next_rng(),
-                        temperature=self.temperature, top_k=self.top_k,
-                    )
+            with tracer.span("sample"):
+                tok = sampling.sample(
+                    logits, self._next_rng(),
+                    temperature=self.temperature, top_k=self.top_k,
                 )
+            with tracer.span("fetch"):
+                tok = int(tok)
             self._written[slot] = P
             self._last[slot] = tok
             if self.reqtrace is not None and req.rid is not None:
@@ -997,9 +1051,14 @@ class ServeEngine:
         cap = self._oob  # positions a slot's table can address
         drafts: dict[int, list[int]] = {}
         with tracer.span("stage"):
+            if self._owed:
+                # the drafter and the accept rule read the prompts' first
+                # tokens on the host: the verify step is not run ahead
+                owed, self._owed = self._owed, {}
+                self._fetch(owed, self._next, stats)
             for slot in active:
                 if self.sched.slots[slot] is None:
-                    continue  # a peer's _ensure_blocks preempted it
+                    continue  # its first token finished it, or preempted
                 w = int(self._written[slot])
                 ks = max(min(self.spec_k, cap - 1 - w), 0)
                 drafts[slot] = self._draft(slot, ks) if ks else []
@@ -1026,7 +1085,7 @@ class ServeEngine:
             toks, pos = jnp.asarray(toks), jnp.asarray(pos)
         sp.attrs.update(
             slots=len(active), kv_tokens=kv_tokens, table_blocks=mbu,
-            kv_positions_walked=walked)
+            kv_positions_walked=walked, ahead=0)
         with tracer.span("dispatch"):
             logits, self.cache = self._verify(
                 self.params, self.cache, table, toks, pos,
@@ -1076,55 +1135,111 @@ class ServeEngine:
 
     def _do_decode(self, active: list[int], stats: StepStats) -> None:
         with self.tracer.span("decode") as sp:
-            if self.paged and self.spec_k > 0:
+            if not self.paged:
+                self._do_dense_decode(active, stats, sp)
+            elif self.spec_k > 0:
                 self._do_verify_decode(active, stats, sp)
             else:
-                self._do_plain_decode(active, stats, sp)
+                self._do_paged_decode(active, stats, sp)
         stats.decode_s = sp.duration
 
-    def _do_plain_decode(self, active: list[int], stats: StepStats,
+    def _fetch(self, owed: dict[int, Request], tokens: jax.Array,
+               stats: StepStats, sp=None, counts=None) -> None:
+        """Fetch ``tokens`` (the device vector holding what ``owed`` is
+        owed) and deliver each slot's token; at a decode span's fetch
+        (``sp``) the expert counts too."""
+        with self.tracer.span("fetch"):
+            host = np.asarray(tokens)
+            if sp is not None:
+                self._observe_expert_load(sp, counts)
+        with self.tracer.span("deliver"):
+            for slot, req in owed.items():
+                tok = int(host[slot])
+                self._last[slot] = tok
+                self._deliver(slot, tok, stats)
+
+    def _do_paged_decode(self, active: list[int], stats: StepStats,
+                         sp) -> None:
+        """One decode step ahead of the host: stage and dispatch this
+        step's call on the device's token vector (the previous call's
+        tokens and this step's first tokens, none of them fetched), then
+        fetch and deliver what the earlier calls owe. A slot whose owed
+        token spends its budget is not decoded again; one that ends by EOS,
+        cancel or deadline meanwhile has one call too many, whose token is
+        never delivered (``_release_slot``)."""
+        tracer = self.tracer
+        written, owed = self._written, self._owed
+        with tracer.span("stage"):
+            active = [s for s in active if s not in owed
+                      or self.sched.out_of_budget(
+                          owed[s], len(owed[s].generated) + 1) is None]
+            self._fetch_if_preempting(sum(
+                self._blocks_needed(s, written[s], written[s] + 1)
+                for s in active), stats)
+            # make each decoding slot's write position privately owned
+            # (fresh block at a boundary, COW off a shared block);
+            # allocation pressure may preempt the youngest residents, so
+            # re-filter afterwards
+            for slot in active:
+                if self.sched.slots[slot] is not None:
+                    w = int(self._written[slot])
+                    self._ensure_blocks(slot, w, w + 1)
+            active = [s for s in active if self.sched.slots[s] is not None]
+            if active:
+                stats.decoded_slots = len(active)
+                # non-decoding slots write through the past-the-table
+                # sentinel — their garbage token must not touch a live
+                # (possibly shared) block
+                lens = np.full(self.sched.num_slots, self._oob, np.int32)
+                lens[active] = self._written[active]
+                mbu = self._mb_bucket(
+                    max(len(self._blocks[s]) for s in active))
+                # each live slot attends its written positions plus the
+                # token it writes now, and the kernel fetches its own
+                # blocks up to the one that token lands in, whatever the
+                # table's width; from the host's mirror, no device read
+                bs = self.block_size
+                sp.attrs.update(
+                    slots=len(active),
+                    kv_tokens=int(self._written[active].sum()) + len(active),
+                    table_blocks=mbu,
+                    kv_positions_walked=int(
+                        ((self._written[active] + bs) // bs).sum()) * bs,
+                    ahead=int(bool(self._owed)))
+                table = jnp.asarray(self._table[:, :mbu])
+                lens = jnp.asarray(lens)
+        tokens, owed = self._next, self._owed
+        if active:
+            with tracer.span("dispatch"):
+                # the call donates the cache: its counts are read as they
+                # stand before it
+                counts = self._expert_counts(copy=True)
+                logits, self.cache = self._decode(
+                    self.params, self.cache, table, tokens, lens)
+                self._next = sampling.sample(
+                    logits, self._next_rng(),
+                    temperature=self.temperature, top_k=self.top_k)
+            self._written[active] += 1  # the call writes at the old index
+            self._owed = {s: self.sched.slots[s] for s in active}
+        elif owed:
+            counts = self._expert_counts(copy=False)
+            self._owed = {}
+        else:
+            return
+        self._fetch(owed, tokens, stats, sp, counts)
+
+    def _do_dense_decode(self, active: list[int], stats: StepStats,
                          sp) -> None:
         tracer = self.tracer
         with tracer.span("stage"):
-            if self.paged:
-                # make each decoding slot's write position privately owned
-                # (fresh block at a boundary, COW off a shared block);
-                # allocation pressure may preempt the youngest residents,
-                # so re-filter afterwards
-                for slot in active:
-                    if self.sched.slots[slot] is not None:
-                        w = int(self._written[slot])
-                        self._ensure_blocks(slot, w, w + 1)
-                active = [s for s in active
-                          if self.sched.slots[s] is not None]
-                if not active:
-                    return
             stats.decoded_slots = len(active)
             # each live slot attends its written positions plus the token
             # it writes now; from the host's mirror, no device read
             sp.attrs.update(
                 slots=len(active),
-                kv_tokens=int(self._written[active].sum()) + len(active))
-            if self.paged:
-                # non-decoding slots write through the past-the-table
-                # sentinel — their garbage token must not touch a live
-                # (possibly shared) block
-                lens = np.full(self.sched.num_slots, self._oob, np.int32)
-                for slot in active:
-                    lens[slot] = self._written[slot]
-                mbu = self._mb_bucket(
-                    max(len(self._blocks[s]) for s in active))
-                # the kernel fetches each live slot's own blocks, up to the
-                # one its new token lands in, whatever the table's width
-                bs = self.block_size
-                sp.attrs.update(
-                    table_blocks=mbu,
-                    kv_positions_walked=int(
-                        ((self._written[active] + bs) // bs).sum()) * bs)
-                args = (jnp.asarray(self._table[:, :mbu]),
-                        jnp.asarray(self._last), jnp.asarray(lens))
-            else:
-                args = (jnp.asarray(self._last), jnp.asarray(self._written))
+                kv_tokens=int(self._written[active].sum()) + len(active),
+                ahead=0)
+            args = (jnp.asarray(self._last), jnp.asarray(self._written))
         with tracer.span("dispatch"):
             logits, self.cache = self._decode(self.params, self.cache, *args)
         with tracer.span("fetch"):
@@ -1134,7 +1249,6 @@ class ServeEngine:
                     temperature=self.temperature, top_k=self.top_k,
                 )
             )
-            self._observe_expert_load(sp)
         with tracer.span("deliver"):
             for slot in active:
                 # the decode wrote k/v at the old index

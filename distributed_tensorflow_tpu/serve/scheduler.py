@@ -337,28 +337,38 @@ class Scheduler:
             r is not None for r in self.slots
         )
 
-    def append_token(self, slot: int, token: int) -> Request | None:
-        """Record a sampled token for the request in ``slot``; evict and
-        return the request if this token finishes it, else None.
+    def out_of_budget(self, req: Request, g: int) -> str | None:
+        """Why ``req`` is finished once it holds ``g`` generated tokens,
+        whatever they are (its ``max_new_tokens``, or the slot's cache
+        budget), else None. The engine asks it ahead of a token it has not
+        fetched yet: such a request is not decoded again.
 
         Cache accounting: after ``g`` generated tokens, continuing
         requires writing token ``g`` at cache position ``P + g - 1``, so
         the slot is out of budget once ``P + g > max_len`` — the request
         keeps that final token (it was sampled from in-budget state) and
         frees the slot before an out-of-bounds write can happen."""
+        if g >= req.max_new_tokens:
+            return FINISH_MAX_NEW
+        if len(req.prompt) + g > self.max_len:
+            return FINISH_MAX_LEN
+        return None
+
+    def append_token(self, slot: int, token: int) -> Request | None:
+        """Record a sampled token for the request in ``slot``; evict and
+        return the request if this token finishes it (its ``eos_id``, or
+        ``out_of_budget``), else None."""
         req = self.slots[slot]
         if req is None:
             raise ValueError(f"append_token on empty slot {slot}")
         req.generated.append(int(token))
-        g, P = len(req.generated), len(req.prompt)
+        g = len(req.generated)
         if g == 1:
             req.t_first_token = self.clock()
         if req.eos_id is not None and int(token) == req.eos_id:
             req.finish_reason = FINISH_EOS
-        elif g >= req.max_new_tokens:
-            req.finish_reason = FINISH_MAX_NEW
-        elif P + g > self.max_len:
-            req.finish_reason = FINISH_MAX_LEN
+        else:
+            req.finish_reason = self.out_of_budget(req, g)
         if req.done:
             self.slots[slot] = None
             self._finish(req, req.finish_reason)

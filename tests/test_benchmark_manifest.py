@@ -32,6 +32,10 @@ LONGDOCS = [f"{name}.longdocs" for name in (
     "state_snapshot_trim_pct", "serve_step_host_ms", "setup_trace_lower_s",
     "setup_backend_compile_s", "gated_delta_device_pct", "ttft_p50_ms",
     "tpot_p50_ms", "serve_queue_wait_p95_ms")]
+#: the serve loop's share of decode steps sent ahead, one a serving cell
+#: (not `.docs` / `.longdocs`: the family tests count the metrics so named)
+AHEAD = [f"decode_ahead_pct.{s}"
+         for s in ("complete", "docs_r80", "longdocs_r80")]
 
 
 def test_the_seven_span_metrics_and_what_was_appended_after_them():
@@ -39,7 +43,8 @@ def test_the_seven_span_metrics_and_what_was_appended_after_them():
     readers and read the hand-built ring as they did; after them come only
     entries that later PRs appended, in the order they came: the eighteen
     `.docs` metrics of the hybrid cell, then `pool_copy_device_pct.complete`,
-    then the twenty `.longdocs` metrics of the latent and expert cell."""
+    then the twenty `.longdocs` metrics of the latent and expert cell, then
+    the three `decode_ahead_pct` metrics of the serving cells."""
     import test_span_readers as t
 
     cells = {"train_input_wait_ms": [t.TRAIN],
@@ -56,7 +61,7 @@ def test_the_seven_span_metrics_and_what_was_appended_after_them():
     after = names[at + 7:]
     assert all(n.endswith(".docs") for n in after[:18])
     assert after[18] == "pool_copy_device_pct.complete"
-    assert after[19:] == LONGDOCS
+    assert after[19:] == LONGDOCS + AHEAD
     for m in seven:
         spec = t.spec_of(m["name"])
         assert {k: spec[k] for k in m} == m
@@ -87,7 +92,8 @@ def test_pool_copy_share_counts_each_copy_operation_once():
     assert entry == [{k: spec[k] for k in entry[0]}]
     # appended, not inserted: only the later cell's entries come after it
     at = MANIFEST["per_layer"].index(entry[0])
-    assert [m["name"] for m in MANIFEST["per_layer"][at + 1:]] == LONGDOCS
+    assert [m["name"] for m in MANIFEST["per_layer"][at + 1:]] \
+        == LONGDOCS + AHEAD
     assert set(spec) - set(entry[0]) == {"reader", "args"}
     names = spec["args"]["kernels"]
     assert len(names) == len(set(names)) and not any(

@@ -349,3 +349,34 @@ def test_the_lower_precision_controls_read_over_the_cells_limit(weights):
     gaps = {q: ref.served_gaps(CFG, w, toks, served, pad_to=128, n_out=32,
                                quant=q).mean() for q in (None, "int8", "fp8")}
     assert gaps[None] < limit < min(gaps["int8"], gaps["fp8"])
+
+
+def test_expert_counts_land_on_the_decode_spans_one_step_in_flight(weights):
+    """One decode step in flight (docs/serving.md "The engine's step"): a
+    decode phase reads the expert counts as they stood before its own call,
+    so the counts on the `serve.step.decode` spans add up to what the
+    counters gained, no prefill span carries any, and the residents are
+    served the reference's greedy tokens."""
+    from distributed_tensorflow_tpu import obs
+
+    tracer = obs.Tracer(annotate=False)
+    eng = engine(weights, tracer=tracer)
+    prompts = [prompt(n, seed=n) for n in (21, 9, 30, 14)]
+    uids = [eng.submit(p, max_new_tokens=8) for p in prompts]
+    done = eng.run()
+    for uid, p in zip(uids, prompts):
+        gen = done[uid].generated
+        want = np.asarray(ref.logits(weights, np.array(p + gen), CFG))
+        assert want[len(p) - 1: len(p) - 1 + 8].argmax(-1).tolist() == gen
+    spans = list(tracer.events)
+    decodes = [s for s in spans if s.name == "serve.step.decode"]
+    assert not any("moe_assignments" in s.attrs for s in spans
+                   if s.name == "serve.step.prefill")
+    assert all("moe_assignments" in s.attrs for s in decodes)
+    reg = eng.registry
+    for attr, counter in (("moe_assignments", "moe_local_assignments_total"),
+                          ("moe_expert_calls", "moe_expert_calls_total")):
+        assert sum(s.attrs[attr] for s in decodes) \
+            == reg.get(counter).value > 0
+    calls = [s for s in decodes if "ahead" in s.attrs]
+    assert sum(s.attrs["ahead"] for s in calls) == len(calls)

@@ -430,3 +430,44 @@ def test_period_and_stacks_of_the_config():
         max_len=32)
     uid = eng.submit([1, 2, 3, 4, 5], max_new_tokens=3)
     assert len(eng.run()[uid].generated) == 3
+
+
+def reference_greedy(weights, toks, gen):
+    """The reference's own greedy tokens over ``toks`` + ``gen``: the argmax
+    of its full forward at every position from the prompt's last on."""
+    want = np.asarray(ref.logits(weights, np.array(toks + gen), CFG))
+    return want[len(toks) - 1: len(toks) - 1 + len(gen)].argmax(-1).tolist()
+
+
+def test_an_eos_and_a_cancel_in_flight_leave_clean_state_behind(weights):
+    """One decode step in flight (docs/serving.md "The engine's step"): a
+    request that meets its EOS, and one cancelled, with a token on the
+    device have already been sent in the next call, which advances their
+    slots' recurrent state once more. The requests admitted into those
+    slots are served the reference's greedy tokens, as they are alone."""
+    prompts = [prompt(n, seed=n) for n in (19, 33, 12, 27)]
+    solo = engine(weights)
+    alone = []
+    for p in prompts:  # one at a time: each alone in the engine
+        uid = solo.submit(p, max_new_tokens=14)
+        alone.append(solo.run()[uid].generated)
+    k = next(i for i in range(3, 14) if alone[0][i] not in alone[0][:i])
+    eng = engine(weights, num_slots=2)
+    a = eng.submit(prompts[0], max_new_tokens=14, eos_id=alone[0][k])
+    b = eng.submit(prompts[1], max_new_tokens=14)
+    rest = [eng.submit(p, max_new_tokens=14) for p in prompts[2:]]
+    for _ in range(5):
+        eng.step()
+    assert eng._owed and eng.cancel(b)
+    done = eng.run()
+    assert done[a].finish_reason == "eos"
+    assert done[a].generated == alone[0][:k + 1]
+    assert done[b].finish_reason == "cancelled"
+    got_b = done[b].generated
+    assert 0 < len(got_b) < 14 and got_b == alone[1][:len(got_b)]
+    for uid, p, want in zip(rest, prompts[2:], alone[2:]):
+        assert done[uid].generated == want
+        assert reference_greedy(weights, p, want) == want
+    eng.drain()
+    assert eng._owed == {}
+    assert eng.alloc.blocks_free == eng.alloc.num_blocks

@@ -1231,7 +1231,9 @@ def test_decode_walk_follows_each_slots_own_length(decoder):
 
 def test_step_span_counts_add_up_to_the_engines_own(traced_run):
     """What the spans count is what the registry counts: a prefill span a
-    chunk, a token a prefill fetch and a token a live slot of a decode."""
+    chunk, a token a prompt's first-token sample and a token a live slot of
+    a decode (no request of the run ends by EOS, cancel or deadline with a
+    token still on the device, so every token sampled is delivered)."""
     run = traced_run
     reg = run.eng.registry
     pre = _named(run, "serve.step.prefill")
@@ -1239,7 +1241,7 @@ def test_step_span_counts_add_up_to_the_engines_own(traced_run):
         == sum(st.prefill_chunks for st in run.steps)
     decode_tokens = sum(s.attrs.get("slots", 0)
                         for s in _named(run, "serve.step.decode"))
-    first_tokens = len(_named(run, "serve.step.prefill.fetch"))
+    first_tokens = len(_named(run, "serve.step.prefill.sample"))
     assert decode_tokens + first_tokens \
         == reg.get("serve_tokens_total").value
     # one serve.step span per step() call, whose StepStats timing split
@@ -1303,11 +1305,14 @@ def test_span_children_lie_inside_their_parents(traced_run):
         assert s.name.startswith(p.name + ".") \
             and "." not in s.name[len(p.name) + 1:]
     names = {s.name for s in run.spans}
+    # the run's preemption is preceded by a fetch of the tokens still on
+    # the device, inside the decode's staging
     assert names == {
         "serve.step", "serve.step.admit", "serve.step.prefill",
         "serve.step.prefill.stage", "serve.step.prefill.dispatch",
-        "serve.step.prefill.fetch", "serve.step.decode",
-        "serve.step.decode.stage", "serve.step.decode.dispatch",
+        "serve.step.prefill.sample", "serve.step.decode",
+        "serve.step.decode.stage", "serve.step.decode.stage.fetch",
+        "serve.step.decode.stage.deliver", "serve.step.decode.dispatch",
         "serve.step.decode.fetch", "serve.step.decode.deliver",
         "serve.request.queue", "serve.request.prefill",
         "serve.request.decode"}
@@ -1322,26 +1327,32 @@ def test_span_children_lie_inside_their_parents(traced_run):
 def test_dense_and_speculative_steps_leave_the_same_span_tree(decoder, kw):
     from distributed_tensorflow_tpu import obs
 
-    cfg, _, params = decoder
+    cfg, model, params = decoder
     tracer = obs.Tracer(annotate=False)
     eng = serve.ServeEngine(cfg, params, num_slots=2, tracer=tracer, **kw)
     uids = [eng.submit([7, 8, 9, 7, 8, 9, 7, 8], max_new_tokens=6)
             for _ in range(2)]
+    # neither runs ahead: each request's first token and a decoded one
+    # arrive in the step that sampled them
+    first = eng.step()
+    assert [u for u, _ in first.tokens].count(uids[0]) >= 2
     done = eng.run()
     names = {s.name for s in tracer.events}
-    assert {"serve.step.prefill.dispatch", "serve.step.prefill.fetch",
+    assert {"serve.step.prefill.dispatch", "serve.step.prefill.sample",
             "serve.step.decode.stage", "serve.step.decode.dispatch",
             "serve.step.decode.fetch", "serve.step.decode.deliver",
             "serve.request.decode"} <= names
     decodes = [s for s in tracer.events if s.name == "serve.step.decode"]
     assert all(s.attrs["slots"] >= 1 and s.attrs["kv_tokens"] > 0
-               for s in decodes)
+               and s.attrs["ahead"] == 0 for s in decodes)
     # every decode step that made a call delivered what it fetched
     assert len([s for s in tracer.events
                 if s.name == "serve.step.decode.deliver"]) == len(decodes)
     assert {s.key for s in tracer.events
             if s.name == "serve.request.decode"} == set(uids)
     assert all(len(done[u].generated) == 6 for u in uids)
+    _assert_uncached_greedy(model, params, [7, 8, 9, 7, 8, 9, 7, 8],
+                            done[uids[0]].generated)
 
 
 def test_request_spans_only_on_the_tracers_own_clock(decoder):
@@ -1364,3 +1375,236 @@ def test_request_spans_only_on_the_tracers_own_clock(decoder):
         assert len([n for n in names
                     if n.startswith("serve.request.")]) == want
         assert "serve.step.decode.fetch" in names
+
+
+# ---------------------------------------------------------------------------
+# One decode step in flight (docs/serving.md "The engine's step"): tokens
+# stay on the device and are fetched one step late
+# ---------------------------------------------------------------------------
+
+
+def _assert_uncached_greedy(model, params, prompt, gen):
+    """``gen`` is the uncached forward's greedy continuation of ``prompt``:
+    one full forward over prompt + all but the last token, whose argmax at
+    every position from the prompt's last on is the next token."""
+    seq = jnp.asarray([list(prompt) + list(gen[:-1])], jnp.int32)
+    full = model.apply({"params": params}, seq)[0, len(prompt) - 1:]
+    assert list(gen) == [int(t) for t in jnp.argmax(full, -1)]
+
+
+def _solo(cfg, params, prompt, n, **kw):
+    eng = _paged_engine(cfg, params, num_slots=1, **kw)
+    return list(eng.stream(prompt, max_new_tokens=n))
+
+
+def test_a_token_arrives_one_step_after_the_call_that_sampled_it(decoder):
+    """A lone request of four tokens: each step delivers what the calls of
+    the step before sampled, the first token in the step of the prompt's
+    last chunk; the call that samples the fourth is the last, and a step
+    with nothing left to call only fetches."""
+    from distributed_tensorflow_tpu import obs
+
+    cfg, model, params = decoder
+    tracer = obs.Tracer(annotate=False)
+    eng = _paged_engine(cfg, params, num_slots=1, tracer=tracer)
+    uid = eng.submit([5, 17, 3], max_new_tokens=4)
+    req = eng._find(uid)
+    seen = []
+    while eng.sched.has_work:
+        st = eng.step()
+        seen.append((st.decoded_slots, [t for _, t in st.tokens],
+                     list(eng._owed)))
+    g = req.generated
+    assert seen == [(1, g[:1], [0]), (1, g[1:2], [0]), (1, g[2:3], [0]),
+                    (0, g[3:], [])]
+    assert req.finish_reason == sched_lib.FINISH_MAX_NEW
+    _assert_uncached_greedy(model, params, [5, 17, 3], g)
+    decodes = [s for s in tracer.events if s.name == "serve.step.decode"]
+    assert [s.attrs.get("ahead") for s in decodes] == [1, 1, 1, None]
+    assert "slots" not in decodes[-1].attrs  # it made no call
+    eng.drain()
+    assert eng.alloc.blocks_free == eng.cache.num_blocks
+
+
+def test_in_flight_streams_are_the_uncached_forwards_greedy_ones(decoder):
+    """Five requests through three slots, one of 64 tokens, two behind a
+    shared prefix whose tail block is copied on the first divergent write:
+    every stream is the uncached forward's greedy one, the calls go ahead
+    of the host, and the pool comes home whole."""
+    from distributed_tensorflow_tpu import obs
+
+    cfg, model, params = decoder
+    shared = list(range(1, 21))  # 2 full blocks + a 4-token tail at bs 8
+    prompts = [[5, 17, 3, 99, 42, 7, 11], shared + [60], shared + [61],
+               [(5 * i + 2) % cfg.vocab_size for i in range(30)], [88, 12]]
+    lengths = [64, 20, 20, 24, 30]
+    tracer = obs.Tracer(annotate=False)
+    eng = _paged_engine(cfg, params, num_slots=3, tracer=tracer)
+    # prefilled in three chunks, registered, and decoding into its tail
+    warm = eng.submit(shared, max_new_tokens=30)
+    for _ in range(3):
+        eng.step()
+    uids = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, lengths)]
+    done = eng.run()
+    _assert_uncached_greedy(model, params, shared, done[warm].generated)
+    for uid, p, n in zip(uids, prompts, lengths):
+        assert len(done[uid].generated) == n
+        _assert_uncached_greedy(model, params, p, done[uid].generated)
+    assert eng.alloc.cow_copies >= 1
+    assert int(eng.registry.get("prefix_reuse_hits_total").value) > 0
+    calls = [s for s in tracer.events
+             if s.name == "serve.step.decode" and "ahead" in s.attrs]
+    assert sum(s.attrs["ahead"] for s in calls) == len(calls) > 60
+    assert not any(s.name.endswith(".stage.fetch") for s in tracer.events)
+    eng.drain()
+    assert eng._owed == {}
+    assert eng.alloc.blocks_free == eng.cache.num_blocks
+
+
+def test_an_eos_in_flight_leaves_the_slot_and_its_blocks_clean(decoder):
+    """A request that meets its EOS has already been sent in the next call:
+    that call's token is never delivered, its write lands in the request's
+    own block, and the slot and the blocks serve the next requests (one
+    behind the finished request's prompt, through the prefix cache) as
+    they would be served alone."""
+    cfg, model, params = decoder
+    a_prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3]  # a block and a 2-token tail
+    a_solo = _solo(cfg, params, a_prompt, 12)
+    # the first token the stream had not emitted before: A's EOS
+    k = next(i for i in range(1, 12) if a_solo[i] not in a_solo[:i])
+    assert k >= 3
+    eos = a_solo[k]
+    b_prompt = [(7 * i + 3) % cfg.vocab_size for i in range(13)]
+    b_solo = _solo(cfg, params, b_prompt, 16)
+    c_prompt = a_prompt + [77, 78]
+    c_solo = _solo(cfg, params, c_prompt, 10)
+    eng = _paged_engine(cfg, params, num_slots=1, max_len=32, num_blocks=4)
+    a = eng.submit(a_prompt, max_new_tokens=12, eos_id=eos)
+    b = eng.submit(b_prompt, max_new_tokens=16)
+    c = eng.submit(c_prompt, max_new_tokens=10)
+    calls = 0
+    real = eng._decode
+
+    def counted(*args):
+        nonlocal calls
+        calls += 1
+        return real(*args)
+
+    eng._decode = counted
+    done = eng.run()
+    assert done[a].finish_reason == sched_lib.FINISH_EOS
+    assert done[a].generated == a_solo[:k + 1]
+    assert done[b].generated == b_solo and done[c].generated == c_solo
+    _assert_uncached_greedy(model, params, c_prompt, done[c].generated)
+    # A's k calls and the one too many, B's fifteen, C's nine
+    assert calls == k + 1 + 15 + 9
+    eng.drain()
+    assert eng.alloc.blocks_free == eng.cache.num_blocks
+
+
+def test_cancel_and_deadline_while_a_token_is_in_flight(decoder):
+    """Both end a request whose next token is on the device: it keeps what
+    was delivered, gets nothing after, its slot and blocks go home, and the
+    request admitted behind it is served as it would be alone."""
+    from distributed_tensorflow_tpu.resilience import FaultClock
+
+    cfg, model, params = decoder
+    prompts = [[5, 17, 3], [88, 12, 61, 40, 2], [7, 7, 1, 9]]
+    solo = [_solo(cfg, params, p, n) for p, n in zip(prompts, (30, 20, 12))]
+    clk = FaultClock()
+    eng = _paged_engine(cfg, params, num_slots=2, clock=clk)
+    a = eng.submit(prompts[0], max_new_tokens=30, deadline_s=5.0)
+    b = eng.submit(prompts[1], max_new_tokens=20)
+    c = eng.submit(prompts[2], max_new_tokens=12)
+    for _ in range(3):
+        eng.step()
+    slot_b = eng.sched.slots.index(eng._find(b))
+    assert set(eng._owed) == {0, 1}  # both residents have a token out
+    b_seen = list(eng._find(b).generated)
+    assert eng.cancel(b) is True
+    assert slot_b not in eng._owed and eng._blocks[slot_b] == []
+    clk.advance(10.0)
+    a_seen = list(eng._find(a).generated)
+    stats = eng.step()  # A expires before its token is fetched
+    assert a in stats.finished
+    done = eng.run()
+    assert done[b].finish_reason == sched_lib.FINISH_CANCELLED
+    assert done[b].generated == b_seen == solo[1][:len(b_seen)]
+    assert done[a].finish_reason == sched_lib.FINISH_TIMEOUT
+    assert done[a].generated == a_seen == solo[0][:len(a_seen)]
+    assert len(a_seen) >= 3 and len(b_seen) >= 3
+    assert done[c].generated == solo[2]
+    _assert_uncached_greedy(model, params, prompts[2], done[c].generated)
+    eng.drain()
+    assert eng._owed == {}
+    assert eng.alloc.blocks_free == eng.cache.num_blocks
+    _assert_telemetry_invariant(eng, 3)
+
+
+def test_drain_with_steps_in_flight_delivers_every_token(decoder):
+    """drain() while the residents have tokens on the device: every one is
+    fetched and delivered, the queued request is cancelled, no request
+    finishes with a token left on the device, and the pool is whole."""
+    cfg, model, params = decoder
+    prompts = [[5, 17, 3], [9, 4, 77, 2, 60], [1, 2, 3]]
+    eng = _paged_engine(cfg, params, num_slots=2)
+    uids = [eng.submit(p, max_new_tokens=9) for p in prompts]
+    eng.step(), eng.step()
+    assert len(eng._owed) == 2
+    done = eng.drain()
+    for uid, p in zip(uids[:2], prompts):
+        assert done[uid].finish_reason == sched_lib.FINISH_MAX_NEW
+        assert done[uid].generated == _solo(cfg, params, p, 9)
+        _assert_uncached_greedy(model, params, p, done[uid].generated)
+    assert done[uids[2]].finish_reason == sched_lib.FINISH_CANCELLED
+    assert eng._owed == {} and not eng.sched.has_work
+    assert eng.alloc.blocks_free == eng.cache.num_blocks
+    _assert_telemetry_invariant(eng, 3)
+
+
+def test_stream_beside_other_residents_yields_the_solo_tokens(decoder):
+    cfg, model, params = decoder
+    other = [(3 * i + 1) % cfg.vocab_size for i in range(20)]
+    eng = _paged_engine(cfg, params, num_slots=2)
+    o = eng.submit(other, max_new_tokens=40)
+    got = list(eng.stream([5, 17, 3], max_new_tokens=25))
+    assert got == _solo(cfg, params, [5, 17, 3], 25)
+    _assert_uncached_greedy(model, params, [5, 17, 3], got)
+    done = eng.run()
+    assert done[o].generated == _solo(cfg, params, other, 40)
+
+
+def test_a_preemption_fetches_the_owed_tokens_first(decoder):
+    """A pool too small for the residents: before a staging that would
+    preempt, the tokens on the device are fetched and delivered, so the
+    victim re-prefills from all it was given; the decode step sent after
+    that fetch is not ahead, and the tokens are those of an ample pool."""
+    from distributed_tensorflow_tpu import obs
+
+    cfg, model, params = decoder
+    prompts = [[10 + i] * 10 for i in range(3)]
+
+    def drive(num_blocks):
+        tracer = obs.Tracer(annotate=False)
+        eng = _paged_engine(cfg, params, num_slots=2, max_len=32,
+                            num_blocks=num_blocks, prefix_reuse=False,
+                            tracer=tracer)
+        uids = [eng.submit(p, max_new_tokens=20) for p in prompts]
+        done = eng.run()
+        eng.drain()
+        assert eng.alloc.blocks_free == eng.cache.num_blocks
+        return ([done[u].generated for u in uids],
+                sum(done[u].preemptions for u in uids), list(tracer.events))
+
+    ample, _, _ = drive(8)
+    tight, preempted, spans = drive(5)
+    assert preempted > 0 and tight == ample
+    for p, gen in zip(prompts, tight):
+        _assert_uncached_greedy(model, params, p, gen)
+    by_id = {s.id: s for s in spans}
+    early = [s for s in spans if s.name.endswith(".stage.fetch")]
+    assert early
+    for s in early:
+        owner = by_id[by_id[s.parent].parent]
+        if owner.name == "serve.step.decode" and "ahead" in owner.attrs:
+            assert owner.attrs["ahead"] == 0

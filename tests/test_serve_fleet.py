@@ -472,8 +472,12 @@ def test_drained_replica_leaves_its_span_ring_beside_the_flight_recorder(
     # 19 prompt tokens in chunks of 8: three calls, each with its size
     assert [s["attrs"]["q_tokens"] for s in by_name["serve.step.prefill"]] \
         == [8, 8, 3]
+    # four tokens: the last chunk samples the first, three decode calls the
+    # rest; the last decode phase makes no call and only fetches
+    calls = [s for s in by_name["serve.step.decode"] if "slots" in s["attrs"]]
+    assert len(calls) == 3 == len(by_name["serve.step.decode"]) - 1
     assert all(s["attrs"]["kv_tokens"] <= s["attrs"]["kv_positions_walked"]
-               for s in by_name["serve.step.decode"])
+               for s in calls)
     uid = by_name["serve.step.prefill"][0]["key"]
     phases = [by_name[f"serve.request.{p}"][0]
               for p in ("queue", "prefill", "decode")]
