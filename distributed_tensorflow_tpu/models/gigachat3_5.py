@@ -372,6 +372,12 @@ class GigaChat35:
         """Random parameters (``init_params``)."""
         return init_params(self.cfg, key)
 
+    def serving_params(self, params) -> dict:
+        """The tree the serving programs take (serve/decode.py "the serving
+        protocol"): the params as they are (at the published widths every
+        table is a multiple of the lanes already)."""
+        return params
+
     def init_cache(self, num_slots, num_blocks, block_size, num_snapshots,
                    dtype=jnp.bfloat16):
         """The engine's cache for this model: ``serve.kv_cache.LatentCache``."""

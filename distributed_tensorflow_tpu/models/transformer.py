@@ -32,7 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..data.text import IGNORE_INDEX  # single sentinel shared with the data layer
 from ..ops.attention import attention_reference, blockwise_attention
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import LANES, flash_attention
 from ..ops.moe import collect_aux_loss
 from ..parallel import mesh as mesh_lib
 from ..parallel import sharding
@@ -646,7 +646,16 @@ class Transformer(nn.Module):
                 )
         tok = nn.Embed(cfg.vocab_size, cfg.d_model, name="tok_embed",
                        embedding_init=nn.initializers.normal(0.02))
-        x = tok(input_ids)
+        # a tree that serving_params prepared carries both tables padded to
+        # the lanes in its "serving" collection: token rows, and on the
+        # cached path position rows, are gathered from those, which the
+        # device holds row-major (the head below reads tok_embed as it is)
+        laid_out = self.has_variable("serving", "tok_rows")
+        if laid_out:
+            x = _rows(self.get_variable("serving", "tok_rows"), input_ids,
+                      cfg.d_model)
+        else:
+            x = tok(input_ids)
         pos = self.param(
             "pos_embed", nn.initializers.normal(0.02),
             (cfg.max_len, cfg.d_model), jnp.float32,
@@ -654,10 +663,12 @@ class Transformer(nn.Module):
         if kv_cache is not None:
             # per-sequence absolute positions (clip guards the padded tail
             # of a bucketed prefill, whose rows are discarded anyway)
-            x = (x + pos[jnp.clip(decode_pos, 0, cfg.max_len - 1)]
-                 ).astype(dtype)
+            at = jnp.clip(decode_pos, 0, cfg.max_len - 1)
+            pos = (_rows(self.get_variable("serving", "pos_rows"), at,
+                         cfg.d_model) if laid_out else pos[at])
         else:
-            x = (x + pos[None, :S]).astype(dtype)
+            pos = pos[None, :S]
+        x = (x + pos).astype(dtype)
         if not cfg.pre_ln:
             x = nn.LayerNorm(dtype=jnp.float32, name="embed_ln")(x).astype(dtype)
         x = nn.Dropout(cfg.dropout, deterministic=not train)(x)
@@ -744,6 +755,24 @@ class Transformer(nn.Module):
     has_state = False
 
     @nn.nowrap
+    def serving_params(self, params):
+        """The tree the engine's programs take (serve/decode.py "the
+        serving protocol"). The device lays out a table whose width is not
+        a multiple of the 128 lanes with its long axis minor (GPT-2-XL's
+        ``[50304, 1600]`` embedding and ``[1024, 1600]`` positions), and a
+        row gather then copies the whole table into row-major order first,
+        in every program run. So such tables are padded once, here, on the
+        device, to the next multiple of the lanes, and ride beside
+        ``params`` in the "serving" collection (``__call__`` reads their
+        first ``d_model`` columns; the zeros are never read). At a
+        lane-multiple width ``params`` come back as they are."""
+        if self.cfg.d_model % LANES == 0:
+            return params
+        return {"params": params, "serving": _lane_padded({
+            "tok_rows": params["tok_embed"]["embedding"],
+            "pos_rows": params["pos_embed"]})}
+
+    @nn.nowrap
     def prefill_chunk(self, params, cache, table_row, tokens, start, length,
                       slot=None):
         """One prefill chunk of one request through the block pool
@@ -754,7 +783,7 @@ class Transformer(nn.Module):
         idx = jnp.arange(tokens.shape[0], dtype=jnp.int32)
         pos = jnp.where(idx < length, start + idx, sentinel)
         logits, cache = self.apply(
-            {"params": params}, tokens[None], kv_cache=cache,
+            serving_variables(params), tokens[None], kv_cache=cache,
             decode_pos=pos[None], block_table=table_row[None],
         )
         return logits[0, length - 1], cache
@@ -764,10 +793,39 @@ class Transformer(nn.Module):
         """One token for every slot over the block pool
         (serve.decode.paged_decode_step has the contract)."""
         logits, cache = self.apply(
-            {"params": params}, tokens[:, None], kv_cache=cache,
+            serving_variables(params), tokens[:, None], kv_cache=cache,
             decode_pos=lengths[:, None], block_table=block_tables,
         )
         return logits[:, 0], cache
+
+
+def _rows(table, ids, width):
+    """Rows ``ids`` of a lane-padded table, its first ``width`` columns, as
+    ONE gather of ``width`` columns (``jnp.take``'s semantics: an index out
+    of range reads NaN). A gather of whole rows and then a slice reads the
+    same numbers, but on the TPU the slice moves the compiler's layout
+    choices through the whole program: every layer's q/k/v projection then
+    transposed its weights, 72 copies a decode step at 24 layers."""
+    dims = jax.lax.GatherDimensionNumbers(
+        offset_dims=(ids.ndim,), collapsed_slice_dims=(0,),
+        start_index_map=(0,))
+    return jax.lax.gather(
+        table, ids[..., None], dims, slice_sizes=(1, width),
+        mode=jax.lax.GatherScatterMode.FILL_OR_DROP, fill_value=jnp.nan)
+
+
+@jax.jit
+def _lane_padded(tables):
+    """Each ``[rows, width]`` table zero-padded to a multiple of the lanes."""
+    return jax.tree.map(
+        lambda t: jnp.pad(t, ((0, 0), (0, -t.shape[-1] % LANES))), tables)
+
+
+def serving_variables(params):
+    """What ``Transformer.apply`` takes for a serving tree: one that
+    ``Transformer.serving_params`` prepared is already the variables
+    (``params`` beside the ``serving`` tables); a param tree is wrapped."""
+    return params if "serving" in params else {"params": params}
 
 
 # ---------------------------------------------------------------------------

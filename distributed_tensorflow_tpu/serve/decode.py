@@ -20,10 +20,14 @@ by name: they ask ``model.prefill_chunk(params, cache, table_row, tokens,
 start, length, slot)`` and ``model.decode_step(params, cache,
 block_tables, tokens, lengths)``, and the engine asks ``model.has_state``
 (recurrent state beside the K/V pool: snapshots for prefix reuse, no
-speculation). ``models.transformer.Transformer``,
-``models.olmo_hybrid.OlmoHybrid`` and ``models.gigachat3_5.GigaChat35``
-answer it; a model with state also builds its own cache
-(``model.init_cache``).
+speculation) and, once for each weights it is given,
+``model.serving_params(params)``: the tree every program then takes, with
+whatever the model lays out once at load rather than in every run
+(``Transformer``: its embedding tables padded to the lanes when the width
+is not a multiple of them; the others hand their params back as they are).
+``models.transformer.Transformer``, ``models.olmo_hybrid.OlmoHybrid`` and
+``models.gigachat3_5.GigaChat35`` answer it; a model with state also
+builds its own cache (``model.init_cache``).
 
 Numerics: the cache path runs the same f32 masked softmax(QKᵀ)V as the
 dense reference (ops.attention.cached_attention docstring), so cached
@@ -40,7 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..models.transformer import Transformer
+from ..models.transformer import Transformer, serving_variables
 from .kv_cache import HybridCache, KVCache, PagedKVCache
 
 
@@ -60,7 +64,7 @@ def prefill(
     slot_cache = dataclasses.replace(cache, k=row(cache.k), v=row(cache.v))
     pos = jnp.arange(P, dtype=jnp.int32)[None]
     logits, slot_cache = model.apply(
-        {"params": params}, tokens[None], kv_cache=slot_cache,
+        serving_variables(params), tokens[None], kv_cache=slot_cache,
         decode_pos=pos,
     )
     put = lambda buf, upd: lax.dynamic_update_slice_in_dim(
@@ -84,7 +88,7 @@ def decode_step(
     write index (= tokens already in its cache). Returns (next-token
     logits [num_slots, vocab] f32, updated cache)."""
     logits, cache = model.apply(
-        {"params": params}, tokens[:, None], kv_cache=cache,
+        serving_variables(params), tokens[:, None], kv_cache=cache,
         decode_pos=lengths[:, None],
     )
     return logits[:, 0], cache
@@ -195,7 +199,7 @@ def paged_verify_step(
     device copy). One compiled program per K, shared by every prompt
     and every acceptance pattern."""
     logits, cache = model.apply(
-        {"params": params}, tokens, kv_cache=cache,
+        serving_variables(params), tokens, kv_cache=cache,
         decode_pos=positions, block_table=block_tables,
     )
     return logits, cache

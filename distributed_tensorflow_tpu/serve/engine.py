@@ -183,6 +183,12 @@ class ServeEngine:
         self.spec_k = spec_k
         self.spec_ngram = spec_ngram
         self.cfg = cfg
+        #: span ring for the step's host phases and each request's
+        #: queue/prefill/decode phases (obs/trace.py; the names and counts
+        #: are a contract, docs/observability.md "Span tracing"); present
+        #: in every run, defaults to the process ring
+        self.tracer = (tracer if tracer is not None
+                       else trace_lib.default_tracer())
         self.params = params
         M = cfg.max_len if max_len is None else max_len
         if M > cfg.max_len:
@@ -260,12 +266,6 @@ class ServeEngine:
         # engine's drain event, so the postmortem timeline interleaves
         self.flightrec = (flightrec if flightrec is not None
                           else flightrec_lib.default_recorder())
-        #: span ring for the step's host phases and each request's
-        #: queue/prefill/decode phases (obs/trace.py; the names and counts
-        #: are a contract, docs/observability.md "Span tracing"); present
-        #: in every run, defaults to the process ring
-        self.tracer = (tracer if tracer is not None
-                       else trace_lib.default_tracer())
         #: per-request span ledger (obs/reqtrace.py) shared with the
         #: scheduler; None = untraced. Only requests that entered with a
         #: router trace id (rid) emit spans — direct submissions don't.
@@ -403,6 +403,32 @@ class ServeEngine:
         self._spec_accepted = 0
         if paged:
             self._sync_block_metrics()
+
+    @property
+    def params(self):
+        """The weights as they were given."""
+        return self._params
+
+    @params.setter
+    def params(self, params) -> None:
+        """Set the weights, and lay out once what the model's programs read
+        of them (``model.serving_params``, serve/decode.py "the serving
+        protocol"): every program takes that tree, so weights set again in
+        the same shapes compile nothing. ``None`` drops both."""
+        self._params = params
+        self._prepared = None
+        if params is None:
+            return
+        with self.tracer.span("serve.prepare") as sp:
+            # waited for: the layout's temporaries are gone before the
+            # pool is made beside the weights
+            self._prepared = jax.block_until_ready(
+                self.model.serving_params(params))
+            given = {id(x) for x in jax.tree.leaves(params)}
+            added = [x for x in jax.tree.leaves(self._prepared)
+                     if id(x) not in given]
+            sp.attrs["tables_padded"] = len(added)
+            sp.attrs["padded_bytes"] = sum(x.nbytes for x in added)
 
     @classmethod
     def with_random_params(
@@ -856,7 +882,7 @@ class ServeEngine:
                         context=end, table_blocks=mbu)
         with tracer.span("dispatch"):
             logits, self.cache = self._prefill_chunk_fn(
-                self.params, self.cache, table, buf, start, n,
+                self._prepared, self.cache, table, buf, start, n,
                 *((slot,) if self.has_state else ()),
             )
             shared = self._shared_upto.get(slot, 0) if self.has_state else 0
@@ -997,7 +1023,7 @@ class ServeEngine:
                 toks[:P] = req.prompt
             with tracer.span("dispatch"):
                 logits, self.cache = self._prefill(
-                    self.params, self.cache, slot, toks, P
+                    self._prepared, self.cache, slot, toks, P
                 )
             with tracer.span("sample"):
                 tok = sampling.sample(
@@ -1088,7 +1114,7 @@ class ServeEngine:
             kv_positions_walked=walked, ahead=0)
         with tracer.span("dispatch"):
             logits, self.cache = self._verify(
-                self.params, self.cache, table, toks, pos,
+                self._prepared, self.cache, table, toks, pos,
             )
         with tracer.span("fetch"):
             logits = np.asarray(logits)
@@ -1215,7 +1241,7 @@ class ServeEngine:
                 # stand before it
                 counts = self._expert_counts(copy=True)
                 logits, self.cache = self._decode(
-                    self.params, self.cache, table, tokens, lens)
+                    self._prepared, self.cache, table, tokens, lens)
                 self._next = sampling.sample(
                     logits, self._next_rng(),
                     temperature=self.temperature, top_k=self.top_k)
@@ -1241,7 +1267,8 @@ class ServeEngine:
                 ahead=0)
             args = (jnp.asarray(self._last), jnp.asarray(self._written))
         with tracer.span("dispatch"):
-            logits, self.cache = self._decode(self.params, self.cache, *args)
+            logits, self.cache = self._decode(self._prepared, self.cache,
+                                              *args)
         with tracer.span("fetch"):
             toks = np.asarray(
                 sampling.sample(

@@ -151,45 +151,62 @@ def test_gpt2xl_paged_kernel_call_compiles_for_v5e(
     assert mem.temp_size_in_bytes < 4e6
 
 
-@pytest.mark.parametrize("width", [4, 48])
-@pytest.mark.parametrize("program", ["prefill", "decode"])
-def test_gpt2xl_step_programs_compile_and_keep_the_pool_in_place(
-        one_chip, kernels_for_the_chip, program, width):
-    """The transformer's two serving programs at GPT-2-XL's widths (d 1600,
-    25 heads of 64, d_ff 6400; 16 slots, the pool of 24 layers and 640 + 1
-    blocks of 16 with heads stored at 128, chunk 32; four layers of the
-    model keep the compile short) on a narrow and the widest table: both kernels are there, the
-    donated pool is the program's result where it lies, and the
-    temporaries (the weights cast to bf16, most of them) are far under one
-    pool: a program that slices a layer out of the pool or stacks it again
-    cannot hide there."""
+def _gpt2_step_program(one_chip, program, width, d_model, num_heads):
+    """One of the transformer's two serving programs at GPT-2-XL's serving
+    shapes (vocabulary 50304, d_ff 4·d; 16 slots, the pool of 24 layers and
+    640 + 1 blocks of 16 with heads of 64 stored at 128, chunk 32; four
+    layers of the model keep the compile short), compiled on the tree the
+    engine hands it: ``model.serving_params`` of the params, run under
+    ``jax.eval_shape``. Returns (params, that tree, cache, compiled)."""
     from distributed_tensorflow_tpu.models import transformer as tr
     from distributed_tensorflow_tpu.serve import decode, kv_cache
 
     cfg = tr.TransformerConfig(
-        vocab_size=50304, d_model=1600, num_heads=25, num_layers=4,
-        d_ff=6400, max_len=1024, dropout=0.0, causal=True, pre_ln=True,
-        dtype="bfloat16", paged_attention_impl="pallas")
+        vocab_size=50304, d_model=d_model, num_heads=num_heads, num_layers=4,
+        d_ff=4 * d_model, max_len=1024, dropout=0.0, causal=True,
+        pre_ln=True, dtype="bfloat16", paged_attention_impl="pallas")
     model = tr.Transformer(cfg)
     on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                              sharding=one_chip)
     params = jax.tree.map(on_chip, jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0),
                            jnp.zeros((1, 8), jnp.int32)))["params"])
+    served = jax.tree.map(on_chip,
+                          jax.eval_shape(model.serving_params, params))
     slots = 16
     # the engine's cache as `init_paged_cache` makes it on the chip (here
     # the backend is the CPU): heads stored at the 128 lanes
-    pool = jax.ShapeDtypeStruct((24, 641, 25, 16, 128), jnp.bfloat16,
+    pool = jax.ShapeDtypeStruct((24, 641, num_heads, 16, 128), jnp.bfloat16,
                                 sharding=one_chip)
     cache = kv_cache.PagedKVCache(k=pool, v=pool)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
     if program == "prefill":
         done = decode.jit_paged_prefill_chunk(model).lower(
-            params, cache, i32(width), i32(32), i32(), i32())
+            served, cache, i32(width), i32(32), i32(), i32())
     else:
         done = decode.jit_paged_decode_step(model).lower(
-            params, cache, i32(slots, width), i32(slots), i32(slots))
-    done = done.compile()
+            served, cache, i32(slots, width), i32(slots), i32(slots))
+    return params, served, cache, done.compile()
+
+
+@pytest.mark.parametrize("width", [4, 48])
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_gpt2xl_step_programs_compile_and_keep_the_pool_in_place(
+        one_chip, kernels_for_the_chip, program, width):
+    """The transformer's two serving programs at GPT-2-XL's widths (d 1600,
+    25 heads of 64, d_ff 6400) on a narrow and the widest table: both
+    kernels are there, the donated pool is the program's result where it
+    lies, and the temporaries (the weights cast to bf16, most of them) are
+    far under one pool: a program that slices a layer out of the pool or
+    stacks it again cannot hide there. The token and position rows come
+    from the tables the preparation padded to 1664 lanes, so neither table
+    is copied into row-major order in the program (at 1600 the device
+    holds both with the long axis minor, and a gather copied the 322 MB
+    embedding every run), and no layer's weights are copied for it."""
+    _, served, cache, done = _gpt2_step_program(
+        one_chip, program, width, 1600, 25)
+    assert {k: v.shape for k, v in served["serving"].items()} == {
+        "tok_rows": (50304, 1664), "pos_rows": (1024, 1664)}
     text = done.as_text()
     assert "paged_attention_fwd" in text and "paged_kv_write" in text
     pool_bytes = cache.k.size * cache.k.dtype.itemsize
@@ -200,6 +217,24 @@ def test_gpt2xl_step_programs_compile_and_keep_the_pool_in_place(
     assert not re.search(r"= bf16\[24,641,25,16,128\]\S* (copy|fusion)\(",
                          text)
     assert "bf16[641,25,16,128]" not in text
+    # neither embedding table is copied whole, and the lookup leaves the
+    # layers' layouts as they were: a gather of whole padded rows and then
+    # a slice made every layer transpose its q/k/v weights (3 copies a
+    # layer, against the first layer's 3)
+    assert not re.search(r"= f32\[(50304|1024),1600\]\S* copy\(", text)
+    assert len(re.findall(r"= bf16\[1600,1600\]\S* copy\(", text)) <= 3
+
+
+def test_lane_multiple_tables_are_served_as_they_are(
+        one_chip, kernels_for_the_chip):
+    """At a width that is a multiple of the lanes (GPT-2-medium's 1024, 16
+    heads of 64) the preparation adds nothing and pads nothing: the decode
+    program takes the params themselves, and copies neither table."""
+    params, served, _, done = _gpt2_step_program(
+        one_chip, "decode", 48, 1024, 16)
+    assert served == params
+    assert not re.search(r"= f32\[(50304|1024),1024\]\S* copy\(",
+                         done.as_text())
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode"])

@@ -152,6 +152,12 @@ def test_a_restored_snapshot_and_shared_latent_blocks_serve_the_same_logits(
     assert eng.snapshots.taken == 1 and eng.snapshots.hits == 1
 
 
+def test_the_serving_tree_is_the_params_as_they_are(weights):
+    """Nothing to lay out at load (serve/decode.py "the serving protocol"):
+    the engine's programs take the weights themselves."""
+    assert gc.GigaChat35(MODEL_CFG).serving_params(weights) is weights
+
+
 def test_the_cache_the_model_builds_and_its_block_copy():
     cache = gc.GigaChat35(MODEL_CFG).init_cache(3, 20, 8, 2)
     assert isinstance(cache, kv_cache.LatentCache)

@@ -212,6 +212,12 @@ def test_paged_kernels_with_a_layer_axis_match_the_plain_forms():
 # ---------------------------------------------------------------------------
 
 
+def test_the_serving_tree_is_the_params_as_they_are(weights):
+    """Nothing to lay out at load (serve/decode.py "the serving protocol"):
+    the engine's programs take the weights themselves."""
+    assert oh.OlmoHybrid(MODEL_CFG).serving_params(weights) is weights
+
+
 def test_full_forward_matches_the_reference(weights):
     toks = np.array(prompt(70))
     got = jax.jit(oh.OlmoHybrid(MODEL_CFG).forward)(weights, jnp.asarray(toks))

@@ -1306,11 +1306,13 @@ def test_span_children_lie_inside_their_parents(traced_run):
             and "." not in s.name[len(p.name) + 1:]
     names = {s.name for s in run.spans}
     # the run's preemption is preceded by a fetch of the tokens still on
-    # the device, inside the decode's staging
+    # the device, inside the decode's staging; the weights were laid out
+    # once, when the engine was built
     assert names == {
-        "serve.step", "serve.step.admit", "serve.step.prefill",
-        "serve.step.prefill.stage", "serve.step.prefill.dispatch",
-        "serve.step.prefill.sample", "serve.step.decode",
+        "serve.prepare", "serve.step", "serve.step.admit",
+        "serve.step.prefill", "serve.step.prefill.stage",
+        "serve.step.prefill.dispatch", "serve.step.prefill.sample",
+        "serve.step.decode",
         "serve.step.decode.stage", "serve.step.decode.stage.fetch",
         "serve.step.decode.stage.deliver", "serve.step.decode.dispatch",
         "serve.step.decode.fetch", "serve.step.decode.deliver",
@@ -1608,3 +1610,108 @@ def test_a_preemption_fetches_the_owed_tokens_first(decoder):
         owner = by_id[by_id[s.parent].parent]
         if owner.name == "serve.step.decode" and "ahead" in owner.attrs:
             assert owner.attrs["ahead"] == 0
+
+
+# ---------------------------------------------------------------------------
+# Tables laid out at load (docs/serving.md "The engine's step"): the tiny
+# decoder's width, 32, is not a multiple of the 128 lanes, so its token and
+# position tables are padded once and the programs gather from those
+# ---------------------------------------------------------------------------
+
+
+def test_the_prepared_tree_gives_the_raw_trees_logits(decoder):
+    """A paged prefill chunk and a decode step, and the uncached forward,
+    read the same logits from the prepared tree as from the params."""
+    cfg, model, params = decoder
+    served = model.serving_params(params)
+    assert {k: v.shape for k, v in served["serving"].items()} == {
+        "tok_rows": (cfg.vocab_size, 128), "pos_rows": (cfg.max_len, 128)}
+    assert served["params"] is params
+    table = jnp.arange(4, dtype=jnp.int32)
+    toks = jnp.asarray([5, 17, 3, 99, 42, 7, 0, 0], jnp.int32)
+    tables = jnp.stack([table, table + 4])
+
+    def run(tree):
+        cache = serve.init_paged_cache(cfg, 8, 8, dtype="float32")
+        chunk, cache = model.prefill_chunk(tree, cache, table, toks,
+                                           jnp.int32(0), jnp.int32(6))
+        step, _ = model.decode_step(tree, cache, tables,
+                                    jnp.asarray([11, 12], jnp.int32),
+                                    jnp.asarray([6, 0], jnp.int32))
+        full = model.apply(tfm.serving_variables(tree), toks[None, :6])
+        return chunk, step, full
+
+    for got, want in zip(run(served), run(params)):
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=0)
+
+
+def test_prepared_and_raw_trees_serve_the_same_greedy_tokens(
+        decoder, monkeypatch):
+    """32 greedy tokens through the engine on the prepared tree and on an
+    engine whose model hands its params back as they are."""
+    cfg, model, params = decoder
+    prompt = [5, 17, 3, 99, 42, 7, 11]
+    served = _solo(cfg, params, prompt, 32)
+    monkeypatch.setattr(tfm.Transformer, "serving_params",
+                        lambda self, p: p)
+    raw = _solo(cfg, params, prompt, 32)
+    assert len(served) == 32 and served == raw
+    _assert_uncached_greedy(model, params, prompt, served)
+
+
+def test_weights_set_again_are_laid_out_again_and_compile_nothing(decoder):
+    """`eng.params = None; eng.params = tree` (how the calibration swaps a
+    seed's weights in) lays the new tree out under one `serve.prepare`
+    span, and the programs take it without compiling again."""
+    from distributed_tensorflow_tpu import obs
+
+    cfg, model, params = decoder
+    tracer = obs.Tracer(annotate=False)
+    eng = _paged_engine(cfg, params, num_slots=1, tracer=tracer)
+    prompt = [5, 17, 3]
+    first = list(eng.stream(prompt, max_new_tokens=8))
+    programs = (eng._prefill_chunk_fn, eng._decode, tfm._lane_padded)
+    compiled = [f._cache_size() for f in programs]
+    before = eng._prepared["serving"]["tok_rows"]
+    eng.params = None
+    assert eng.params is None and eng._prepared is None
+    tree = jax.tree.map(lambda a: a + 0, params)
+    eng.params = tree
+    assert eng.params is tree and eng._prepared["params"] is tree
+    assert eng._prepared["serving"]["tok_rows"] is not before
+    assert list(eng.stream(prompt, max_new_tokens=8)) == first
+    assert [f._cache_size() for f in programs] == compiled
+    prepares = [s for s in tracer.events if s.name == "serve.prepare"]
+    padded = (cfg.vocab_size + cfg.max_len) * 128 * 4
+    assert [s.attrs for s in prepares] == [
+        {"tables_padded": 2, "padded_bytes": padded}] * 2
+    assert all(s.parent is None for s in prepares)
+
+
+def test_the_init_tree_is_the_params_alone(decoder):
+    """Training, eval and checkpoints see the tree they always saw: no
+    `serving` collection, the tables at the model's width."""
+    cfg, model, params = decoder
+    variables = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    assert set(variables) == {"params"}
+    assert jax.tree.map(jnp.shape, variables["params"]) == jax.tree.map(
+        jnp.shape, params)
+    assert params["tok_embed"]["embedding"].shape == (cfg.vocab_size,
+                                                      cfg.d_model)
+    assert params["pos_embed"].shape == (cfg.max_len, cfg.d_model)
+
+
+def test_a_lane_multiple_width_is_served_as_it_is():
+    """At d 128 nothing is padded: the engine's programs take the params
+    themselves, and its `serve.prepare` span says so."""
+    from distributed_tensorflow_tpu import obs
+
+    cfg = tiny_decoder(d_model=128)
+    model = tfm.Transformer(cfg)
+    params, _ = tfm.make_init_fn(model, 8)(jax.random.PRNGKey(1))
+    assert model.serving_params(params) is params
+    tracer = obs.Tracer(annotate=False)
+    eng = _paged_engine(cfg, params, num_slots=1, tracer=tracer)
+    assert eng._prepared is params
+    [prep] = [s for s in tracer.events if s.name == "serve.prepare"]
+    assert prep.attrs == {"tables_padded": 0, "padded_bytes": 0}
