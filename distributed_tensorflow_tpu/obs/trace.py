@@ -23,15 +23,28 @@ places the whole ring (spans the profiler's reader drops as too short
 included) on the device trace's time axis. Outside a session the
 annotation is a no-op of well under a microsecond.
 
-A tracer that is given a ``registry`` mirrors every duration into
-``trace_span_seconds{span=<path>}``; the default tracer has none, so the
-hot path pays no labelled-histogram lookup.
-
 Compiles: ``default_tracer()`` installs one ``jax.monitoring`` listener
 pair that records jax's own trace / lower / backend-compile durations as
 spans ``compile.trace`` (outermost traces only), ``compile.lower`` and
 ``compile.backend``, children of whatever span was open when they ended
 — "which step recompiled" is the parent's name.
+
+Collections: ``default_tracer()`` also installs one ``gc.callbacks`` hook
+that records the interpreter's own pauses as spans ``runtime.gc`` (attrs
+``generation``, ``collected``, ``uncollectable``), children of the span
+open on the collecting thread: "which step a collection held up" is the
+parent's name. While a profiler session runs EVERY collection is an
+annotation ``runtime.gc.<id>``, so the trace's idle gaps can name it; only
+those a step can feel become ring spans: every generation-2 collection,
+and any other of ``GC_SPAN_MIN_S`` or more. The ring's bound decides that
+rule. A whole traced run of the GPT-2 serving cell fills 56k of its
+65,536 slots, and three benchmark metrics read set-up's ``compile.*``
+spans from the oldest end; generation-0 collections run every 700 net
+allocations, ~1,400 of them in that cell's set-up and ~150 a window, and
+would evict those. Under the rule a window leaves 2-11 spans. A full
+collection walks the whole heap, the ring included, and is what a
+long-lived server pays for it; 1 ms is an eighth of a GPT-2 token
+interval, a pause one can feel.
 
 Thread model: the open-span stack is a ``threading.local`` — each thread
 nests independently; a shared Tracer collects all of them.
@@ -39,6 +52,7 @@ nests independently; a shared Tracer collects all of them.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -49,11 +63,8 @@ from collections import deque
 import jax.monitoring
 import jax.profiler
 
-from .registry import Registry
-
 __all__ = ["Span", "Tracer", "default_tracer"]
 
-SPAN_HISTOGRAM = "trace_span_seconds"
 #: dump header schema tag — bump when the record layout changes
 SCHEMA = "dtf-spans-1"
 
@@ -63,8 +74,11 @@ SCHEMA = "dtf-spans-1"
 #: some 25 minutes of a server stepping three times a second, under two
 #: minutes of one stepping fifty times a second. A trainer's five spans a
 #: step make it the last ~13,000 steps. At ~0.3 kB a span the full ring
-#: is ~20 MB of host memory. (A whole run of either benchmark cell is
-#: under 6,000 spans, so its readers see ``dropped == 0``.)
+#: is ~20 MB of host memory. The fullest benchmark run, a traced run of
+#: the GPT-2-XL serving cell at 14.5 requests/s, ends holding 56,171
+#: spans (52,865 of them its 51 s window's steps, 954 compiles, 104
+#: collections; one v5e host), so its readers see ``dropped == 0``: a
+#: span type that adds thousands a window needs a larger bound.
 RING_SPANS = 1 << 16
 
 #: jax.monitoring duration events -> span names (jax 0.9.0,
@@ -75,6 +89,12 @@ COMPILE_EVENTS = {
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
     "/jax/core/compile/backend_compile_duration": "compile.backend",
 }
+
+#: the span of one collection of the interpreter's garbage collector, and
+#: the shortest one of generation 0 or 1 that is recorded (the module's
+#: docstring says why)
+GC_SPAN = "runtime.gc"
+GC_SPAN_MIN_S = 1e-3
 
 #: process-wide span ids (``next`` on a count is atomic under the GIL)
 _ids = itertools.count(1)
@@ -89,10 +109,10 @@ class Span:
     __slots__ = ("id", "parent", "name", "start", "end", "key", "attrs",
                  "_tracer", "_ann")
 
-    def __init__(self, tracer, name, key, attrs):
+    def __init__(self, tracer, name, key, attrs, span_id=None):
         self._tracer = tracer
         self._ann = None
-        self.id = next(_ids)
+        self.id = next(_ids) if span_id is None else span_id
         self.parent = None
         self.name = name
         self.start = self.end = None
@@ -139,8 +159,7 @@ class Span:
 
 
 class Tracer:
-    """Collects completed spans (bounded ring, always on) and optionally
-    mirrors durations into a metrics registry.
+    """Collects completed spans (bounded ring, always on).
 
     >>> tr = Tracer()
     >>> with tr.span("step", step=7):
@@ -152,13 +171,11 @@ class Tracer:
 
     def __init__(
         self,
-        registry: Registry | None = None,
         *,
         max_events: int = RING_SPANS,
         annotate: bool = True,
         clock=time.perf_counter,
     ):
-        self.registry = registry
         self.annotate = annotate
         self.clock = clock
         #: completed spans in closing order, oldest dropped past the bound
@@ -178,12 +195,6 @@ class Tracer:
         if len(self.events) == self.events.maxlen:
             self.dropped += 1
         self.events.append(sp)
-        if self.registry is not None:
-            self.registry.histogram(
-                SPAN_HISTOGRAM,
-                "wall-clock duration of host trace spans",
-                span=sp.name,
-            ).observe(sp.end - sp.start)
 
     def span(self, name: str, key=None, **attrs) -> Span:
         """A span to open with ``with``; it nests under the span open on
@@ -197,15 +208,18 @@ class Tracer:
         return stack[-1] if stack else None
 
     def record(self, name: str, start: float, end: float, *, key=None,
-               parent: int | None = None, **attrs) -> Span:
+               parent: int | None = None, span_id: int | None = None,
+               **attrs) -> Span:
         """Add a region whose two ends are already known: no clock read,
         no annotation. The stamps MUST be readings of this tracer's
         ``clock`` — the ring has one time axis, and ``dump``'s single
         ``clock_origin`` places all of it — so a caller whose stamps come
         from another clock records nothing (``ServeEngine`` skips its
         ``serve.request.*`` spans then). ``name`` is taken as given, not
-        nested."""
-        sp = Span(self, name, key, attrs)
+        nested. ``span_id`` is an id taken from the process-wide count
+        before the region began (an annotation named with it); by default
+        a new one."""
+        sp = Span(self, name, key, attrs, span_id)
         sp.parent, sp.start, sp.end = parent, start, end
         self._append(sp)
         return sp
@@ -237,13 +251,15 @@ _default_lock = threading.Lock()
 
 def default_tracer() -> Tracer:
     """The process ring (Trainer and ServeEngine record here unless given
-    another). Made on first use, together with the compile listener."""
+    another). Made on first use, together with the compile listener and
+    the collection hook: each is installed once a process."""
     global _default
     if _default is None:
         with _default_lock:
             if _default is None:
                 tracer = Tracer()
                 _listen_for_compiles(tracer)
+                gc.callbacks.append(_collection_hook(tracer))
                 _default = tracer
     return _default
 
@@ -278,3 +294,39 @@ def _listen_for_compiles(tracer: Tracer) -> None:
     jax.monitoring.register_scalar_listener(on_start)
     jax.monitoring.register_event_duration_secs_listener(on_duration)
 
+
+
+def _collection_hook(tracer: Tracer):
+    """The ``gc.callbacks`` entry that spans the interpreter's collections
+    on ``tracer``. CPython collects nothing while a callback runs, and an
+    exception in one is printed, not raised: nothing here assumes that a
+    span is open, and a full ring only counts one more ``dropped``."""
+    tls = tracer._tls
+
+    def on_collection(phase: str, info: dict) -> None:
+        if phase == "start":
+            span_id, ann = next(_ids), None
+            if tracer.annotate:
+                ann = jax.profiler.TraceAnnotation(f"{GC_SPAN}.{span_id}")
+                ann.__enter__()
+            # read inside the annotation, as a span's ends are
+            tls.gc = (span_id, ann, tracer.clock())
+            return
+        opened = getattr(tls, "gc", None)
+        if opened is None:  # installed while this collection ran
+            return
+        tls.gc = None
+        span_id, ann, start = opened
+        end = tracer.clock()
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        generation = info["generation"]
+        if generation < 2 and end - start < GC_SPAN_MIN_S:
+            return
+        top = tracer.current()
+        tracer.record(GC_SPAN, start, end, span_id=span_id,
+                      parent=None if top is None else top.id,
+                      generation=generation, collected=info["collected"],
+                      uncollectable=info["uncollectable"])
+
+    return on_collection

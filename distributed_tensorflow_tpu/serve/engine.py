@@ -96,15 +96,6 @@ class StepStats:
     #: (its prefill token AND its first decode token)
     tokens: list[tuple[int, int]] = dataclasses.field(default_factory=list)
     finished: list[int] = dataclasses.field(default_factory=list)
-    #: host wall-clock split of this step: prefill phase (all admits,
-    #: compile-warm), decode phase (one fused step), and the whole call —
-    #: the durations of the step's ``serve.step.admit`` + ``.prefill``,
-    #: ``.decode`` and ``serve.step`` spans (obs/trace.py). The decode
-    #: phase ends on a token fetch: with one step in flight, the fetch of
-    #: the tokens the previous calls sampled.
-    wall_s: float = 0.0
-    prefill_s: float = 0.0
-    decode_s: float = 0.0
 
 
 class ServeEngine:
@@ -501,7 +492,9 @@ class ServeEngine:
                         self._m_queue_wait.observe(req.t_admit - req.t_submit)
                     if self.paged:
                         self._begin_paged(slot, req, admit)
-            stats.prefill_s = admit.duration
+            # the host's split of the step, for the three histograms: the
+            # durations of its admit + prefill spans and of its decode span
+            prefill_s, decode_s = admit.duration, 0.0
             # occupancy counts every slot WORKING this step — decoding,
             # mid-chunked-prefill, or just admitted (even if its first
             # token finishes it before the step ends); measured here,
@@ -515,21 +508,20 @@ class ServeEngine:
                 # bound
                 for slot in sorted(self._pending):
                     if slot in self._pending:  # preemption may drop peers
-                        self._paged_prefill_step(slot, stats)
+                        prefill_s += self._paged_prefill_step(slot, stats)
             else:
                 for slot, req in placed:
-                    self._do_prefill(slot, req, stats)
+                    prefill_s += self._do_prefill(slot, req, stats)
             active = self.sched.active_slots()
             if self.paged:
                 active = [s for s in active if s not in self._pending]
             if active:
-                self._do_decode(active, stats)
-        stats.wall_s = sp.duration
-        self._m_step.observe(stats.wall_s)
+                decode_s = self._do_decode(active, stats)
+        self._m_step.observe(sp.duration)
         if stats.admitted or stats.prefill_chunks:
-            self._m_prefill.observe(stats.prefill_s)
+            self._m_prefill.observe(prefill_s)
         if stats.decoded_slots:  # not a step whose decode preempted away
-            self._m_decode.observe(stats.decode_s)
+            self._m_decode.observe(decode_s)
         if stats.occupancy:  # publish prefill-only steps too
             self._m_occupancy.set(stats.occupancy)
         if self.paged:
@@ -849,16 +841,16 @@ class ServeEngine:
         self._pending[slot] = matched
         self._ptoks[slot] = toks
 
-    def _paged_prefill_step(self, slot: int, stats: StepStats) -> None:
+    def _paged_prefill_step(self, slot: int, stats: StepStats) -> float:
         """Run ONE prefill chunk for ``slot``; on the final chunk,
         sample the first token onto the device's token vector (owed to
         the request until the decode phase fetches it), publish the
         prompt's blocks for prefix reuse, and hand the slot to the decode
-        phase."""
+        phase. Returns the chunk's span's duration."""
         req = self.sched.slots[slot]
         with self.tracer.span("prefill", key=req.uid) as sp:
             self._prefill_chunk(slot, req, stats, sp)
-        stats.prefill_s += sp.duration
+        return sp.duration
 
     def _prefill_chunk(self, slot: int, req: Request, stats: StepStats,
                        sp) -> None:
@@ -1012,7 +1004,7 @@ class ServeEngine:
             self._written[slot] = 0  # idle slots park their write index at 0
             self._observe_finish(finished, stats)
 
-    def _do_prefill(self, slot: int, req: Request, stats: StepStats) -> None:
+    def _do_prefill(self, slot: int, req: Request, stats: StepStats) -> float:
         P = len(req.prompt)
         tracer = self.tracer
         with tracer.span("prefill", key=req.uid, q_tokens=P,
@@ -1037,7 +1029,7 @@ class ServeEngine:
             if self.reqtrace is not None and req.rid is not None:
                 self.reqtrace.transition(req.rid, "decode_gap", uid=req.uid)
             self._deliver(slot, tok, stats)
-        stats.prefill_s += sp.duration
+        return sp.duration
 
     def _draft(self, slot: int, k: int) -> list[int]:
         """N-gram prompt-lookup drafter (zero extra weights): find the
@@ -1159,7 +1151,7 @@ class ServeEngine:
             self._m_spec_rate.set(
                 self._spec_accepted / self._spec_proposed)
 
-    def _do_decode(self, active: list[int], stats: StepStats) -> None:
+    def _do_decode(self, active: list[int], stats: StepStats) -> float:
         with self.tracer.span("decode") as sp:
             if not self.paged:
                 self._do_dense_decode(active, stats, sp)
@@ -1167,7 +1159,7 @@ class ServeEngine:
                 self._do_verify_decode(active, stats, sp)
             else:
                 self._do_paged_decode(active, stats, sp)
-        stats.decode_s = sp.duration
+        return sp.duration
 
     def _fetch(self, owed: dict[int, Request], tokens: jax.Array,
                stats: StepStats, sp=None, counts=None) -> None:

@@ -1,7 +1,7 @@
-"""What two tests under `tests/benchmark` held of `BENCHMARK.json`, bar a
+"""What three tests under `tests/benchmark` held of `BENCHMARK.json`, bar a
 premise about positions that a later appended entry ends (only a
-`benchmark` PR may edit a file there; `pytest.ini` deselects the two by
-name and says why), and this PR's one new per-layer metric on a hand-built
+`benchmark` PR may edit a file there; `pytest.ini` deselects the three by
+name and says why), and `pool_copy_device_pct.complete` on a hand-built
 trace. CPU only; no time of any device."""
 
 import importlib
@@ -36,6 +36,10 @@ LONGDOCS = [f"{name}.longdocs" for name in (
 #: (not `.docs` / `.longdocs`: the family tests count the metrics so named)
 AHEAD = [f"decode_ahead_pct.{s}"
          for s in ("complete", "docs_r80", "longdocs_r80")]
+#: the interpreter's collections as felt by the serve and train loops
+GC = ["serve_gc_pause_ms_per_s.complete", "serve_gc_pause_max_ms.complete",
+      "serve_gc_pause_ms_per_s.docs_r80",
+      "serve_gc_pause_ms_per_s.longdocs_r80", "train_gc_pause_ms"]
 
 
 def test_the_seven_span_metrics_and_what_was_appended_after_them():
@@ -44,7 +48,8 @@ def test_the_seven_span_metrics_and_what_was_appended_after_them():
     entries that later PRs appended, in the order they came: the eighteen
     `.docs` metrics of the hybrid cell, then `pool_copy_device_pct.complete`,
     then the twenty `.longdocs` metrics of the latent and expert cell, then
-    the three `decode_ahead_pct` metrics of the serving cells."""
+    the three `decode_ahead_pct` metrics of the serving cells, then the five
+    collection-pause metrics of the serve and train loops."""
     import test_span_readers as t
 
     cells = {"train_input_wait_ms": [t.TRAIN],
@@ -61,7 +66,7 @@ def test_the_seven_span_metrics_and_what_was_appended_after_them():
     after = names[at + 7:]
     assert all(n.endswith(".docs") for n in after[:18])
     assert after[18] == "pool_copy_device_pct.complete"
-    assert after[19:] == LONGDOCS + AHEAD
+    assert after[19:] == LONGDOCS + AHEAD + GC
     for m in seven:
         spec = t.spec_of(m["name"])
         assert {k: spec[k] for k in m} == m
@@ -93,7 +98,7 @@ def test_pool_copy_share_counts_each_copy_operation_once():
     # appended, not inserted: only the later cell's entries come after it
     at = MANIFEST["per_layer"].index(entry[0])
     assert [m["name"] for m in MANIFEST["per_layer"][at + 1:]] \
-        == LONGDOCS + AHEAD
+        == LONGDOCS + AHEAD + GC
     assert set(spec) - set(entry[0]) == {"reader", "args"}
     names = spec["args"]["kernels"]
     assert len(names) == len(set(names)) and not any(
@@ -118,3 +123,37 @@ def test_pool_copy_share_counts_each_copy_operation_once():
     ctx["trace"] = trace_reduce.Trace(
         {"/device:TPU:0": {"ops": ops[4:], "modules": mods}}, [])
     assert harness.per_layer_metrics(only, cell, ctx) == {}
+
+
+def test_the_decode_ahead_entries_and_what_was_appended_after_them():
+    """What `tests/benchmark/test_decode_ahead.py::
+    test_each_serving_cell_reads_its_own_through_the_harness` held, bar its
+    premise that the three are the manifest's last entries: the three files
+    and their entries agree key for key, stand together straight after the
+    `.longdocs` metrics with only the five collection-pause metrics after
+    them, and the harness gives each serving cell its own metric and the
+    training cell none."""
+    import test_decode_ahead as a
+    import test_span_readers as t
+
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    at = names.index(AHEAD[0])
+    entries = MANIFEST["per_layer"][at:at + 3]
+    assert names[at - len(LONGDOCS):at] == LONGDOCS
+    assert [m["name"] for m in entries] == AHEAD
+    assert names[at + 3:] == GC
+    for m, suffix in zip(entries, a.CELLS):
+        spec = a.spec_of(suffix)
+        assert {k: spec[k] for k in m} == m
+        assert set(spec) - set(m) == {"reader", "args"}
+        assert m["workloads"] == [a.CELLS[suffix]] and m["unit"] == "%"
+        assert m["layer"] == "serve loop"
+        assert m["moves"] == ("tpot_p95_ms" if suffix == "complete"
+                              else "serve_tokens_per_s")
+    only = {"per_layer": entries}
+    ctx = t.hand_ctx(ring=a.ahead_ring())
+    for suffix, cell in a.CELLS.items():
+        got = harness.per_layer_metrics(only, cell, ctx)
+        assert got == {f"decode_ahead_pct.{suffix}": {
+            "value": pytest.approx(75.0), "unit": "%"}}
+    assert harness.per_layer_metrics(only, "gpt2m_train_1k", ctx) == {}

@@ -3,6 +3,7 @@ accuracy against numpy quantiles, Prometheus render invariants, span
 tracing, JSONL export, and the serve-engine telemetry acceptance gate
 (ISSUE 2: sum of latency-histogram counts == finished requests)."""
 
+import gc
 import json
 import re
 import urllib.request
@@ -370,14 +371,18 @@ def test_http_scrape_endpoint():
 
 
 def test_tracer_nesting_and_registry_feed():
+    """Spans nest by thread; the ring is their one record: a tracer takes
+    no registry to mirror durations into (the engine's histograms observe
+    its spans' durations itself)."""
     fake_t = [0.0]
 
     def clock():
         fake_t[0] += 1.0
         return fake_t[0]
 
-    r = obs.Registry()
-    tr = obs.Tracer(registry=r, annotate=False, clock=clock)
+    with pytest.raises(TypeError):
+        obs.Tracer(registry=obs.Registry())
+    tr = obs.Tracer(annotate=False, clock=clock)
     with tr.span("step") as outer:
         assert tr.current() is outer and outer.name == "step"
         with tr.span("prefill") as inner:
@@ -388,10 +393,9 @@ def test_tracer_nesting_and_registry_feed():
         ("step.prefill", outer.id, 1.0),
         ("step", None, 3.0),
     ]
-    from distributed_tensorflow_tpu.obs.trace import SPAN_HISTOGRAM
+    from distributed_tensorflow_tpu.obs import trace as trace_lib
 
-    assert r.get(SPAN_HISTOGRAM, span="step.prefill").count == 1
-    assert r.get(SPAN_HISTOGRAM, span="step").count == 1
+    assert not hasattr(trace_lib, "SPAN_HISTOGRAM")
 
 
 def test_tracer_records_on_exception_and_bounds_events():
@@ -448,7 +452,7 @@ def test_tracer_ids_parents_keys_and_counts():
     # the other thread's span did not nest under this thread's open span
     assert got["span"].parent is None and got["span"].name == "side"
     assert [s.name for s in a.events] == ["step.prefill", "side", "step"]
-    assert a.registry is None and a.dropped == 0
+    assert not hasattr(a, "registry") and a.dropped == 0
 
 
 def test_tracer_record_takes_stamps_and_reads_no_clock():
@@ -536,7 +540,7 @@ def test_default_tracer_records_compiles_under_the_open_span():
     import jax.numpy as jnp
 
     tr = obs.default_tracer()
-    assert obs.default_tracer() is tr and tr.registry is None
+    assert obs.default_tracer() is tr and not hasattr(tr, "registry")
 
     def f(x):
         return jnp.where(x > 2, jnp.sin(x), jnp.cos(x)) * 3 + 1
@@ -549,6 +553,119 @@ def test_default_tracer_records_compiles_under_the_open_span():
         "compile.backend", "compile.lower", "compile.trace"]
     for s in mine:
         assert s.end <= sp.end and s.end >= s.start
+
+
+def _gc_info(generation, collected=0, uncollectable=0):
+    return {"generation": generation, "collected": collected,
+            "uncollectable": uncollectable}
+
+
+def _hooks():
+    from distributed_tensorflow_tpu.obs import trace as trace_lib
+
+    return [cb for cb in gc.callbacks if getattr(cb, "__qualname__", "")
+            == trace_lib._collection_hook.__name__
+            + ".<locals>.on_collection"]
+
+
+def test_default_tracer_spans_a_full_collection_under_the_open_span():
+    """`gc.collect()` inside a span of the default tracer leaves a
+    `runtime.gc` span of generation 2 whose parent is that span, with the
+    collector's own counts; the hook is installed once, however often the
+    tracer is asked for."""
+    tr = obs.default_tracer()
+    for _ in range(3):
+        assert obs.default_tracer() is tr
+    assert len(_hooks()) == 1
+    with tr.span("test.collect_here") as sp:
+        gc.collect()
+    mine = [s for s in tr.events if s.name == "runtime.gc"
+            and s.parent == sp.id]
+    assert len(mine) == 1
+    got = mine[0]
+    assert sp.start <= got.start <= got.end <= sp.end
+    assert set(got.attrs) == {"generation", "collected", "uncollectable"}
+    assert got.attrs["generation"] == 2
+    assert all(type(got.attrs[k]) is int and got.attrs[k] >= 0
+               for k in ("collected", "uncollectable"))
+    assert got.key is None and got.id > sp.id
+
+
+@pytest.mark.parametrize("generation,seconds,spanned", [
+    (0, 0.5e-3, False),   # a young collection a step cannot feel
+    (1, 0.999e-3, False),
+    (0, 1e-3, True),      # GC_SPAN_MIN_S or more: felt
+    (1, 4e-3, True),
+    (2, 1e-6, True),      # every full collection
+])
+def test_collection_hook_spans_what_a_step_can_feel(
+        monkeypatch, generation, seconds, spanned):
+    """The hook driven directly, on a fake clock: a collection becomes a
+    ring span when it is of generation 2 or lasts `GC_SPAN_MIN_S`; every
+    collection is a profiler annotation `runtime.gc.<id>`, and a recorded
+    one carries that id, the span open on the thread as parent and the
+    counts of the stop phase."""
+    from distributed_tensorflow_tpu.obs import trace as trace_lib
+
+    assert trace_lib.GC_SPAN_MIN_S == 1e-3
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(trace_lib.jax.profiler, "TraceAnnotation", Annotation)
+    now = [0.0]
+    tr = obs.Tracer(clock=lambda: now[0])
+    hook = trace_lib._collection_hook(tr)
+    with tr.span("serve.step") as step:
+        seen.clear()
+        hook("start", _gc_info(generation))
+        now[0] += seconds
+        hook("stop", _gc_info(generation, collected=7, uncollectable=1))
+        assert [k for k, _ in seen] == ["enter", "exit"]
+    assert seen[0][1] == seen[1][1]
+    name, _, span_id = seen[0][1].rpartition(".")
+    assert name == "runtime.gc"
+    gcs = [s for s in tr.events if s.name == "runtime.gc"]
+    if not spanned:
+        assert gcs == [] and [s.name for s in tr.events] == ["serve.step"]
+        return
+    (got,) = gcs
+    assert (got.id, got.parent, got.key) == (int(span_id), step.id, None)
+    assert (got.start, got.end) == (0.0, seconds)
+    assert got.attrs == {"generation": generation, "collected": 7,
+                         "uncollectable": 1}
+
+
+def test_collection_hook_survives_a_full_ring_and_an_unopened_stop():
+    """A full ring still records the collection and counts what it evicts;
+    a stop whose start the hook never saw (installed mid-collection), a
+    collection outside any span and a tracer that annotates nothing
+    record without raising."""
+    from distributed_tensorflow_tpu.obs import trace as trace_lib
+
+    now = [0.0]
+    tr = obs.Tracer(annotate=False, max_events=2, clock=lambda: now[0])
+    hook = trace_lib._collection_hook(tr)
+    hook("stop", _gc_info(2))  # no start seen
+    assert list(tr.events) == []
+    for name in ("a", "b"):
+        with tr.span(name):
+            pass
+    for n in range(3):
+        hook("start", _gc_info(2))
+        now[0] += 0.25
+        hook("stop", _gc_info(2, collected=n))
+    assert len(tr.events) == 2 and tr.dropped == 3
+    assert [(s.name, s.parent, s.attrs["collected"]) for s in tr.events] \
+        == [("runtime.gc", None, 1), ("runtime.gc", None, 2)]
 
 
 # ---------------------------------------------------------------------------
@@ -643,8 +760,9 @@ def test_serve_engine_telemetry_counts_and_render():
 
 
 def test_serve_step_stats_timing_split():
-    """StepStats carries the prefill/decode wall split; registry reset
-    drops warmup observations but keeps recording (the bench contract)."""
+    """The engine's histograms carry the step's prefill/decode wall split,
+    from its spans' durations; registry reset drops warmup observations
+    but keeps recording (the bench contract)."""
     from distributed_tensorflow_tpu import serve
     from distributed_tensorflow_tpu.models import transformer as tfm
 
@@ -656,8 +774,15 @@ def test_serve_step_stats_timing_split():
     eng.submit([1, 2, 3], max_new_tokens=3)
     first = eng.step()
     assert first.admitted == 1
-    assert first.wall_s >= first.prefill_s + first.decode_s - 1e-6
-    assert first.prefill_s > 0 and first.decode_s > 0
+    step, prefill, decode = (eng.registry.get(f"serve_{n}_seconds") for n in
+                             ("step", "prefill", "decode"))
+    assert step.count == prefill.count == decode.count == 1
+    assert step.sum >= prefill.sum + decode.sum - 1e-6
+    assert prefill.sum > 0 and decode.sum > 0
+    spans = {s.name: s for s in eng.tracer.events
+             if s.name in ("serve.step", "serve.step.decode")}
+    assert step.sum == spans["serve.step"].duration
+    assert decode.sum == spans["serve.step.decode"].duration
 
     eng.run()
     eng.registry.reset()

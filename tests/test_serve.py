@@ -1244,24 +1244,33 @@ def test_step_span_counts_add_up_to_the_engines_own(traced_run):
     first_tokens = len(_named(run, "serve.step.prefill.sample"))
     assert decode_tokens + first_tokens \
         == reg.get("serve_tokens_total").value
-    # one serve.step span per step() call, whose StepStats timing split
-    # is the spans' durations
+    # one serve.step span per step() call; the engine's timing histograms
+    # observe the spans' durations: the step's, its admit + prefill
+    # children's (a step that admitted or prefilled), its decode child's (a
+    # step that decoded)
     steps = _named(run, "serve.step")
     assert len(steps) == len(run.steps)
     by_parent = {}
     for s in run.spans:
         by_parent.setdefault(s.parent, []).append(s)
+    split = {"step": [], "prefill": [], "decode": []}
     for sp, st in zip(steps, run.steps):
         kids = by_parent[sp.id]
-        assert st.wall_s == sp.duration
-        assert st.prefill_s == pytest.approx(sum(
-            k.duration for k in kids
-            if k.name in ("serve.step.admit", "serve.step.prefill")))
-        assert st.decode_s == sum(
-            k.duration for k in kids if k.name == "serve.step.decode")
+        split["step"].append(sp.duration)
+        if st.admitted or st.prefill_chunks:
+            split["prefill"].append(sum(
+                k.duration for k in kids
+                if k.name in ("serve.step.admit", "serve.step.prefill")))
+        if st.decoded_slots:
+            split["decode"].append(sum(
+                k.duration for k in kids if k.name == "serve.step.decode"))
         assert st.decoded_slots == sum(
             k.attrs.get("slots", 0) for k in kids
             if k.name == "serve.step.decode")
+    for phase, seconds in split.items():
+        hist = reg.get(f"serve_{phase}_seconds")
+        assert hist.count == len(seconds) > 0
+        assert hist.sum == pytest.approx(sum(seconds))
 
 
 def test_request_spans_partition_submit_to_finish(traced_run):

@@ -54,7 +54,7 @@ def check(verbose: bool = True) -> list[str]:
     reg.counter("obs_check_finished_total", "by reason",
                 reason='max"len\\path').inc()  # escaping torture
 
-    tracer = obs.Tracer(registry=reg, annotate=False)
+    tracer = obs.Tracer(annotate=False)
     with tracer.span("check"):
         with tracer.span("inner"):
             pass
