@@ -264,6 +264,9 @@ def resnet_leg(say, sizes: Sizes, n_chips: int, ckpt: str) -> None:
 
 def gpt_leg(say, sizes: Sizes, n_chips: int, dump_dir: str) -> None:
     import jax
+    import jax.numpy as jnp
+
+    from distributed_tensorflow_tpu.ops._tiling import flash_tile_plan
 
     overrides = [
         *sizes.gpt, "--mesh.data=-1",
@@ -294,9 +297,16 @@ def gpt_leg(say, sizes: Sizes, n_chips: int, dump_dir: str) -> None:
     counts = {k: flash.count(k) for k in sorted(set(flash))}
     say(f"gpt_lm: compiled step has {len(calls)} Mosaic calls, "
         f"{len(flash)} on per-device q/k/v {qkv}: {counts}")
-    check(len(counts) == 3 and min(counts.values()) >= model.num_layers,
-          f"want forward, dkv and dq flash kernels on {qkv} in each of "
-          f"{model.num_layers} layers, got {counts} of {len(calls)} calls; "
+    # the backward is one fused kernel where the plan keeps a head's
+    # queries in VMEM, else the dKV + dQ split
+    plan = flash_tile_plan(*qkv[:3], qkv[2], qkv[3],
+                           jnp.dtype(model.dtype).itemsize, True)
+    want = {"flash_attention_fwd"} | (
+        {"flash_attention_bwd_fused"} if plan.fused_bwd
+        else {"flash_attention_bwd_dkv", "flash_attention_bwd_dq"})
+    check(set(counts) == want and min(counts.values()) >= model.num_layers,
+          f"want {sorted(want)} on {qkv} in each of {model.num_layers} "
+          f"layers, got {counts} of {len(calls)} calls; "
           f"see chiprun_out/chip_smoke/gpt_step_mosaic_calls.txt")
 
 

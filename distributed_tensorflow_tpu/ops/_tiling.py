@@ -116,7 +116,9 @@ class FlashTilePlan(NamedTuple):
     """How the flash-attention kernels tile one call. A grid step owns
     ``hb`` heads of one ``block_q`` (forward, dQ) or ``block_k`` (dKV) row
     block and sweeps the other axis itself in chunks, over ``kv_span`` /
-    ``q_span`` resident rows (the whole sequence unless it does not fit)."""
+    ``q_span`` resident rows (the whole sequence unless it does not fit).
+    With ``fused_bwd`` the backward is one call, the dKV sweep summing dQ
+    as well; without, the dKV and dQ calls of the FlashAttention-2 split."""
 
     block_q: int
     block_k: int
@@ -124,13 +126,46 @@ class FlashTilePlan(NamedTuple):
     q_span: int
     kv_span: int
     resident: bool  # both spans cover their whole sequence
+    fused_bwd: bool  # resident, and the fused backward fits its VMEM limit
     grid_steps: int  # of the forward call
     computed_over_needed: float  # score elements computed / kept by the mask
 
 
+# the fused backward's scoped VMEM, of the v5e's 128 MiB (default 16), and
+# the part of it the plan's count may fill (the 3 MB slack of the 16)
+FLASH_BWD_VMEM_LIMIT = 32 * 1024 * 1024
+FLASH_BWD_VMEM_BUDGET = FLASH_BWD_VMEM_LIMIT - (16 << 20) + FULL_VMEM_BUDGET
+
+
+def _flash_dkv_bytes(block_k: int, hb: int, q_span: int, D: int,
+                     itemsize: int) -> int:
+    row = _lane_pad(D) * itemsize
+    stat = 128 * 4  # one [.., 8]-wide f32 or int32 row, lane-padded
+    return (2 * (hb * (4 * block_k * row + 2 * q_span * row
+                       + 2 * 8 * q_span * 4) + block_k * stat)
+            + 2 * hb * block_k * _lane_pad(D) * 4)
+
+
+def flash_fused_bwd_vmem_bytes(block_q: int, block_k: int, hb: int, Sq: int,
+                               D: int, itemsize: int) -> int:
+    """Per-step VMEM of the fused backward: the dKV kernel's at a q span of
+    the whole sequence and its score tiles (``flash_vmem_bytes``), plus the
+    f32 dQᵀ scratch ([D, Sq] a head: D on sublanes), the pipeline's two
+    copies of the [Sq, D] dQ output block (D on lanes), and a chunk's dQᵀ
+    product and its transposed, scaled copy at the write."""
+    d8 = -(-D // 8) * 8
+    chunk = hb * block_q * (d8 + 2 * _lane_pad(D)) * 4
+    return (_flash_dkv_bytes(block_k, hb, Sq, D, itemsize)
+            + 3 * hb * block_q * block_k * 4
+            + hb * d8 * Sq // block_q * _lane_pad(block_q) * 4
+            + 2 * hb * Sq * _lane_pad(D) * itemsize
+            + chunk)
+
+
 def flash_vmem_bytes(block_q: int, block_k: int, hb: int, q_span: int,
                      kv_span: int, D: int, itemsize: int) -> int:
-    """Largest per-step VMEM footprint of the three flash kernels. Streamed
+    """Largest per-step VMEM footprint of the forward and the two split
+    backward kernels (the fused one: ``flash_fused_bwd_vmem_bytes``). Streamed
     blocks are double-buffered, the head dim is padded to the lane width
     (D = 64 costs what 128 does), [rows, 8] row statistics pad to a full
     lane tile, and the score tile's temporaries are counted as three f32
@@ -148,9 +183,7 @@ def flash_vmem_bytes(block_q: int, block_k: int, hb: int, q_span: int,
     dq = (2 * (hb * (3 * block_q * row + 2 * kv_span * row
                      + 2 * block_q * stat) + 8 * kv_span * 4)
           + hb * block_q * acc)
-    dkv = (2 * (hb * (4 * block_k * row + 2 * q_span * row
-                      + 2 * 8 * q_span * 4) + block_k * stat)
-           + 2 * hb * block_k * acc)
+    dkv = _flash_dkv_bytes(block_k, hb, q_span, D, itemsize)
     return max(fwd, dq, dkv) + tiles
 
 
@@ -217,9 +250,12 @@ def flash_tile_plan(B: int, H: int, Sq: int, Sk: int, D: int, itemsize: int,
         needed = sum(min(max(r + 1 + Sk - Sq, 0), Sk) for r in range(Sq))
     else:
         needed = Sq * Sk
+    resident = q_span == Sq and kv_span == Sk
     plan = FlashTilePlan(
         block_q=block_q, block_k=block_k, hb=hb, q_span=q_span,
-        kv_span=kv_span, resident=(q_span == Sq and kv_span == Sk),
+        kv_span=kv_span, resident=resident,
+        fused_bwd=resident and flash_fused_bwd_vmem_bytes(
+            block_q, block_k, hb, Sq, D, itemsize) <= FLASH_BWD_VMEM_BUDGET,
         grid_steps=B * (H // hb) * (Sq // block_q) * (Sk // kv_span),
         computed_over_needed=computed / max(needed, 1),
     )

@@ -36,10 +36,15 @@ Design (FlashAttention-2, with the sweep inside the kernel):
   f32 VMEM scratch.
 - The forward also emits LSE = m + log l at sublane width
   ([B,H,Sq,STAT_DIM], STAT_DIM=8 — lane-broadcasting the row stat 128-wide
-  would cost 16x HBM for long sequences). The backward is two more pallas
-  calls, the FlashAttention-2 split that keeps every accumulator local to
-  one grid cell (no cross-instance atomics, which TPU does not have);
-  delta = rowsum(dO·O) is one plain reduction before them.
+  would cost 16x HBM for long sequences). Where a head's queries stay in
+  VMEM (a resident plan whose fused footprint fits,
+  ``FlashTilePlan.fused_bwd``) the backward is one more pallas call: the
+  dKV sweep, whose key blocks run in order for a head group, also sums dQ
+  in a VMEM accumulator over all the heads' queries, written once. Else
+  it is two, the FlashAttention-2 split that keeps every accumulator
+  local to one grid cell (no cross-instance atomics, which TPU does not
+  have), dQ computing the score tiles a second time. delta = rowsum(dO·O)
+  is one plain reduction before them.
 - ``kv_mask`` [B, Sk] covers padding (BERT-style); mask semantics match
   ops/attention.py (True = attend). A call without one compiles kernels
   with no mask select.
@@ -63,7 +68,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._tiling import flash_tile_plan, pad_to_sublane, paged_attn_plan
+from ._tiling import (
+    FLASH_BWD_VMEM_LIMIT, flash_tile_plan, pad_to_sublane, paged_attn_plan)
 from .attention import NEG_INF
 
 LANES = 128  # TPU lane width
@@ -287,19 +293,28 @@ def _fwd_call(q, k, v, kv_mask, *, sm_scale, causal, has_mask, q_offset,
 
 
 # ---------------------------------------------------------------------------
-# Backward: dKV kernel (kv block owned, q chunks swept) and
+# Backward: dKV kernel (kv block owned, q chunks swept; where a head's
+#           queries are resident it sums dQ too) and
 #           dQ kernel (q block owned, kv chunks swept)
 # ---------------------------------------------------------------------------
 
 
 def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref,
-    dk_acc, dv_acc,
-    *, sm_scale, causal, has_mask, block_q, q_offset,
+    q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref, *refs,
+    sm_scale, causal, has_mask, block_q, q_offset, fused,
 ):
     """On the transposed tile sᵀ = k·qᵀ [hb, bk, bq]: dV = pᵀ·dO and
-    dK = dsᵀ·q are then plain matmuls, and lse/delta are [1, bq] rows."""
+    dK = dsᵀ·q are then plain matmuls, and lse/delta are [1, bq] rows.
+
+    ``fused`` (a resident plan: one q span, so a head group's key blocks
+    run in order) also sums each chunk's dQᵀ = kᵀ·ds, the forward's
+    ``_dot_tn`` form, into an f32 scratch over all the heads' queries, a
+    chunk a leading index (a dynamic lane offset is not something Mosaic
+    takes), and writes dQ once, at the last key block."""
+    if fused:
+        dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = refs
     block_k = k_ref.shape[2]
     q_span = q_ref.shape[2]
     n_chunks = q_span // block_q
@@ -316,6 +331,11 @@ def _bwd_dkv_kernel(
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    if fused:
+        @pl.when(kj == 0)
+        def _init_dq():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
     k = k_ref[0]  # [hb, bk, D]
     v = v_ref[0]
@@ -338,7 +358,10 @@ def _bwd_dkv_kernel(
         dp = _dot_nt(v, do)  # [hb, bk, bq]
         ds = p * (dp - delta)
         dv_acc[...] += _dot_nn(p.astype(do.dtype), do)  # [hb, bk, D]
-        dk_acc[...] += _dot_nn(ds.astype(q.dtype), q)
+        ds = ds.astype(q.dtype)
+        dk_acc[...] += _dot_nn(ds, q)
+        if fused:
+            dq_acc[c] += _dot_tn(k, ds)  # [hb, D, bq]
 
     if causal:
         _loop(first, full, functools.partial(chunk, diagonal=True))
@@ -348,6 +371,13 @@ def _bwd_dkv_kernel(
     def _finalize():
         dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    if fused:
+        @pl.when(kj == pl.num_programs(2) - 1)
+        def _write_dq():
+            for c in range(n_chunks):
+                dq_ref[0, :, c * block_q:(c + 1) * block_q, :] = jnp.swapaxes(
+                    dq_acc[c] * sm_scale, 1, 2).astype(dq_ref.dtype)
 
 
 def _bwd_dq_kernel(
@@ -452,7 +482,9 @@ def _bwd_call(q, k, v, kv_mask, out, lse, do, *, sm_scale, causal, has_mask,
 
     # dKV: owns kv block j, sweeps the q chunks of span i from the diagonal
     # down; a causal kv block needs no q span before the one holding its
-    # first key's own query, so earlier spans map onto that one (no DMA)
+    # first key's own query, so earlier spans map onto that one (no DMA).
+    # Where the queries are resident it sums dQ as well, and is all of it.
+    fused = plan.fused_bwd
     span = plan.q_span
     if causal:
         q_i = lambda j, i: jnp.maximum(  # noqa: E731
@@ -465,8 +497,17 @@ def _bwd_call(q, k, v, kv_mask, out, lse, do, *, sm_scale, causal, has_mask,
         (1, hb, 1, span // bq, bq),
         lambda b, h, j, i: (b, h, q_i(j, i), 0, 0))
     rows = lambda x: x.reshape(B, H, -1, span // bq, bq)  # noqa: E731
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=bq, **common),
+    out_specs = [pl.BlockSpec((1, hb, bk, D), kspec)] * 2
+    out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
+                 jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    scratch = [pltpu.VMEM((hb, bk, D), jnp.float32)] * 2
+    if fused:
+        out_specs.append(pl.BlockSpec((1, hb, Sq, D), qspec))
+        out_shape.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
+        scratch.append(pltpu.VMEM((Sq // bq, hb, D, bq), jnp.float32))
+    grads = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, block_q=bq, fused=fused,
+                          **common),
         grid=(B, H // hb, Sk // bk, Sq // span),
         in_specs=[
             pl.BlockSpec((1, hb, span, D), qspec),
@@ -477,22 +518,20 @@ def _bwd_call(q, k, v, kv_mask, out, lse, do, *, sm_scale, causal, has_mask,
             rowspec,
             rowspec,
         ],
-        out_specs=[
-            pl.BlockSpec((1, hb, bk, D), kspec),
-            pl.BlockSpec((1, hb, bk, D), kspec),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((hb, bk, D), jnp.float32),
-            pltpu.VMEM((hb, bk, D), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
-        name="flash_attention_bwd_dkv",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FLASH_BWD_VMEM_LIMIT) if fused else None,
+        name=("flash_attention_bwd_fused" if fused
+              else "flash_attention_bwd_dkv"),
     )(q, k, v,
       _column_mask(kv_mask), do, rows(lse[..., 0]), rows(delta))
+    if fused:
+        dk, dv, dq = grads
+        return dq, dk, dv
+    dk, dv = grads
 
     # dQ: the forward's sweep
     span = plan.kv_span

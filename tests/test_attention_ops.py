@@ -246,6 +246,42 @@ def test_flash_spans_that_do_not_fit_vmem(monkeypatch, Sq, Sk, causal, mask):
         _tiling.flash_tile_plan.cache_clear()
 
 
+@pytest.mark.parametrize("mask", ["none", "padded", "row_fully_masked"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("Sq,Sk", [(256, 256), (256, 512)])
+def test_flash_fused_backward_matches_the_split(monkeypatch, Sq, Sk, causal,
+                                                mask):
+    """One input through both backwards at the same tiles: the fused call
+    (dQ summed in the dKV sweep) and the dKV + dQ split, forced by a fused
+    budget of nothing. Only the order of dQ's key-block terms differs."""
+    from distributed_tensorflow_tpu.ops import _tiling
+
+    B, H, D = 2, 2, 64
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(13), 4)
+    q = jax.random.normal(kq, (B, H, Sq, D))
+    k = jax.random.normal(kk, (B, H, Sk, D))
+    v = jax.random.normal(kv, (B, H, Sk, D))
+    w = jax.random.normal(kw, (B, H, Sq, D))
+    kw_ = dict(causal=causal, kv_mask=_masked(mask, B, Sk), block_q=128,
+               block_k=128)
+
+    def run():
+        _tiling.flash_tile_plan.cache_clear()
+        plan = _tiling.flash_tile_plan(B, H, Sq, Sk, D, 4, causal, 128, 128)
+        return plan, _out_and_grads(flash_attention, q, k, v, w, **kw_)
+
+    try:
+        fused_plan, fused = run()
+        monkeypatch.setattr(_tiling, "FLASH_BWD_VMEM_BUDGET", 0)
+        split_plan, split = run()
+    finally:
+        _tiling.flash_tile_plan.cache_clear()
+    assert fused_plan.fused_bwd and not split_plan.fused_bwd
+    assert fused_plan._replace(fused_bwd=False) == split_plan
+    for name, a, b in zip(("out", "dq", "dk", "dv"), fused, split):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5, err_msg=name)
+
+
 _PAGED_BS = 8  # block size of the paged cases below
 
 

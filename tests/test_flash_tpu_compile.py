@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from distributed_tensorflow_tpu.ops._tiling import flash_tile_plan
 from distributed_tensorflow_tpu.ops.flash_attention import flash_attention
 
 
@@ -42,7 +43,7 @@ def one_chip():
 ], ids=lambda v: str(getattr(v, "__name__", v)).replace(" ", ""))
 def test_flash_forward_and_backward_compile_for_v5e(
         one_chip, shape, dtype, causal, padded):
-    B, _, S, _ = shape
+    B, H, S, D = shape
     qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     mask = jax.ShapeDtypeStruct((B, S), jnp.bool_, sharding=one_chip)
 
@@ -55,9 +56,18 @@ def test_flash_forward_and_backward_compile_for_v5e(
         return jax.grad(loss, (0, 1, 2))(q, k, v)
 
     text = jax.jit(step).lower(qkv, qkv, qkv, mask).compile().as_text()
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dkv",
-                   "flash_attention_bwd_dq"):
+    # the backward is one fused call where a head's queries stay in VMEM,
+    # else the dKV + dQ split; the plan says which, the compiled text holds
+    # that and not the other
+    plan = flash_tile_plan(B, H, S, S, D, jnp.dtype(dtype).itemsize, causal)
+    assert plan.fused_bwd == (S <= 2048)  # the two longest do not fit
+    fused = ("flash_attention_bwd_fused",)
+    split = ("flash_attention_bwd_dkv", "flash_attention_bwd_dq")
+    want, absent = (fused, split) if plan.fused_bwd else (split, fused)
+    for kernel in ("flash_attention_fwd", *want):
         assert kernel in text
+    for kernel in absent:
+        assert kernel not in text
 
 
 # ---------------------------------------------------------------------------

@@ -91,6 +91,34 @@ def test_flash_plan_for_the_benchmark_training_call():
     assert plan.computed_over_needed <= 1.3
 
 
+def test_flash_plan_fuses_the_backward_of_the_benchmark_training_call():
+    """(8, 16, 1024, 64) bf16 causal: a head's 1024 queries stay in VMEM,
+    so the backward is one call; the forward's tiles are what they were
+    before there was a fused backward (4 heads a step, 256 x 256)."""
+    plan = _tiling.flash_tile_plan(8, 16, 1024, 1024, 64, 2, True)
+    assert plan.fused_bwd
+    assert (plan.hb, plan.block_q, plan.block_k) == (4, 256, 256)
+    assert (plan.q_span, plan.kv_span) == (1024, 1024)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("S", FLASH_S)
+def test_flash_fused_backward_only_where_queries_are_resident(S, D, causal):
+    """The fused backward holds every query of its heads: it is chosen only
+    for a resident plan, within its own scoped limit by the plan's count,
+    and wherever a resident plan's fused count fits."""
+    plan = _tiling.flash_tile_plan(1, 8, S, S, D, 2, causal)
+    fused = _tiling.flash_fused_bwd_vmem_bytes(
+        plan.block_q, plan.block_k, plan.hb, S, D, 2)
+    assert plan.fused_bwd <= plan.resident
+    assert plan.fused_bwd == (
+        plan.resident and fused <= _tiling.FLASH_BWD_VMEM_BUDGET)
+    if plan.fused_bwd:
+        assert fused < _tiling.FLASH_BWD_VMEM_LIMIT
+    assert _tiling.FLASH_BWD_VMEM_BUDGET < _tiling.FLASH_BWD_VMEM_LIMIT
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("S", [384, 640])
 def test_flash_blocks_divide_bert_padded_lengths(S, causal):
